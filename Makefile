@@ -5,7 +5,7 @@ SHELL := /bin/bash  # test-tier1 needs pipefail
 
 .PHONY: all native test bench bench-all bench-smoke bench-cluster \
         bench-multichip bench-write bench-compact bench-fanout run clean \
-        protos lint typecheck check test-tier1
+        protos lint typecheck check test-tier1 chip-smoke
 
 all: native
 
@@ -57,13 +57,24 @@ bench-all: native
 	KB_BENCH_METRIC=compact python bench.py
 	KB_BENCH_METRIC=insert python bench.py
 
-# Scheduler microbench on a tiny dataset (CPU, no native build needed):
+# The on-chip check (PERF.md): the served --storage=tpu path end to end on
+# the attached TPU, both scan kernels, answers compared with the smoke's own
+# oracle and /metrics held to "the DEVICE answered". No JAX_PLATFORMS pin:
+# the smoke gives its server child JAX_PLATFORMS=tpu and FAILS without a
+# chip. One process per chip — nothing else may hold it meanwhile.
+chip-smoke:
+	python chip_smoke.py
+
+# CPU-sim. Scheduler microbench on a tiny dataset (no native build needed):
 # asserts scheduled == unscheduled byte-identically, reports coalescing
 # and shed counters. Fast enough for CI smoke.
 bench-smoke:
 	JAX_PLATFORMS=cpu KB_BENCH_METRIC=sched KB_BENCH_KEYS=2000 \
 	    KB_BENCH_OPS=200 python bench.py
 
+# CPU-sim (REPLICAS>0 and SCENARIO=watch_heavy start 2-3 JAX servers at
+# once, and a chip belongs to one process: CPU-sim ONLY until servers can
+# be pinned to their own chips, ROADMAP R7(b)).
 # Cluster-scale workload replay (kubebrain_tpu/workload): deterministic
 # kube-apiserver traffic for an N-node simulated cluster through the real
 # gRPC front — pod churn + controller list/watch + node lease keepalives +
@@ -112,7 +123,7 @@ bench-cluster:
 	    KB_WORKLOAD_REPLICAS=$(REPLICAS) \
 	    KB_WORKLOAD_MESH_WAT=$(MESH_WAT) python bench.py
 
-# Watch fan-out bench (docs/watch.md): block-batched device matching at
+# CPU-sim. Watch fan-out bench (docs/watch.md): block-batched device matching at
 # 10k+ watchers — watch_fanout_events_per_sec, delivery masks asserted
 # byte-identical to the host segment-index oracle, batched path >= 2x the
 # per-batch device path on CPU-sim (TPU bar pending_tpu off-TPU). Emits
@@ -120,20 +131,21 @@ bench-cluster:
 bench-fanout:
 	JAX_PLATFORMS=cpu KB_BENCH_METRIC=fanout python bench.py
 
-# Multichip sharded serving curve (docs/multichip.md): the scan workload
+# CPU-sim (8 virtual devices). Multichip sharded serving curve
+# (docs/multichip.md): the scan workload
 # served through the scheduler at mesh sizes 1..8, byte-identical across
 # sizes; KB_MULTICHIP_OUT=MULTICHIP_rNN.json writes the schema'd report.
 bench-multichip:
 	JAX_PLATFORMS=cpu KB_BENCH_METRIC=multichip python bench.py
 
-# Write-path group commit (docs/writes.md): write_txns_per_sec serial vs
+# CPU-sim. Write-path group commit (docs/writes.md): write_txns_per_sec serial vs
 # grouped at 8-writer concurrency (grouped >= 1.5x asserted on CPU,
 # byte-identity vs the sequential oracle), plus the TPU-engine steady
 # state proving the incremental delta merge never takes a full rebuild.
 bench-write:
 	JAX_PLATFORMS=cpu KB_BENCH_METRIC=write python bench.py
 
-# Device-side compaction (docs/compaction.md): the stored-domain pipeline
+# CPU-sim. Device-side compaction (docs/compaction.md): the stored-domain pipeline
 # vs the engine-generic host compactor over one ~1M-row store with a
 # realistic victim mix — byte-identity vs the sequential oracle asserted,
 # zero full rebuilds / re-dictionary encodes asserted, >= 2x host asserted

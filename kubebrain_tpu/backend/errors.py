@@ -1,4 +1,4 @@
-"""Backend-level error taxonomy (maps onto etcd3 error codes at the shim)."""
+"""Backend-level error classes (maps onto etcd3 error codes at the shim)."""
 
 from __future__ import annotations
 

@@ -311,13 +311,11 @@ class WatcherHub:
     _on_tpu_cached: bool | None = None
 
     def _on_tpu(self) -> bool:
+        # only consulted with a device matcher installed, so jax is there
         if WatcherHub._on_tpu_cached is None:
-            try:
-                import jax
+            import jax
 
-                WatcherHub._on_tpu_cached = jax.default_backend() == "tpu"
-            except Exception:
-                WatcherHub._on_tpu_cached = False
+            WatcherHub._on_tpu_cached = jax.default_backend() == "tpu"
         return WatcherHub._on_tpu_cached
 
     def stream(self, batch: list[WatchEvent]) -> None:

@@ -51,18 +51,12 @@ def _wat_shard_map(f, mesh, n_wat_args: int, n_rep_args: int, n_out: int):
     from jax.sharding import PartitionSpec as PS
 
     axis = mesh.axis_names[0]
-    specs = dict(
+    return jax.shard_map(
+        f, mesh=mesh,
         in_specs=(PS(),) * n_rep_args + (PS(axis),) * n_wat_args,
         out_specs=(PS(axis),) * n_out,
+        check_vma=False,
     )
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.8 jax
-        from jax.experimental.shard_map import shard_map
-
-        specs["check_rep"] = False
-    else:
-        specs["check_vma"] = False
-    return shard_map(f, mesh=mesh, **specs)
 
 
 @functools.partial(jax.jit, static_argnames=("size", "mesh"))

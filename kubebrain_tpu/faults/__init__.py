@@ -9,7 +9,7 @@ Three pieces, mirroring the reference's robustness posture (the whole
   at every boundary (storage ops, endpoint RPCs, watch streams, the TPU
   mirror's merge machinery), inert until armed;
 - :mod:`.inject` — the ``FaultyStorage`` engine decorator injecting the
-  storage error taxonomy (latency / definite error / *uncertain*
+  storage error classes (latency / definite error / *uncertain*
   outcome) under any engine.
 
 The chaos runner (``make bench-cluster FAULTS=<preset>``) replays a

@@ -1,4 +1,4 @@
-"""Engine decorator injecting the storage error taxonomy at the op
+"""Engine decorator injecting the storage error classes at the op
 boundary — the fault-plane twin of ``storage/metrics_wrap.py``.
 
 ``FaultyStorage`` wraps any engine and, per boundary call, asks the

@@ -24,7 +24,7 @@ import random
 from dataclasses import dataclass
 from typing import Iterator
 
-# ------------------------------------------------------------ fault taxonomy
+# ------------------------------------------------------------ fault classes
 #: storage-op boundary (create/update/delete/write_batch/get/iter/scan)
 STORAGE_LATENCY = "storage_latency"    # param = added latency seconds
 STORAGE_ERROR = "storage_error"        # definite failure, nothing applied

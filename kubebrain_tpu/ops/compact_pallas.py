@@ -39,21 +39,21 @@ from .scan_pallas import (
 )
 
 
-def _kernel(scal_ref, start_ref, end_ref,
+def _kernel(nv_ref, scal_ref, start_ref, end_ref,
             keys_ref, rh_ref, rl_ref, tomb_ref, ttl_ref,
             mask_ref,
             carry_key, carry_flags,
             *, with_ttl: bool):
-    i = pl.program_id(0)
-    nt = pl.num_programs(0)
-    t = nt - 1 - i  # reversed tile order
+    # grid = (partitions, reverse tiles): the tile sweep of each partition is
+    # contiguous, and its first step (tile nt-1) masks the carry via have_i
+    t = pl.num_programs(1) - 1 - pl.program_id(1)  # reversed tile order
 
-    n_valid = scal_ref[0]
-    unbounded = scal_ref[1]
-    chi = scal_ref[2]  # compact revision, 31-bit split
-    clo = scal_ref[3]
-    thi = scal_ref[4]  # TTL cutoff revision, 31-bit split
-    tlo = scal_ref[5]
+    n_valid = nv_ref[pl.program_id(0)]
+    unbounded = scal_ref[0]
+    chi = scal_ref[1]  # compact revision, 31-bit split
+    clo = scal_ref[2]
+    thi = scal_ref[3]  # TTL cutoff revision, 31-bit split
+    tlo = scal_ref[4]
 
     keys = keys_ref[:, :]          # [C, T] int32 sign-flipped chunks
     rh = rh_ref[:, :]              # [1, T] int32 31-bit rev hi
@@ -98,18 +98,20 @@ def _kernel(scal_ref, start_ref, end_ref,
         seed_i = seed.astype(jnp.int32)
         boundary = same_next & is_last_col
         seed_i = jnp.where(boundary, carry_flags[1], seed_i)
-        expired = seed_i != 0
-        # in-tile links only: the last column's link is the boundary seed
-        run = same_next & ~is_last_col
+        # in-tile links only: the last column's link is the boundary seed.
+        # The segmented OR runs on int32 0/1 vectors rolled along the lane
+        # axis: Mosaic has no lowering for rolls of i1 vectors
+        expired_i = seed_i
+        run_i = (same_next & ~is_last_col).astype(jnp.int32)
         step = 1
         while step < tile:
             # wrapping rolls are safe: run windows containing the cut last
-            # column are False, so wrapped values never land
-            expired = expired | (run & jnp.roll(expired, -step))
-            run = run & jnp.roll(run, -step)
+            # column are 0, so wrapped values never land
+            expired_i = expired_i | (run_i & jnp.roll(expired_i, -step, axis=1))
+            run_i = run_i & jnp.roll(run_i, -step, axis=1)
             step *= 2
-        victims = victims | (expired & ttlk & valid)
-        carry_flags[1] = expired.astype(jnp.int32)[0, 0]
+        victims = victims | ((expired_i != 0) & ttlk & valid)
+        carry_flags[1] = expired_i[0, 0]
 
     mask_ref[:, :] = (victims & in_range).astype(jnp.int8)
 
@@ -118,62 +120,71 @@ def _kernel(scal_ref, start_ref, end_ref,
     carry_flags[0] = le_compact.astype(jnp.int32)[0, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("with_ttl", "interpret"))
-def victim_mask_pallas(keys_t, rh31, rl31, tomb8, ttl8, n_valid, start, end,
-                       unbounded, chi31, clo31, thi31, tlo31,
-                       with_ttl=True, interpret=False):
-    """Victim mask via the Pallas kernel over one partition.
+def _victim_call(keys_t, rh31, rl31, tomb8, ttl8, n_valid, start, end,
+                 unbounded, chi31, clo31, thi31, tlo31, with_ttl, interpret):
+    """THE victim ``pallas_call`` over P partitions (grid = partitions ×
+    reverse tiles; explicit axis, not ``jax.vmap`` — see ops/scan_pallas.py).
 
-    keys_t int32[C, N] chunk-major sign-flipped (N % LANE_TILE == 0);
-    rh31/rl31 int32[N]; tomb8/ttl8 int8[N]; start/end int32[C] sign-flipped
-    bounds; scalars n_valid/unbounded/compact/ttl-cutoff. Returns bool[N].
+    keys_t int32[P, C, N] chunk-major sign-flipped (N % LANE_TILE == 0);
+    rh31/rl31 int32[P, N]; tomb8/ttl8 int8[P, N]; n_valid int32[P];
+    start/end int32[C] sign-flipped bounds; scalars unbounded/compact/
+    ttl-cutoff. Returns bool[P, N].
     """
-    c, n = keys_t.shape
+    p, c, n = keys_t.shape
     assert n % LANE_TILE == 0, "pad rows to LANE_TILE"
     nt = n // LANE_TILE
-    scal = jnp.stack([
-        jnp.asarray(n_valid, jnp.int32),
-        jnp.asarray(unbounded, jnp.int32),
-        jnp.asarray(chi31, jnp.int32),
-        jnp.asarray(clo31, jnp.int32),
-        jnp.asarray(thi31, jnp.int32),
-        jnp.asarray(tlo31, jnp.int32),
-    ])
-    rev_map = lambda i: (0, nt - 1 - i)
+    scal = jnp.stack([jnp.asarray(x, jnp.int32) for x in (
+        unbounded, chi31, clo31, thi31, tlo31)])
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    bound = pl.BlockSpec((c, 1), lambda pi, i: (0, 0))
+    row = pl.BlockSpec((None, 1, LANE_TILE), lambda pi, i: (pi, 0, nt - 1 - i))
     mask = pl.pallas_call(
         functools.partial(_kernel, with_ttl=with_ttl),
-        grid=(nt,),
+        grid=(p, nt),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # scalars
-            pl.BlockSpec((c, 1), lambda i: (0, 0)),          # start bound
-            pl.BlockSpec((c, 1), lambda i: (0, 0)),          # end bound
-            pl.BlockSpec((c, LANE_TILE), rev_map),           # keys
-            pl.BlockSpec((1, LANE_TILE), rev_map),           # rev hi
-            pl.BlockSpec((1, LANE_TILE), rev_map),           # rev lo
-            pl.BlockSpec((1, LANE_TILE), rev_map),           # tombstones
-            pl.BlockSpec((1, LANE_TILE), rev_map),           # ttl-key flags
+            smem, smem,                 # n_valid[P]; scalars
+            bound, bound,               # start / end bounds
+            pl.BlockSpec((None, c, LANE_TILE),
+                         lambda pi, i: (pi, 0, nt - 1 - i)),  # keys
+            row, row, row, row,         # rev hi, rev lo, tombstones, ttl keys
         ],
-        out_specs=pl.BlockSpec((1, LANE_TILE), rev_map),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int8),
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((p, 1, n), jnp.int8),
         scratch_shapes=[
             pltpu.VMEM((c, 1), jnp.int32),                   # carried first key
             pltpu.SMEM((2,), jnp.int32),                     # le_compact, expired
         ],
         interpret=interpret,
     )(
-        scal,
+        jnp.asarray(n_valid, jnp.int32).reshape(p), scal,
         start.reshape(c, 1), end.reshape(c, 1),
-        keys_t, rh31.reshape(1, n), rl31.reshape(1, n),
-        tomb8.reshape(1, n), ttl8.reshape(1, n),
+        keys_t, rh31.reshape(p, 1, n), rl31.reshape(p, 1, n),
+        tomb8.reshape(p, 1, n), ttl8.reshape(p, 1, n),
     )
-    return mask.reshape(n) != 0
+    return mask.reshape(p, n) != 0
+
+
+@functools.partial(jax.jit, static_argnames=("with_ttl", "interpret"))
+def victim_mask_pallas(keys_t, rh31, rl31, tomb8, ttl8, n_valid, start, end,
+                       unbounded, chi31, clo31, thi31, tlo31,
+                       with_ttl=True, interpret=False):
+    """Victim mask over one partition.
+
+    keys_t int32[C, N] chunk-major sign-flipped (N % LANE_TILE == 0);
+    rh31/rl31 int32[N]; tomb8/ttl8 int8[N]; start/end int32[C] sign-flipped
+    bounds; scalars n_valid/unbounded/compact/ttl-cutoff. Returns bool[N].
+    """
+    return _victim_call(
+        keys_t[None], rh31[None], rl31[None], tomb8[None], ttl8[None],
+        n_valid, start, end, unbounded, chi31, clo31, thi31, tlo31,
+        with_ttl, interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("with_ttl", "interpret"))
 def victim_mask_batch_cached(keys_t, rh31, rl31, tomb8, ttl8, nv, start, end,
                              unbounded, compact_hi, compact_lo,
                              ttl_hi, ttl_lo, with_ttl=True, interpret=False):
-    """Batched (vmapped over partitions) victim masks over the
+    """Batched (all partitions in one launch) victim masks over the
     `prepare_mirror`-cached layout, mirroring engine._victim_batch's contract:
     32-bit uint revision splits in, bool[P, Npad] out (caller slices padding).
 
@@ -188,8 +199,5 @@ def victim_mask_batch_cached(keys_t, rh31, rl31, tomb8, ttl8, nv, start, end,
     s = _flip_sign_jnp(jnp.asarray(start, jnp.uint32))
     e = _flip_sign_jnp(jnp.asarray(end, jnp.uint32))
     unb = jnp.asarray(unbounded, jnp.int32)
-    f = lambda kt, a, b, t8, x8, n: victim_mask_pallas(
-        kt, a, b, t8, x8, n, s, e, unb, chi31, clo31, thi31, tlo31,
-        with_ttl=with_ttl, interpret=interpret,
-    )
-    return jax.vmap(f)(keys_t, rh31, rl31, tomb8, ttl8, nv)
+    return _victim_call(keys_t, rh31, rl31, tomb8, ttl8, nv, s, e, unb,
+                        chi31, clo31, thi31, tlo31, with_ttl, interpret)

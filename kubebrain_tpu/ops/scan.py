@@ -81,6 +81,13 @@ def visibility_mask(
     return cand & ~superseded & ~tomb
 
 
+# Query-batched compares up to this many (query, row) pairs per block are
+# vmapped over the query axis; larger ones map it sequentially. The vmapped
+# temporaries are ~210 B per pair on a v5e (436 MB at 8 x 262,144 by the TPU
+# compiler's memory analysis), so this is < 1 GB.
+_VMAP_QUERY_ROWS = 1 << 22
+
+
 def visibility_mask_queries(
     keys, rev_hi, rev_lo, tomb, n_valid, starts, ends, unbounded_ends,
     read_his, read_los,
@@ -90,11 +97,22 @@ def visibility_mask_queries(
     ``unbounded_ends`` bool[Q], ``read_his``/``read_los`` uint32[Q])
     answered against ONE block in one traced program. Returns bool[Q, N] —
     the jnp fallback of the query-batched Pallas kernel
-    (scan_pallas.scan_mask_pallas_q)."""
-    f = lambda s, e, u, hi, lo: visibility_mask(
-        keys, rev_hi, rev_lo, tomb, n_valid, s, e, u, hi, lo
+    (scan_pallas.scan_mask_pallas_q).
+
+    One launch either way, but XLA does not fuse the lex compare's
+    argmax/gather: the vmapped program materializes ``pred[Q, N, C]`` with C
+    padded to 128 lanes on a TPU — 20 GB at Q=8 over 20M rows, more than a
+    chip holds. A block too large for that (shapes are static) maps the
+    query axis sequentially instead; a served kube-sized mirror keeps the
+    plain vmap."""
+    f = lambda q: visibility_mask(
+        keys, rev_hi, rev_lo, tomb, n_valid, *q
     )
-    return jax.vmap(f)(starts, ends, unbounded_ends, read_his, read_los)
+    small = starts.shape[0] * keys.shape[0] <= _VMAP_QUERY_ROWS
+    # batch_size 0 is a full-width vmap, None one query at a time
+    return jax.lax.map(
+        f, (starts, ends, unbounded_ends, read_his, read_los),
+        batch_size=0 if small else None)
 
 
 @jax.jit

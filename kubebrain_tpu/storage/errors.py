@@ -1,4 +1,4 @@
-"""Storage error taxonomy that drives MVCC control flow.
+"""Storage error classes that drive MVCC control flow.
 
 Reference: pkg/storage/errors.go:23-75. Three errors matter to the layers
 above the engine:

@@ -34,6 +34,7 @@ import queue
 import socket
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 import urllib.request
@@ -82,6 +83,22 @@ _TXN = "/etcdserverpb.KV/Txn"
 _RANGE = "/etcdserverpb.KV/Range"
 _COMPACT = "/etcdserverpb.KV/Compact"
 _LEASE_GRANT_RPC = "/etcdserverpb.Lease/LeaseGrant"
+
+
+def _server_platform(snap: slo.PromSnapshot, storage: str) -> dict:
+    """Where the SERVER computed, read off its own /metrics rather than
+    this process's environment: the ``kb_mirror_bytes{device=}`` labels are
+    ``str(device)`` of every scan-mesh device. A server that keeps no
+    device mirror (memkv/native storage) stamps ``host``."""
+    devices = sorted({labels.get("device", "")
+                      for labels, _v in snap.get("kb_mirror_bytes", [])})
+    kinds = sorted({"tpu" if d.startswith("TPU") else "cpu" if "CPU" in d
+                    else d for d in devices})
+    return {
+        "platform": "+".join(kinds) or "host",
+        "device": f"kubebrain-cli(storage={storage}, front=sync-grpc, "
+                  f"devices={','.join(devices) or 'none'})",
+    }
 
 
 def free_port() -> int:
@@ -175,6 +192,7 @@ class WorkloadRunner:
         self._lease_lock = threading.Lock()
         self._lease_ids: dict[int, int] = {}
         self._server: subprocess.Popen | None = None
+        self._server_err: Any = None  # the spawned servers' stderr
         self._info_port = info_port
         # /metrics lives on the target's host, not necessarily localhost
         self._info_host = (target.rsplit(":", 1)[0] if target
@@ -481,9 +499,8 @@ class WorkloadRunner:
                 # compactor would make the op trace's COMPACT accounting lie
                 "--compact-interval", "86400"]
         args += role_args + chaos_args
-        platform = os.environ.get("KB_WORKLOAD_JAX_PLATFORM", "cpu")
-        if platform:
-            args += ["--jax-platform", platform]
+        # the server inherits the platform: JAX_PLATFORMS, or jax's own
+        # choice (the TPU when one is attached)
         proc = subprocess.Popen(args, cwd=REPO_ROOT, stderr=stderr, env=env)
         return proc, f"127.0.0.1:{client_port}", info_port
 
@@ -511,10 +528,11 @@ class WorkloadRunner:
                     # meet a merge
                     chaos_args += ["--merge-threshold", "32"]
         env = self._mesh_env()
-        stderr = subprocess.DEVNULL
-        log_fh = None
-        if self._server_log:
-            stderr = log_fh = open(self._server_log, "ab")  # noqa: SIM115
+        # never discarded: a server that dies at boot (no chip, a rejected
+        # flag) is reported with its own last words. Closed at teardown.
+        stderr = self._server_err = (
+            open(self._server_log, "a+b")  # noqa: SIM115
+            if self._server_log else tempfile.TemporaryFile())
         try:
             mesh_args = self._mesh_args()
             self._server, self._target, self._info_port = self._spawn_one(
@@ -536,12 +554,10 @@ class WorkloadRunner:
                     self._followers.append(proc)
                     self._targets.append(target)
                     self._info_ports.append(info)
-        finally:
-            # every child holds its own dup of the log fd after spawn; the
-            # parent's handle must not outlive this scope — and must close
-            # when a spawn fails partway
-            if log_fh is not None:
-                log_fh.close()
+        except BaseException:
+            # a spawn that fails partway never reaches run()'s teardown
+            self._server_err.close()
+            raise
 
     def _mesh_args(self) -> list[str]:
         args: list[str] = []
@@ -578,9 +594,9 @@ class WorkloadRunner:
             # the wat axis needs its own device count; axes don't compose
             # into one grid here (separate 1-D meshes), so cover the max
             want_dev = max(want_dev, self.spec.mesh_wat)
-            if os.environ.get("KB_WORKLOAD_JAX_PLATFORM", "cpu") == "cpu":
-                # simulate the mesh devices in the child (the same
-                # mechanism tests/conftest.py uses)
+            if os.environ.get("JAX_PLATFORMS") == "cpu":
+                # CPU simulation: give the child the mesh devices (the
+                # same mechanism tests/conftest.py uses)
                 env = dict(os.environ)
                 flags = env.get("XLA_FLAGS", "")
                 if "xla_force_host_platform_device_count" not in flags:
@@ -606,8 +622,8 @@ class WorkloadRunner:
             if proc is not None and proc.poll() is not None:
                 raise RuntimeError(
                     f"server at {target} exited rc="
-                    f"{proc.returncode} before serving (rerun with "
-                    f"server_log= to capture its stderr)")
+                    f"{proc.returncode} before serving; its stderr ends:\n"
+                    f"{self._server_stderr_tail()}")
             probe = EtcdCompatClient(target)
             try:
                 probe.count(b"/workload-probe", b"/workload-probe0")
@@ -617,6 +633,14 @@ class WorkloadRunner:
                 probe.close()
                 time.sleep(0.3)
         raise RuntimeError(f"server at {target} never served")
+
+    def _server_stderr_tail(self, nbytes: int = 2000) -> str:
+        """The end of what the spawned servers wrote to stderr. pread: the
+        children share the spill file's offset, which a seek would move."""
+        fd = self._server_err.fileno()
+        size = os.fstat(fd).st_size
+        return os.pread(fd, nbytes, max(0, size - nbytes)).decode(
+            errors="replace")
 
     def _probe_all(self) -> None:
         self._probe()
@@ -1108,6 +1132,8 @@ class WorkloadRunner:
                     proc.wait(timeout=10)
                 except subprocess.TimeoutExpired:
                     proc.kill()
+            if self._server_err is not None:
+                self._server_err.close()
 
         passed, violations = slo.evaluate(report, spec.bounds)
         report["slo"]["pass"] = passed
@@ -1320,12 +1346,7 @@ class WorkloadRunner:
         report = {
             "schema": slo.SCHEMA_ID,
             "spec": spec.to_dict(),
-            "platform": {
-                "platform": os.environ.get("KB_WORKLOAD_JAX_PLATFORM")
-                            or os.environ.get("JAX_PLATFORMS") or "default",
-                "device": f"kubebrain-cli(storage={spec.storage}, "
-                          f"front=sync-grpc)",
-            },
+            "platform": _server_platform(final_snaps[0], spec.storage),
             "trace": {
                 "sha256": sha,
                 "ops": len(schedule.ops),

@@ -8,6 +8,7 @@ forever on a sleeping child process) must FAIL in well under 120s with
 thread stacks in the report and the wedged child reaped.
 """
 
+import os
 import subprocess
 import sys
 import time
@@ -38,6 +39,8 @@ def test_deadlocked_subprocess_test_fails_fast(tmp_path):
         [sys.executable, "-m", "pytest", str(tmp_path / "test_wedge.py"), "-q",
          "-p", "no:cacheprovider"],
         capture_output=True, text=True, timeout=115, cwd=str(tmp_path),
+        # the conftest imports the package (compile-cache rule, sanitizers)
+        env={**os.environ, "PYTHONPATH": str(REPO)},
     )
     elapsed = time.monotonic() - t0
     out = proc.stdout + proc.stderr

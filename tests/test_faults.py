@@ -1,7 +1,7 @@
 """Deterministic fault injection + graceful degradation (docs/faults.md).
 
 Covers the three chaos pieces: the pure fault schedule (replay identity),
-the FaultyStorage injection taxonomy through a real Backend (definite vs
+the FaultyStorage injection classification through a real Backend (definite vs
 uncertain outcomes, group-commit per-op demux, the async-FIFO read-back
 repair), the TPU mirror's quarantine / merge-retry / escalation state
 machine, and the end-to-end chaos smoke that asserts the keystone
@@ -67,7 +67,7 @@ def test_schedule_windows_inside_horizon():
     for w in s.windows:
         assert 0 <= w.t0_ms < w.t1_ms <= s.horizon_ms
         assert 0.0 < w.rate <= 1.0
-    # every single-server taxonomy kind is scheduled by the full preset;
+    # every single-server classification kind is scheduled by the full preset;
     # the follower-boundary kinds ride their own `replica` preset (armed
     # on follower processes only — docs/replication.md)
     assert set(s.kinds()) == set(faults.ALL_KINDS) - set(faults.REPLICA_KINDS)
@@ -171,7 +171,7 @@ def test_faults_none_is_byte_identical():
         faulty_store.close()
 
 
-# ----------------------------------------------- storage fault taxonomy
+# ----------------------------------------------- storage fault classes
 def test_definite_error_nothing_applied_and_sequencer_advances():
     store = FaultyStorage(new_storage("memkv"),
                           _ScriptedPlane([("error", 0.0)]))

@@ -23,9 +23,7 @@ def test_entry_compiles_and_runs():
 def test_dryrun_multichip_serves_and_emits_metric(n, capsys):
     """The dry run's tail is now the measured ``multichip_rows_per_sec``
     metric from real traffic served through the scheduler at mesh sizes
-    {1, n} — not the old ``dryrun ok: ...`` line. (The served phase runs on
-    jax versions without ``jax.shard_map``; only the legacy data-plane step
-    is gated on it.)"""
+    {1, n} — not the old ``dryrun ok: ...`` line."""
     import __graft_entry__ as g
 
     g.dryrun_multichip(n)

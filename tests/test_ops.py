@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from kubebrain_tpu.ops import keys as keyops
 from kubebrain_tpu.ops.compact import compact_block, victim_mask
 from kubebrain_tpu.ops.fanout import fanout_mask
+from kubebrain_tpu.ops import scan
 from kubebrain_tpu.ops.scan import lex_less, visibility_mask
 
 
@@ -100,6 +101,30 @@ def test_visibility_mask_vs_oracle(seed):
             got = {(rows[i][0], rows[i][1]) for i in np.nonzero(mask)[0]}
             want = _oracle_visible(rows, start, end, read_rev)
             assert got == want, f"seed={seed} rev={read_rev} range=({start},{end})"
+
+
+@pytest.mark.parametrize("vmap_rows", [1 << 22, 0])
+def test_visibility_mask_queries_both_forms_match_single(monkeypatch, vmap_rows):
+    """The query axis — vmapped on a small block, mapped sequentially on one
+    too large for the vmapped temporaries — is Q single-query masks."""
+    monkeypatch.setattr(scan, "_VMAP_QUERY_ROWS", vmap_rows)
+    rows, max_rev = _random_dataset(3)
+    chunks, _ = keyops.pack_keys([r[0] for r in rows])
+    hi, lo = keyops.split_revs(np.array([r[1] for r in rows], dtype=np.uint64))
+    block = [jnp.asarray(x) for x in (
+        chunks, hi, lo, np.array([r[2] for r in rows]), np.int32(len(rows)))]
+    queries = [(b"", b"", max_rev), (b"/reg/c", b"/reg/p", max_rev // 2),
+               (b"/reg/zz", b"", 1), (b"/reg/a", b"/reg/m", max_rev)]
+    qhi, qlo = keyops.split_revs(np.array([q[2] for q in queries], dtype=np.uint64))
+    starts = jnp.asarray(np.stack([keyops.pack_one(q[0]) for q in queries]))
+    ends = jnp.asarray(np.stack([keyops.pack_one(q[1]) for q in queries]))
+    unb = jnp.asarray(np.array([not q[1] for q in queries]))
+    got = np.asarray(scan.visibility_mask_queries(
+        *block, starts, ends, unb, jnp.asarray(qhi), jnp.asarray(qlo)))
+    for j in range(len(queries)):
+        want = np.asarray(visibility_mask(
+            *block, starts[j], ends[j], unb[j], qhi[j], qlo[j]))
+        assert (got[j] == want).all(), f"query {j}"
 
 
 def test_visibility_padding_rows_excluded():

@@ -164,7 +164,7 @@ def test_auto_depth_adapts_to_synthetic_slow_dispatch():
             assert sched.submit(measured_dispatch(0.0001, 0.1))
         assert sched.current_depth() == AUTO_DEPTH_MIN
 
-        # pathological RTT (wedged tunnel): clamped at the ceiling
+        # pathological RTT: clamped at the ceiling
         for _ in range(80):
             assert sched.submit(measured_dispatch(30.0, 0.1))
         assert sched.current_depth() == AUTO_DEPTH_MAX

@@ -49,7 +49,7 @@
 #                         real gRPC front
 #  10. chaos (FAULTS)   — deterministic fault injection (docs/faults.md):
 #                         schedule sha determinism, FAULTS=none inertness
-#                         byte-identity, the storage error taxonomy through
+#                         byte-identity, the storage error classes through
 #                         a live Backend (definite/uncertain + group-commit
 #                         demux + FIFO read-back repair), mirror quarantine/
 #                         merge-retry/escalation, watch resume (no lost or
@@ -126,7 +126,7 @@ echo "=== [9/12] replica: fence reads + bounded staleness + watch resume + two-r
 env JAX_PLATFORMS=cpu python -m pytest tests/test_replica.py -q -m 'not slow' \
     -p no:cacheprovider || exit 1
 
-echo "=== [10/12] chaos: fault-schedule determinism + inertness + taxonomy + FAULTS=smoke consistency gate"
+echo "=== [10/12] chaos: fault-schedule determinism + inertness + classification + FAULTS=smoke consistency gate"
 env JAX_PLATFORMS=cpu python -m pytest tests/test_faults.py \
     tests/test_watch_robustness.py -q -m 'not slow' \
     -p no:cacheprovider || exit 1
