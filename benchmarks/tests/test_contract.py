@@ -1,0 +1,122 @@
+"""BENCHMARK.json against the files it names: everything a cell, a
+configuration or a metric needs is found by name, so a later PR adds files
+and entries and edits nothing — shown by doing so."""
+
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+from types import SimpleNamespace
+
+import run
+from conftest import BENCH
+
+ROOT = os.path.dirname(BENCH)
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    B = json.load(_f)
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def test_names_and_files():
+    assert set(B) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    for c in B["configs"]:
+        assert NAME.match(c["name"]) and os.path.isfile(os.path.join(ROOT, c["file"]))
+        assert c["file"] == f"benchmarks/configs/{c['name']}.json"
+        assert set(c["reduced"]) == set(run.load_json(
+            "configs", c["name"] + ".json")["reduced"])
+    for w in B["workloads"]:
+        assert NAME.match(w["name"]) and len(w["why"]) <= 200 and w["chips"] == 1
+        assert w["name"] == f"{w['config']}.{w['traffic']}"
+        traffic, config = run.cell_files(w)
+        assert traffic["streams"] and config["tables"]
+    for m in B["end_to_end"] + B["per_layer"]:
+        assert NAME.match(m["name"])
+        spec = run.load_json("metrics", m["name"] + ".json")
+        assert os.path.isfile(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
+
+
+def test_every_layer_metric_moves_a_metric_its_cells_report():
+    cells = {w["name"] for w in B["workloads"]}
+    reports = {m["name"]: set(m.get("workloads", cells)) for m in B["end_to_end"]}
+    for m in B["per_layer"]:
+        assert set(m["workloads"]) <= reports[m["moves"]], m["name"]
+    for cell in cells:
+        assert any(cell in r for n, r in reports.items() if n != "setup_s")
+        assert any(cell in m["workloads"] for m in B["per_layer"])
+
+
+def test_the_server_is_started_with_the_readme_flags_and_one_more():
+    """No --merge-threshold, no --sched-*: the cells measure the defaults."""
+    for c in B["configs"]:
+        flags = run.load_json("configs", c["name"] + ".json")["server"]
+        assert flags == ["--single-node", "--storage=tpu", "--inner-storage=native",
+                         "--use-pallas", "--compact-interval", "86400"]
+
+
+def test_a_new_metric_over_an_existing_reader_is_one_file_and_one_entry(tmp_path, monkeypatch):
+    bench = tmp_path / "benchmarks"
+    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    (bench / "metrics" / "txn_p50_ms.json").write_text(json.dumps(
+        {"reader": "client_percentile", "args": {"family": "txn", "q": 50}}))
+    monkeypatch.setattr(run, "HERE", str(bench))
+    ctx = SimpleNamespace(recs=lambda fam: [
+        (1, "update", 0.0, 0.0, ms / 1e3, True) for ms in (1, 2, 3, 4, 5)])
+    assert run.read_metric("txn_p50_ms", ctx) == 3.0
+
+
+def test_a_rate_metric_is_one_file_over_the_reader_that_is_kept_for_it(tmp_path, monkeypatch):
+    """``write_ops_per_s`` left with the insert cell (PERF.md section 7); the
+    overload cell that brings it back adds this one file."""
+    bench = tmp_path / "benchmarks"
+    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    (bench / "metrics" / "write_ops_per_s.json").write_text(json.dumps(
+        {"reader": "ack_rate", "args": {"family": "txn"}}))
+    monkeypatch.setattr(run, "HERE", str(bench))
+    recs = [(1, "create", 0.0, 0.0, t, t < 9.5) for t in (1.0, 2.0, 9.0, 9.9, 12.0)]
+    ctx = SimpleNamespace(recs=lambda fam, **kw: recs, window=(0.0, 10.0), window_s=10.0)
+    assert run.read_metric("write_ops_per_s", ctx) == 0.3
+
+
+def test_a_cell_a_configuration_and_a_metric_are_added_as_files_only(tmp_path):
+    """A later PR's whole diff: one configuration file, one traffic file, one
+    metric file, and their entries in BENCHMARK.json. No file that was there
+    changes, and the new cell runs (here against the plain reference)."""
+    shutil.copytree(BENCH, tmp_path / "benchmarks",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    small = {"name": "tiny-kv", "reduced": [], "server": [], "tables": [{
+        "name": "kv", "key": "/kubebrain/bench/{h}", "prefix": "/kubebrain/bench/",
+        "ns_prefix": "/kubebrain/bench/", "hash_chars": 53, "count": 1500,
+        "namespaces": 1, "value_bytes": {"dist": "fixed", "bytes": 512}}]}
+    (tmp_path / "benchmarks" / "configs" / "tiny-kv.json").write_text(json.dumps(small))
+    (tmp_path / "benchmarks" / "traffic" / "trickle.json").write_text(json.dumps({
+        "why": "a later PR's mix", "warm_seconds": 1, "warmup_writes": 10,
+        "merges_in_window": 0, "streams": [
+            {"name": "w", "loop": "open", "rate": 50, "procs": 1, "judged": True,
+             "ops": [{"op": "create", "table": "kv", "weight": 1}]}]}))
+    (tmp_path / "benchmarks" / "metrics" / "txn_p50_ms.json").write_text(json.dumps(
+        {"reader": "client_percentile", "args": {"family": "txn", "q": 50}}))
+    bench = json.loads(json.dumps(B))
+    bench["configs"].append({"name": "tiny-kv", "source": "test", "reduced": [],
+                             "file": "benchmarks/configs/tiny-kv.json", "why": "t"})
+    bench["workloads"].append({"name": "tiny-kv.trickle", "config": "tiny-kv",
+                               "traffic": "trickle", "chips": 1, "why": "t"})
+    bench["end_to_end"].append({
+        "name": "txn_p50_ms", "unit": "ms", "better": "lower", "bound": 0.1,
+        "source": "host_clock", "workloads": ["tiny-kv.trickle"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    out = subprocess.run(
+        [sys.executable, str(tmp_path / "benchmarks" / "run.py"), "--workload",
+         "tiny-kv.trickle", "--seed", str(2**31 + 77), "--seconds", "2",
+         "--trace", "0", "--sut", "reference"],
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert RESULT_KEYS <= set(line) and list(line)[-1] == "compared"
+    assert set(line["metrics"]) == {"txn_p50_ms", "setup_s"}
+    assert line["attempted"] == 100 and line["failed"] == 0
+    assert line["rehearsal"]["comparison_passed"] and line["correct"] is False
+    assert "merges in window: counted None, designed 0" in out.stdout
