@@ -2312,10 +2312,10 @@ def main() -> None:
         # overhead" and fail the <5% assert spuriously
         def traced_scan():
             with TRACER.span("bench.scan"):
-                with TRACER.stage("device_dispatch", device=True):
+                with TRACER.stage("device_dispatch"):
                     out = scan_count(d_args[0], d_args[1], d_args[2],
                                      d_args[3], nv, s_dev, e_dev, qhi, qlo)
-                with TRACER.stage("device_compute", device=True):
+                with TRACER.stage("device_compute"):
                     jax.block_until_ready(out)
 
         lat_tr = []

@@ -753,9 +753,9 @@ class Backend:
         if fast is None:
             return None
         read_rev = self._read_revision_checked(revision)
-        # one C call does scan + wire encode; attribute it as the engine
-        # compute stage so the raw fast path still shows up in traces
-        with TRACER.stage("device_compute"):
+        # one C call does scan + wire encode on the host: the engine's
+        # iteration stage, so the raw fast path still shows up in traces
+        with TRACER.stage("host_scan"):
             blob, n, more = fast(start, end, read_rev, limit)
         return blob, n, more, read_rev
 

@@ -164,13 +164,12 @@ class Scanner:
         """
         lo, hi = coder.internal_range(start, end)
         snapshot = self._snapshot_checked(read_revision)
-        # trace attribution: the engine scan is this scanner's "device"
-        # (host iteration here; a kernel dispatch in the TPU scanner), the
-        # result merge is the host copy — the same stage names both engines
-        # report so /debug/traces reads identically across storage choices
+        # trace attribution: the engine iteration is ``host_scan`` (no
+        # device is involved: the device_* stages are the TPU scanner's
+        # kernel path alone), the result merge is the host copy
         if limit > 0:
             kvs: list[KeyValue] = []
-            with TRACER.stage("device_compute"):
+            with TRACER.stage("host_scan"):
                 self._scan_partition(
                     Partition(lo, hi), snapshot, read_revision, kvs.append,
                     limit=limit + 1,
@@ -179,7 +178,7 @@ class Scanner:
                 more = len(kvs) > limit
                 out = kvs[:limit]
             return out, more
-        with TRACER.stage("device_compute"):
+        with TRACER.stage("host_scan"):
             results = self._parallel_scan(lo, hi, snapshot, read_revision)
         with TRACER.stage("host_copy"):
             merged: list[KeyValue] = []
@@ -190,7 +189,7 @@ class Scanner:
     def count(self, start: bytes, end: bytes, read_revision: int) -> int:
         lo, hi = coder.internal_range(start, end)
         snapshot = self._snapshot_checked(read_revision)
-        with TRACER.stage("device_compute"):
+        with TRACER.stage("host_scan"):
             results = self._parallel_scan(
                 lo, hi, snapshot, read_revision, count_only=True)
         return sum(r.count for r in results)

@@ -14,8 +14,13 @@ import os
 import signal
 import sys
 import threading
+import time
 
 from . import __version__
+
+#: where boot's clock starts: this module's import (the package's own
+#: ``__init__`` has run by then; the interpreter's start is ~0.1 s earlier)
+_T_IMPORT = time.monotonic()
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -232,12 +237,34 @@ def uses_jax(args) -> bool:
     return args.storage == "tpu" or args.tpu_fanout
 
 
-def boot_line(backend) -> str:
+class BootPhases:
+    """Boot by phase (``kb_boot_seconds{phase=}``, ``boot_s`` in the boot
+    line): each ``mark`` closes the phase that ran since the one before, so
+    ``jax_init`` (imports + the first ``jax.devices()``), ``store_open``
+    (the inner store, WAL replay included) and ``listen`` (the rest of the
+    wiring, to the instant the ports answer) tile the time from ``t0``. The
+    mirror is built by the first read, not here: ``TpuScanner`` sets
+    ``mirror_build`` when that read has paid for it."""
+
+    def __init__(self, t0: float, metrics) -> None:
+        self._last = t0
+        self._metrics = metrics
+        self.seconds: dict[str, float] = {}
+
+    def mark(self, phase: str) -> None:
+        now = time.monotonic()
+        self.seconds[phase] = now - self._last
+        self._last = now
+        self._metrics.emit_gauge("kb.boot.seconds", self.seconds[phase],
+                                 phase=phase)
+
+
+def boot_line(backend, boot_s: dict | None = None) -> str:
     """The ONE line that says where this process computes: platform, device
     kind and count, scan mesh, resolved scan kernel, compile-cache
-    directory and the jax stack's versions. A server meant for the chip
-    that quietly came up on the CPU (or on the interpreted Pallas kernel)
-    is visible here and nowhere else at boot."""
+    directory, the jax stack's versions and boot by phase. A server meant
+    for the chip that quietly came up on the CPU (or on the interpreted
+    Pallas kernel) is visible here and nowhere else at boot."""
     import importlib.metadata
     import json
 
@@ -260,6 +287,8 @@ def boot_line(backend) -> str:
     describe = getattr(backend.scanner, "describe", None)
     if describe is not None:
         info.update(describe())
+    if boot_s:
+        info["boot_s"] = {k: round(v, 4) for k, v in boot_s.items()}
     return "kubebrain-tpu boot: " + json.dumps(info)
 
 
@@ -350,10 +379,17 @@ def validate_args(args) -> None:
             raise SystemExit("--fault-horizon-s must be > 0")
 
 
-def build_endpoint(args):
+def build_endpoint(args, boot_t0: float | None = None):
     """Dependency wiring (reference KubeBrainOption.Run, option.go:230-259):
-    storage → [metrics decorator] → backend → server → endpoint."""
+    storage → [metrics decorator] → backend → server → endpoint.
+    ``boot_t0`` is where boot's clock started (``main``: this module's
+    import); an embedding caller's boot starts here."""
     validate_args(args)
+    from .metrics import new_metrics
+
+    metrics = new_metrics(args.cluster_name)
+    boot = BootPhases(time.monotonic() if boot_t0 is None else boot_t0,
+                      metrics)
     # must happen before anything imports jax (embedding callers reach here
     # without going through main())
     apply_jax_platform(args.jax_platform)
@@ -361,15 +397,18 @@ def build_endpoint(args):
         from .util.jaxcache import use_compile_cache
 
         use_compile_cache()
+        import jax
+
+        # the backend's (TPU's) initialisation, paid here so that it is
+        # jax_init's and not the store's or the mesh's
+        jax.devices()
+        boot.mark("jax_init")
     from .backend import Backend, BackendConfig
     from .endpoint import Endpoint, EndpointConfig
-    from .metrics import new_metrics
     from .server import Server
     from .server.service import PeerService, SingleNodePeerService
     from .storage import new_storage
     from .util.net import get_host
-
-    metrics = new_metrics(args.cluster_name)
 
     # arm the process tracer: stage histograms (kb_rpc_stage_seconds) flow
     # into this metrics sink, slow requests into the /debug/traces slow log
@@ -453,6 +492,7 @@ def build_endpoint(args):
         )
     else:
         store = new_storage(args.storage)
+    boot.mark("store_open")
     if fault_plane is not None and args.storage != "tpu":
         from .faults import FaultyStorage
 
@@ -595,6 +635,8 @@ def build_endpoint(args):
         compact_interval=args.compact_interval,
         replica=replica_role,
     )
+    if uses_jax(args):
+        server.register_device_metrics()
     extra_http = {}
     if fault_plane is not None:
         # chaos-runner control surface on the info port: arm aligns the
@@ -614,6 +656,7 @@ def build_endpoint(args):
         grpc_workers=args.grpc_workers,
         extra_http=extra_http,
     ))
+    endpoint.boot = boot  # main() closes ``listen`` once the ports answer
     if args.aio_port:
         from .endpoint.aio import AioEndpoint
 
@@ -701,7 +744,7 @@ def main(argv=None) -> int:
             parts = [200_000, 1000, 1000]
         gc.set_threshold(*parts[:3])
 
-    endpoint, backend, store = build_endpoint(args)
+    endpoint, backend, store = build_endpoint(args, boot_t0=_T_IMPORT)
     if args.tier_auto_failover:
         if not endpoint.server.start_tier_watchdog():
             # an explicitly requested HA feature that cannot arm must not
@@ -726,8 +769,9 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGTERM, _graceful_exit)
 
     endpoint.run()
+    endpoint.boot.mark("listen")
     if uses_jax(args):
-        print(boot_line(backend), file=sys.stderr)
+        print(boot_line(backend, endpoint.boot.seconds), file=sys.stderr)
     print(
         f"kubebrain-tpu {__version__} serving: etcd3+brain gRPC :{args.client_port}, "
         f"peer http :{args.peer_port}, info http :{args.info_port} "

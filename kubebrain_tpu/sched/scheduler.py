@@ -163,10 +163,15 @@ class _Request:
 
     # ---- completion (leader result fans out to coalesced followers)
     def finish(self, result: Any = None,
-               error: BaseException | None = None) -> None:
+               error: BaseException | None = None,
+               executed_at: float = 0.0) -> None:
+        """``executed_at`` is when the execution ended, where the worker
+        stamped it before its slot release and bookkeeping: those are part
+        of handing the result back (``result_deliver``), not a hole in the
+        request's account."""
         self.result = result
         self.error = error
-        self.finished_at = time.monotonic()
+        self.finished_at = executed_at or time.monotonic()
         self.done.set()
         for f in self.followers:
             f.result = result
@@ -310,11 +315,11 @@ class RequestScheduler:
         AUTO_DEPTH_MAX; where it is small it settles near AUTO_DEPTH_MIN."""
         if self.config.depth > 0:
             return self.config.depth
-        # device-marked EWMAs only: host-path scans share the stage names
-        # (uniform traces) but must not shrink the divisor — see
-        # Tracer.record_stage(device=)
+        # only the TPU engine's kernel path records the device_* stages
+        # (a host scan is host_scan), so a µs-scale host iteration never
+        # shrinks the divisor
         rtt = TRACER.dispatch_rtt()
-        compute = TRACER.device_ewma("device_compute")
+        compute = TRACER.ewma("device_compute")
         if not rtt or not compute or compute <= 0:
             return AUTO_DEPTH_DEFAULT
         return max(AUTO_DEPTH_MIN, min(AUTO_DEPTH_MAX, math.ceil(rtt / compute)))
@@ -482,7 +487,8 @@ class RequestScheduler:
                 TRACER.record_stage("coalesce_join", req.enqueued, now,
                                     span=req.span)
             elif req.finished_at:
-                # worker completion -> waiter wakeup, so stage durations sum
+                # execution end -> waiter wakeup (the worker's slot release
+                # and bookkeeping, then the handoff), so stage durations sum
                 # to the observed end-to-end latency (no unattributed tail)
                 TRACER.record_stage("result_deliver", req.finished_at, now,
                                     span=req.span)
@@ -716,13 +722,14 @@ class RequestScheduler:
             except BaseException as e:  # surfaced to the waiting caller
                 result, err = None, e
             finally:
+                t_done = time.monotonic()
                 self._release_slot()
                 with self._cv:
                     if req.key is not None and \
                             self._inflight.get(req.key) is req:
                         del self._inflight[req.key]
                     self._inflight_count -= 1
-            req.finish(result=result, error=err)
+            req.finish(result=result, error=err, executed_at=t_done)
 
     def _run_batch(self, req: _Request) -> None:
         """Execute a batch leader + members as ONE backend call and demux.
@@ -746,6 +753,7 @@ class RequestScheduler:
         except BaseException as e:
             results, err = None, e
         finally:
+            t_done = time.monotonic()
             self._release_slot()
             with self._cv:
                 for r in batch:
@@ -753,14 +761,13 @@ class RequestScheduler:
                             self._inflight.get(r.key) is r:
                         del self._inflight[r.key]
                     self._inflight_count -= 1
-        t_done = time.monotonic()
         for i, r in enumerate(batch):
             if err is not None:
-                r.finish(error=err)
+                r.finish(error=err, executed_at=t_done)
             elif isinstance(results[i], BaseException):
-                r.finish(error=results[i])
+                r.finish(error=results[i], executed_at=t_done)
             else:
-                r.finish(result=results[i])
+                r.finish(result=results[i], executed_at=t_done)
             if r is not req:
                 # the member's whole device residency happened inside the
                 # leader's execution — one stage, coalesce_join-style

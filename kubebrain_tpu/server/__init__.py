@@ -9,7 +9,9 @@ from __future__ import annotations
 
 import json
 import sys
+import tempfile
 import threading
+import time
 import traceback
 
 from .. import __version__
@@ -38,6 +40,8 @@ class Server:
         #: follower role (kubebrain_tpu/replica; docs/replication.md)
         self.replica = replica
         self.brain = BrainServer(backend, peers, compact_interval=compact_interval)
+        self._capture: dict | None = None  # the running profiler capture
+        self._device_gauges: list[str] = []  # register_device_metrics
         self.grpc_handlers = (
             make_etcd_handlers(backend, peers, identity, client_urls or [],
                                replica=replica)
@@ -95,6 +99,21 @@ class Server:
     def start_background(self) -> None:
         self.brain.start_background()
 
+    def register_device_metrics(self) -> None:
+        """``kb_device_memory_peak_bytes{device=}``: each local device's
+        ``memory_stats()`` peak, sampled at scrape time (0 where the backend
+        reports none, as the CPU's does). Only the process that holds the
+        chip can say it, so it is this one's to report."""
+        import jax
+
+        for d in jax.local_devices():
+            self.metrics.register_gauge_fn(
+                "kb.device.memory.peak.bytes",
+                lambda d=d: float(
+                    (d.memory_stats() or {}).get("peak_bytes_in_use", 0)),
+                device=str(d))
+            self._device_gauges.append(str(d))
+
     # ------------------------------------------------------------------ HTTP
     def http_handlers(self) -> dict:
         """path -> fn() -> (content_type, body). The /status payload is the
@@ -106,7 +125,8 @@ class Server:
             "/debug/threads": self._threads,
             "/debug/traces": self._traces,
             "/debug/profile": self._profile,
-            "/debug/jax-profile": self._jax_profile,  # legacy fixed-2s alias
+            "/debug/profile/start": self._profile_start,
+            "/debug/profile/stop": self._profile_stop,
             "/tier/failover": self._tier_failover,
         }
 
@@ -177,17 +197,67 @@ class Server:
 
     _profile_lock = threading.Lock()
 
-    def _profile(self, query=None):
-        """``/debug/profile?seconds=N``: capture an on-demand ``jax.profiler``
-        device trace of the data plane for N seconds (default 2, clamped to
-        [0.05, 60]) — the kernel analogue of the reference's pprof mounts,
-        pkg/endpoint/pprof.go; inspect with tensorboard or xprof. One capture
-        at a time — an overlapping request would stop the in-flight trace
-        mid-capture."""
-        import time
-
+    def _capture_start(self, query) -> dict:
+        """Start the one ``jax.profiler`` capture this process may run, into
+        ``dir=`` (default: a fresh directory under the system's temporary
+        one, ``TMPDIR``). The Python tracer is off: it hooks every call of
+        this (Python) server and slows the very window it traces; device
+        ops and the tracer's ``kb.*`` annotations stay. Times are on
+        ``time.monotonic()``, the tracer's clock."""
         import jax
 
+        if not self._profile_lock.acquire(blocking=False):
+            return {"error": "profile capture already in progress"}
+        try:
+            out_dir = (query or {}).get("dir") or tempfile.mkdtemp(
+                prefix="kb-profile-")
+            t0 = time.monotonic()
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(out_dir, profiler_options=options)
+            start = time.monotonic()
+            self._capture = {"dir": out_dir, "start": start,
+                             "init_s": start - t0}
+            return dict(self._capture)  # the lock is _capture_stop's to release
+        except BaseException:
+            self._profile_lock.release()
+            raise
+
+    def _capture_stop(self) -> dict:
+        import jax
+
+        capture = self._capture
+        if not capture:
+            return {"error": "no profile capture is running"}
+        try:
+            stop = time.monotonic()
+            jax.profiler.stop_trace()
+            return dict(capture, stop=stop, flush_s=time.monotonic() - stop)
+        finally:
+            self._capture = None
+            self._profile_lock.release()
+
+    def _profile_start(self, query=None):
+        """``/debug/profile/start?dir=D``: begin a capture; answers ``dir``,
+        ``start`` and ``init_s`` (the first capture of a process pays the
+        profiler's initialisation)."""
+        return "application/json", json.dumps(
+            self._capture_start(query)).encode()
+
+    _profile_start.kb_query = True
+
+    def _profile_stop(self):
+        """``/debug/profile/stop``: end it; answers the start's fields plus
+        ``stop`` and ``flush_s``."""
+        return "application/json", json.dumps(self._capture_stop()).encode()
+
+    def _profile(self, query=None):
+        """``/debug/profile?seconds=N&dir=D``: capture an on-demand
+        ``jax.profiler`` device trace of the data plane for N seconds
+        (default 2, clamped to [0.05, 60]) — the kernel analogue of the
+        reference's pprof mounts, pkg/endpoint/pprof.go; inspect with
+        tensorboard or xprof. One capture at a time — an overlapping request
+        would stop the in-flight trace mid-capture."""
         try:
             seconds = float((query or {}).get("seconds", 2.0))
         except (TypeError, ValueError):
@@ -195,27 +265,17 @@ class Server:
                 {"error": "seconds must be a number"}
             ).encode()
         seconds = min(60.0, max(0.05, seconds))
-        if not self._profile_lock.acquire(blocking=False):
-            return "application/json", json.dumps(
-                {"error": "profile capture already in progress"}
-            ).encode()
+        started = self._capture_start(query)
+        if "error" in started:
+            return "application/json", json.dumps(started).encode()
         try:
-            out_dir = f"/tmp/kb-jax-profile-{int(time.time())}"
-            jax.profiler.start_trace(out_dir)
-            try:
-                time.sleep(seconds)
-            finally:
-                jax.profiler.stop_trace()
-            return "application/json", json.dumps(
-                {"trace_dir": out_dir, "seconds": seconds}
-            ).encode()
+            time.sleep(seconds)
         finally:
-            self._profile_lock.release()
+            out = self._capture_stop()
+        return "application/json", json.dumps(
+            dict(out, seconds=seconds)).encode()
 
     _profile.kb_query = True  # HTTP layers pass the parsed query string
-
-    def _jax_profile(self):
-        return self._profile()
 
     def start_tier_watchdog(self, interval: float = 1.0, failures: int = 3) -> bool:
         """Auto-failover for the replicated kbstored tier: probe the tier
@@ -277,6 +337,10 @@ class Server:
         return True
 
     def close(self) -> None:
+        for device in self._device_gauges:
+            self.metrics.unregister_gauge_fn("kb.device.memory.peak.bytes",
+                                             device=device)
+        self._device_gauges = []
         if getattr(self, "_watchdog_stop", None) is not None:
             self._watchdog_stop.set()
         self.brain.close()

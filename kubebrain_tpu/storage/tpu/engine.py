@@ -542,6 +542,13 @@ class TpuScanner(Scanner):
         self.rebuild_bg_count = 0
         self._rebuild_kick = threading.Lock()  # single-flight rebuilds
         self._fault_plane = None  # optional chaos-mode injection hooks
+        # seconds the FIRST mirror build took (export from the store,
+        # encode, place on the device): boot's mirror_build phase, paid by
+        # the first read (kb_boot_seconds{phase="mirror_build"})
+        self.boot_mirror_build_s: float | None = None
+        # the tracer's profiler sink: trace/ imports no JAX, so the engine
+        # that does hands it the annotation factory
+        TRACER.set_annotator(jax.profiler.TraceAnnotation)
 
     def describe(self) -> dict:
         """Resolved placement for the server's boot line: which scan kernel
@@ -572,6 +579,11 @@ class TpuScanner(Scanner):
                 state=state,
             )
             self._gauge_regs.append(("kb.mirror.state", {"state": state}))
+        # the delta's fill: what the merge-phase rule of the benchmark rests
+        # on (r rows as the window opens) and what every overlay costs
+        metrics.register_gauge_fn("kb.mirror.delta.rows",
+                                  lambda: len(self._delta))
+        self._gauge_regs.append(("kb.mirror.delta.rows", {}))
         if self._mesh is None:
             return
         for d in self._mesh.devices.flat:
@@ -781,7 +793,9 @@ class TpuScanner(Scanner):
     # ------------------------------------------------------------ write feed
     def record_version_rows(self, rows: list[tuple[bytes, int, bytes]]) -> None:
         plane = self._fault_plane
+        t0 = time.monotonic()
         with self._mlock:
+            waited = time.monotonic() - t0
             self._delta.extend(rows)  # O(log d) per row via the key index
             if plane is not None and plane.encode_overflow():
                 # chaos: an inexpressible key landed — the next merge must
@@ -795,6 +809,11 @@ class TpuScanner(Scanner):
                 or (plane is not None and len(self._delta) > 0
                     and plane.merge_fail_active()))
             pending = len(self._delta) > 0
+        if self._metrics is not None:
+            # a counter, not a histogram: its delta over a window is exact,
+            # where a mean over thousands of unblocked writes says nothing
+            self._metrics.emit_counter("kb.mirror.lock.wait.seconds", waited,
+                                       who="write")
         if plane is not None and plane.merges_suppressed():
             # chaos: merges suppressed — the delta grows (past the
             # threshold, since kicks are denied) and readers pay the
@@ -992,13 +1011,21 @@ class TpuScanner(Scanner):
         """Synchronous rebuild, caller holds ``_mlock`` (boot path and the
         forced ``publish()``); also the foreground recovery from a
         quarantined mirror — exiting the degraded window on success."""
-        self._mirror, _snapshot = self._build_mirror_from_store()
+        t0 = time.monotonic()
+        with TRACER.annotate("mirror_build"):
+            self._mirror, _snapshot = self._build_mirror_from_store()
         self._delta = self._fresh_delta()
         self._force_rebuild = False
         self._pallas_cache = None  # old mirror's device copies must not pin
         self._pallas_ttl_cache = None
         self._probe_cache = None
         self._exit_degraded_locked()
+        if self.boot_mirror_build_s is None:
+            self.boot_mirror_build_s = time.monotonic() - t0
+            if self._metrics is not None:
+                self._metrics.emit_gauge("kb.boot.seconds",
+                                         self.boot_mirror_build_s,
+                                         phase="mirror_build")
 
     def _fresh_delta(self) -> _DeltaIndex:
         """A delta index bound to the CURRENT mirror's stored domain, so
@@ -1028,8 +1055,14 @@ class TpuScanner(Scanner):
             # retry/backoff/escalation machinery must recover
             raise RuntimeError("injected merge failure (fault plane)")
         with self._merge_lock:
+            # three phases that tile [t0, dt] (kb_mirror_merge_phase_seconds,
+            # kb.merge.<phase> on the profiler's clock): snapshot and swap
+            # hold _mlock, which every write needs; build runs off it. Each
+            # locked phase starts with the merger's OWN wait for _mlock,
+            # counted apart (who="merge") so that hold = phase - wait.
             t0 = time.monotonic()
-            with self._mlock:
+            with TRACER.annotate("merge.snapshot"), self._mlock:
+                lock_wait = time.monotonic() - t0
                 if self._force_rebuild or self._mirror is None:
                     self._rebuild_from_store()
                     return
@@ -1038,26 +1071,13 @@ class TpuScanner(Scanner):
             n_rows = len(rows_prefix)
             if n_rows == 0:
                 return
-            ts = self._store.get_timestamp_oracle()
-            m = None
-            full = False
-            if not overflow:
-                delta7 = merge_sorted_stored(blocks)
-                m = merge_partitions_stored(mirror, delta7, self._mesh, ts)
-            if m is None:
-                # full rebuild: re-partition (capacity overflow) or
-                # re-dictionary (EncodeOverflow at seal time) — flat_arrays
-                # decodes to RAW rows, merge there, fresh dictionary sized
-                # to the merged keyspace
-                full = True
-                sorted_delta = merge_sorted_arrays(
-                    rows_to_arrays([], self._kw),
-                    rows_to_arrays(rows_prefix, self._kw))
-                merged = merge_sorted_arrays(mirror.flat_arrays(), sorted_delta)
-                m = build_mirror_from_arrays(*merged, self._mesh, self._kw, ts,
-                                             n_parts=self._partitions or None,
-                                             encode=self._encode)
-            with self._mlock:
+            t_build = time.monotonic()
+            with TRACER.annotate("merge.build"):
+                m, full = self._build_merged(mirror, blocks, rows_prefix,
+                                             overflow)
+            t_swap = time.monotonic()
+            with TRACER.annotate("merge.swap"), self._mlock:
+                lock_wait += time.monotonic() - t_swap
                 if self._mirror is not mirror:
                     # superseded mid-merge (uncertainty rebuild / compact):
                     # the fresher mirror came straight from the store —
@@ -1075,7 +1095,8 @@ class TpuScanner(Scanner):
                 # publish()'s empty-delta fast path returns under _mlock
                 # without touching _merge_lock, so anyone who observed the
                 # merged (empty) delta must also observe these counters
-                dt = time.monotonic() - t0
+                t_end = time.monotonic()
+                dt = t_end - t0
                 self.merge_count += 1
                 if full:
                     self.full_rebuild_total += 1
@@ -1085,9 +1106,37 @@ class TpuScanner(Scanner):
                 self._metrics.emit_histogram(
                     "kb.mirror.merge.seconds", dt,
                     kind="full_rebuild" if full else "incremental")
+                for phase, seconds in (("snapshot", t_build - t0),
+                                       ("build", t_swap - t_build),
+                                       ("swap", t_end - t_swap)):
+                    self._metrics.emit_histogram(
+                        "kb.mirror.merge.phase.seconds", seconds, phase=phase)
+                self._metrics.emit_counter("kb.mirror.lock.wait.seconds",
+                                           lock_wait, who="merge")
                 if not full:
                     self._metrics.emit_counter(
                         "kb.mirror.merge.rows.total", n_rows)
+
+    def _build_merged(self, mirror: Mirror, blocks, rows_prefix,
+                      overflow: bool) -> tuple[Mirror, bool]:
+        """The merge's build phase, off ``_mlock``: ``(successor mirror,
+        whether it took the full rebuild)``."""
+        ts = self._store.get_timestamp_oracle()
+        if not overflow:
+            delta7 = merge_sorted_stored(blocks)
+            m = merge_partitions_stored(mirror, delta7, self._mesh, ts)
+            if m is not None:
+                return m, False
+        # full rebuild: re-partition (capacity overflow) or re-dictionary
+        # (EncodeOverflow at seal time) — flat_arrays decodes to RAW rows,
+        # merge there, fresh dictionary sized to the merged keyspace
+        sorted_delta = merge_sorted_arrays(
+            rows_to_arrays([], self._kw),
+            rows_to_arrays(rows_prefix, self._kw))
+        merged = merge_sorted_arrays(mirror.flat_arrays(), sorted_delta)
+        return build_mirror_from_arrays(*merged, self._mesh, self._kw, ts,
+                                        n_parts=self._partitions or None,
+                                        encode=self._encode), True
 
     def publish(self) -> None:
         """Force the mirror fully up to date (bench/startup hook)."""
@@ -1289,24 +1338,32 @@ class TpuScanner(Scanner):
             # quarantined/rebuilding mirror: serve from the authoritative
             # host store (the differential oracle — byte-identical)
             return Scanner.range_(self, start, end, read_revision, limit)
-        self._snapshot_checked(read_revision)
-        self._ensure_published()
-        with self._mlock:
-            mirror = self._mirror
-            overlay = self._delta.overlay(start, end, read_revision)
-        # device-time attribution: dispatch = query assembly + async kernel
-        # enqueue; compute = the first blocking device transfer (counts +
-        # index pull, which waits out the kernel); host_copy = row
-        # materialization + overlay merge on the host. device=True feeds
-        # the auto-depth RTT EWMAs — only this engine's kernel path does.
-        with TRACER.stage("device_dispatch", device=True):
+        # attribution: delta_overlay = the delta on the read path (publish
+        # check, the wait for the writers' lock, the overlay under it);
+        # dispatch = query assembly + async kernel enqueue; compute = the
+        # first blocking device transfer (counts + index pull, which waits
+        # out the kernel); host_copy = row materialization + overlay merge
+        # on the host. Only this engine's kernel path records the device_*
+        # stages, so their EWMAs are the auto-depth dispatch RTT.
+        with TRACER.stage("delta_overlay"):
+            self._snapshot_checked(read_revision)
+            self._ensure_published()
+            with self._mlock:
+                mirror = self._mirror
+                overlay = self._delta.overlay(start, end, read_revision)
+        with TRACER.stage("device_dispatch"):
             mask, counts = self._dev_mask(mirror, start, end, read_revision)
-        with TRACER.stage("device_compute", device=True):
+        with TRACER.stage("device_compute"):
             total, idx = self._dev_visible_indices(
                 mask, counts, mirror.keys_host.shape[1]
             )
         with TRACER.stage("host_copy"):
             kvs = self._materialize_visible(mirror, idx, overlay)
+            # the read's device arrays go here, inside the stage: dropping
+            # them gives up the GIL, and under three listers getting it
+            # back took ~1 ms on average (42 ms at worst) that no stage
+            # showed (kb_rpc_unaccounted_seconds over 2 ms, PR 26)
+            del mask, counts
         if limit:
             return kvs[:limit], len(kvs) > limit
         return kvs, False
@@ -1365,13 +1422,14 @@ class TpuScanner(Scanner):
             except Exception as e:
                 out[i] = e
             return out
-        self._ensure_published()
-        with self._mlock:
-            mirror = self._mirror
-            overlays = [
-                self._delta.overlay(s[1], s[2], s[3]) for _, s in device
-            ]
-        with TRACER.stage("device_dispatch", device=True):
+        with TRACER.stage("delta_overlay"):
+            self._ensure_published()
+            with self._mlock:
+                mirror = self._mirror
+                overlays = [
+                    self._delta.overlay(s[1], s[2], s[3]) for _, s in device
+                ]
+        with TRACER.stage("device_dispatch"):
             mask, counts = self._dev_mask_batch(
                 mirror, [(s[1], s[2], s[3]) for _, s in device])
             sel = np.zeros(int(mask.shape[0]), dtype=bool)
@@ -1384,7 +1442,7 @@ class TpuScanner(Scanner):
         n_parts = int(mask.shape[1])
         stride = n_parts * n_rows
         idx = np.empty(0, dtype=np.int64)
-        with TRACER.stage("device_compute", device=True):
+        with TRACER.stage("device_compute"):
             counts_h = _host_pull(counts)  # blocks on the kernel; [Qpad, P]
             want = int(counts_h[sel].max()) if sel.any() else 0
             if want:
@@ -1418,6 +1476,7 @@ class TpuScanner(Scanner):
                     mirror, idx[lo:hi] - k * stride, overlays[k])
                 limit = spec[4]
                 out[qi] = (kvs[:limit], len(kvs) > limit) if limit else (kvs, False)
+            del mask, counts  # released inside a stage, as in range_
         return out
 
     def range_stream(self, start: bytes, end: bytes, read_revision: int, batch_size: int = 300):
@@ -1487,16 +1546,21 @@ class TpuScanner(Scanner):
     def count(self, start: bytes, end: bytes, read_revision: int) -> int:
         if self._degraded():
             return Scanner.count(self, start, end, read_revision)
-        self._snapshot_checked(read_revision)
-        self._ensure_published()
-        with self._mlock:
-            mirror = self._mirror
-            overlay = self._delta.overlay(start, end, read_revision)
-        with TRACER.stage("device_dispatch", device=True):
-            _, counts = self._dev_mask(mirror, start, end, read_revision)
-        with TRACER.stage("device_compute", device=True):
+        with TRACER.stage("delta_overlay"):
+            self._snapshot_checked(read_revision)
+            self._ensure_published()
+            with self._mlock:
+                mirror = self._mirror
+                overlay = self._delta.overlay(start, end, read_revision)
+        with TRACER.stage("device_dispatch"):
+            mask, counts = self._dev_mask(mirror, start, end, read_revision)
+        with TRACER.stage("device_compute"):
             total = int(_host_pull(counts).sum())
-        return self._overlay_corrected_count(mirror, total, overlay, read_revision)
+            del mask, counts  # released inside a stage, as in range_
+        # the same stage again: one observation per RPC (Tracer.finish)
+        with TRACER.stage("delta_overlay"):
+            return self._overlay_corrected_count(mirror, total, overlay,
+                                                 read_revision)
 
     def _overlay_corrected_count(self, mirror: Mirror, total: int, overlay,
                                  read_rev: int) -> int:

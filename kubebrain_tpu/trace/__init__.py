@@ -6,23 +6,42 @@ time slices of the serving path:
     endpoint_recv    request decode + peer revision sync (service terminal)
     queue_wait       scheduler admission: enqueue -> worker pickup
     coalesce_join    follower attached to a coalesced leader's execution
+    batch_join       rider of a batch leader's one dispatch / commit group
+    delta_overlay    the TPU engine's delta on the read path: publish check,
+                     the wait for the writers' lock, the overlay taken under
+                     it, a Count's overlay correction
     device_dispatch  building + enqueuing the device kernel (async dispatch)
-    device_compute   device busy time, timed across ``block_until_ready`` /
-                     the first blocking transfer off the device
+    device_compute   the host's wait for the device, timed across
+                     ``block_until_ready`` / the first blocking transfer
+    host_scan        the host engine's iteration (generic scanner, small
+                     pages, the C wire encoder): no device involved
     host_copy        materializing rows on the host (overlay merge, sort)
     result_deliver   worker completion -> waiter wakeup (sched handoff)
     response_encode  building the wire response
     backend_write    Txn write path (create/update/delete)
 
-Spans land in a bounded in-memory ring (`/debug/traces`), slow requests
-additionally in a slow-request log (``--trace-slow-ms``), and every stage
-duration is emitted as the ``kb_rpc_stage_seconds{stage=...}`` histogram so
-per-stage time shows up on ``/metrics`` next to the sched gauges.
+One emission point, three sinks. A finished span lands in a bounded
+in-memory ring (``/debug/traces``; slow requests additionally in a
+slow-request log, ``--trace-slow-ms``), and on ``/metrics`` as one
+``kb_rpc_stage_seconds{stage=,rpc=}`` observation per stage the RPC spent
+time in (``rpc`` = the span's name, ``""`` for a spanless stage) plus one
+``kb_rpc_unaccounted_seconds{rpc=}`` observation: the span's duration minus
+the union of its stages, i.e. the promise "stages account for the latency",
+measured. The third sink is the profiler: ``stage()`` also enters an
+annotation ``kb.<name>``, so during a ``jax.profiler`` capture every stage a
+thread is DOING lies in the ``/host:CPU`` plane on the profiler's clock,
+beside the device's ops. This package imports no JAX: the TPU engine hands
+the annotation factory over (``set_annotator``); without it the sink is
+absent. Stages recorded after the fact from two timestamps (``queue_wait``,
+``result_deliver``, ``coalesce_join``, ``batch_join``) are waits no thread
+does and get no annotation. ``annotate(name)`` gives background work outside
+any RPC (merge, compaction, boot) the annotation alone.
 
 The tracer also keeps per-stage EWMAs; ``dispatch_rtt()`` (device_dispatch
-+ device_compute) is the measured device round trip the scheduler uses to
-size its pipeline depth when ``--sched-depth 0`` (auto) is configured —
-the ROADMAP "size --sched-depth from the measured dispatch RTT" lever.
++ device_compute, which only the TPU engine's kernel path records) is the
+measured device round trip the scheduler uses to size its pipeline depth
+when ``--sched-depth 0`` (auto) is configured — the ROADMAP "size
+--sched-depth from the measured dispatch RTT" lever.
 
 Trace context propagates as a W3C ``traceparent`` header
 (``00-<trace_id>-<span_id>-01``) in gRPC metadata: client.py injects it,
@@ -60,6 +79,10 @@ _TRACEPARENT_RE = re.compile(
 
 #: histogram fed by every completed stage (prom: kb_rpc_stage_seconds)
 STAGE_METRIC = "kb.rpc.stage.seconds"
+#: histogram of what a span's stages leave out (kb_rpc_unaccounted_seconds)
+UNACCOUNTED_METRIC = "kb.rpc.unaccounted.seconds"
+#: profiler annotations are named kb.<stage> / kb.<background work>
+ANNOTATION_PREFIX = "kb."
 
 
 def _gen_id(nbytes: int) -> str:
@@ -152,18 +175,13 @@ class Tracer:
         self.slow_ms = slow_ms
         self.metrics = metrics
         self._ewma: dict[str, float] = {}
-        # device-sourced EWMAs only (record_stage(..., device=True)): the
-        # auto-depth divisor. Host-path scans (generic scanner, the TPU
-        # engine's small-limit host fallback) report the same *stage names*
-        # for uniform traces but must not shrink the compute EWMA — a
-        # µs-scale host scan in the divisor would pin auto depth at the
-        # clamp ceiling and oversubscribe the device queue.
-        self._rtt: dict[str, float] = {}
         self._ewma_alpha = 0.2
-        # KB_TRACE=0 turns span *recording* off (stage histograms still emit
-        # when metrics are configured); default on — the per-RPC cost is a
-        # few monotonic() reads and list appends
-        self.enabled = os.environ.get("KB_TRACE", "1") != "0"
+        # name -> context manager on the profiler's clock
+        # (jax.profiler.TraceAnnotation), handed over by the TPU engine
+        self._annotate: Any = None
+        # False turns span *recording* off (stage histograms and EWMAs
+        # still update); the server never does, tests do
+        self.enabled = True
 
     # ------------------------------------------------------------ configure
     def configure(self, metrics: Any = None, slow_ms: float | None = None,
@@ -176,13 +194,18 @@ class Tracer:
             with self._lock:
                 self._ring = deque(self._ring, maxlen=capacity)
 
+    def set_annotator(self, factory: Any) -> None:
+        """The profiler sink: ``factory(name)`` is a context manager that
+        puts a span on the profiler's clock while a capture runs and costs
+        one flag test when none does."""
+        self._annotate = factory
+
     def reset(self) -> None:
         """Drop recorded traces and EWMAs (tests / bench isolation)."""
         with self._lock:
             self._ring.clear()
             self._slow.clear()
             self._ewma = {}
-            self._rtt = {}
 
     # ---------------------------------------------------------------- spans
     def current(self) -> Span | None:
@@ -236,9 +259,21 @@ class Tracer:
             # span-attached stage histograms are emitted here, once, after
             # the clock stops: an inline prometheus observe per stage
             # boundary costs ~tens of µs that would show up as unattributed
-            # time *inside* the span (and as tracing overhead on the bench)
-            for name, _off, dur in list(span.stages):
-                m.emit_histogram(STAGE_METRIC, dur, stage=name)
+            # time *inside* the span (and as tracing overhead on the bench).
+            # One observation per stage NAME: a stage entered twice (a
+            # Count's delta_overlay, before and after the kernel) is one
+            # share of this RPC, so a stage's mean is per RPC that had it
+            # and the means of one kind of RPC add up to its duration.
+            stages = list(span.stages)
+            per_stage: dict[str, float] = {}
+            for name, _off, dur in stages:
+                per_stage[name] = per_stage.get(name, 0.0) + dur
+            for name, dur in per_stage.items():
+                m.emit_histogram(STAGE_METRIC, dur, stage=name, rpc=span.name)
+            m.emit_histogram(
+                UNACCOUNTED_METRIC,
+                max(0.0, span.duration - _covered(stages, span.duration)),
+                rpc=span.name)
         with self._lock:
             self._ring.append(span)
             slow = self.slow_ms and span.duration * 1e3 >= self.slow_ms
@@ -255,12 +290,24 @@ class Tracer:
 
     # --------------------------------------------------------------- stages
     @contextlib.contextmanager
-    def stage(self, name: str, device: bool = False) -> Iterator[None]:
+    def stage(self, name: str) -> Iterator[None]:
+        """A stage this thread is doing: recorded on the ambient span and,
+        during a profiler capture, an annotation ``kb.<name>``."""
         t0 = time.monotonic()
         try:
-            yield
+            with self.annotate(name):
+                yield
         finally:
-            self.record_stage(name, t0, time.monotonic(), device=device)
+            self.record_stage(name, t0, time.monotonic())
+
+    def annotate(self, name: str) -> Any:
+        """The profiler annotation ``kb.<name>`` alone, for background work
+        outside any RPC (merge phases, compaction, boot); a no-op context
+        without the profiler sink."""
+        annotate = self._annotate
+        if annotate is None:
+            return contextlib.nullcontext()
+        return annotate(ANNOTATION_PREFIX + name)
 
     #: a stage whose start trails the previous stage's end by less than this
     #: is glued to it — instrumentation/transition overhead between stages
@@ -270,13 +317,11 @@ class Tracer:
     GAP_GLUE_S = 0.0005
 
     def record_stage(self, name: str, t0: float, t1: float,
-                     span: Span | None = None, device: bool = False) -> None:
+                     span: Span | None = None) -> None:
         """Record one ``[t0, t1]`` monotonic interval as stage ``name`` on
         ``span`` (default: the ambient span), feed the stage histogram
         (immediately when spanless; at span finish otherwise), and update
-        the stage EWMA. ``device=True`` marks a genuinely device-timed
-        interval: only those feed the dispatch-RTT EWMAs auto-depth divides
-        by. Callable from any thread."""
+        the stage EWMA. Callable from any thread."""
         dur = max(0.0, t1 - t0)
         sp = span if span is not None else _SPAN.get()
         if sp is not None and self.enabled:
@@ -290,7 +335,7 @@ class Tracer:
         else:
             m = self.metrics
             if m is not None:
-                m.emit_histogram(STAGE_METRIC, dur, stage=name)
+                m.emit_histogram(STAGE_METRIC, dur, stage=name, rpc="")
         # EWMA update is a read-modify-write racing every worker thread
         # (and reset()'s dict swap, which holds _lock): unguarded, two
         # concurrent stages lose updates and a racing reset resurrects
@@ -300,29 +345,19 @@ class Tracer:
             self._ewma[name] = (
                 dur if prev is None else prev + self._ewma_alpha * (dur - prev)
             )
-            if device:
-                prev = self._rtt.get(name)
-                self._rtt[name] = (
-                    dur if prev is None
-                    else prev + self._ewma_alpha * (dur - prev)
-                )
 
     # ---------------------------------------------------------------- ewmas
     def ewma(self, stage: str) -> float | None:
         with self._lock:
             return self._ewma.get(stage)
 
-    def device_ewma(self, stage: str) -> float | None:
-        """EWMA over device-marked observations only (auto-depth inputs)."""
-        with self._lock:
-            return self._rtt.get(stage)
-
     def dispatch_rtt(self) -> float | None:
-        """EWMA of the device dispatch round trip (dispatch + compute),
-        fed exclusively by device-marked stages; None until the device
-        engine has been observed (pure host deployments never set it)."""
+        """EWMA of the device dispatch round trip (dispatch + compute).
+        Only the TPU engine's kernel path records those two stages (the
+        host scanner's iteration is ``host_scan``); None until it has been
+        observed (pure host deployments never set it)."""
         with self._lock:
-            vals = [self._rtt[s] for s in self.RTT_STAGES if s in self._rtt]
+            vals = [self._ewma[s] for s in self.RTT_STAGES if s in self._ewma]
         return sum(vals) if vals else None
 
     # ------------------------------------------------------------- snapshot
@@ -340,6 +375,19 @@ class Tracer:
             "stage_ewma_seconds": {k: round(v, 9) for k, v in ewma.items()},
             "dispatch_rtt_seconds": round(rtt, 9) if rtt is not None else None,
         }
+
+
+def _covered(stages: list[tuple[str, float, float]], duration: float) -> float:
+    """Seconds of ``[0, duration]`` that the union of the stage intervals
+    covers: stages overlap (``backend_write`` wraps the write's
+    ``queue_wait``), so their sum would count time twice."""
+    total, edge = 0.0, 0.0
+    for off, end in sorted((off, off + dur) for _n, off, dur in stages):
+        end = min(end, duration)
+        if end > edge:
+            total += end - max(off, edge)
+            edge = end
+    return total
 
 
 def emit_histogram(name: str, value: float, **tags: Any) -> None:

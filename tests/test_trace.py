@@ -107,28 +107,28 @@ def test_disabled_tracer_records_nothing():
 def test_stage_ewma_and_dispatch_rtt():
     t = Tracer()
     assert t.dispatch_rtt() is None
-    t.record_stage("device_dispatch", 0.0, 0.30, device=True)
-    t.record_stage("device_compute", 0.0, 0.10, device=True)
+    t.record_stage("device_dispatch", 0.0, 0.30)
+    t.record_stage("device_compute", 0.0, 0.10)
     rtt = t.dispatch_rtt()
     assert rtt == pytest.approx(0.40)
     # EWMA converges toward repeated observations
     for _ in range(50):
-        t.record_stage("device_compute", 0.0, 0.50, device=True)
+        t.record_stage("device_compute", 0.0, 0.50)
     assert t.ewma("device_compute") == pytest.approx(0.50, rel=0.05)
 
 
 def test_host_stages_do_not_feed_dispatch_rtt():
-    """Host-path scans share the stage names (uniform traces) but must not
-    shrink the auto-depth divisor: only device-marked records count."""
+    """A host scan is ``host_scan``, a stage of its own: it must not shrink
+    the auto-depth divisor, which only the device_* stages form."""
     t = Tracer()
-    t.record_stage("device_compute", 0.0, 0.000005)  # µs host scan
-    t.record_stage("device_dispatch", 0.0, 0.000001)
+    t.record_stage("host_scan", 0.0, 0.000005)  # µs host scan
+    t.record_stage("host_copy", 0.0, 0.000001)
     assert t.dispatch_rtt() is None
-    assert t.device_ewma("device_compute") is None
-    t.record_stage("device_compute", 0.0, 0.02, device=True)
-    assert t.device_ewma("device_compute") == pytest.approx(0.02)
-    # the name-keyed EWMA (trace breakdowns) still sees both
-    assert t.ewma("device_compute") is not None
+    assert t.ewma("device_compute") is None
+    t.record_stage("device_compute", 0.0, 0.02)
+    assert t.dispatch_rtt() == pytest.approx(0.02)
+    # the name-keyed EWMA (trace breakdowns) still sees the host scan
+    assert t.ewma("host_scan") is not None
 
 
 # ------------------------------------------------------------- auto depth
@@ -146,10 +146,8 @@ def test_auto_depth_adapts_to_synthetic_slow_dispatch():
             def fn():
                 # synthetic device timings recorded through the real
                 # execution path (worker thread, ambient span handling)
-                TRACER.record_stage("device_dispatch", 0.0, dispatch_s,
-                                    device=True)
-                TRACER.record_stage("device_compute", 0.0, compute_s,
-                                    device=True)
+                TRACER.record_stage("device_dispatch", 0.0, dispatch_s)
+                TRACER.record_stage("device_compute", 0.0, compute_s)
                 return True
 
             return fn
@@ -176,8 +174,8 @@ def test_auto_depth_adapts_to_synthetic_slow_dispatch():
 def test_fixed_depth_ignores_tracer():
     TRACER.reset()
     try:
-        TRACER.record_stage("device_dispatch", 0.0, 30.0, device=True)
-        TRACER.record_stage("device_compute", 0.0, 0.1, device=True)
+        TRACER.record_stage("device_dispatch", 0.0, 30.0)
+        TRACER.record_stage("device_compute", 0.0, 0.1)
         sched = RequestScheduler(None, SchedConfig(depth=3))
         assert sched.current_depth() == 3
         sched.close()
@@ -256,14 +254,15 @@ def test_range_trace_stages_sum_to_latency(server):
     assert span["parent_id"] == parse_traceparent(tp)[1]
     stages = {s["stage"] for s in span["stages"]}
     assert len(stages) >= 5, span
-    assert {"endpoint_recv", "queue_wait", "device_compute",
+    assert {"endpoint_recv", "queue_wait", "host_scan",
             "host_copy", "response_encode"} <= stages
+    assert not stages & {"device_dispatch", "device_compute"}  # no device here
     total = sum(s["duration_ms"] for s in span["stages"])
     assert total == pytest.approx(span["duration_ms"], rel=0.10), span
 
 
 def test_stage_histogram_on_metrics(server):
-    """queue-wait and device-compute appear in kb_rpc_stage_seconds on
+    """queue-wait and the host scan appear in kb_rpc_stage_seconds on
     /metrics (alongside the sched gauges + the new depth/RTT gauges)."""
     client, _port, info_port = server
     client.range_(rpc_pb2.RangeRequest(
@@ -271,7 +270,8 @@ def test_stage_histogram_on_metrics(server):
     body = _http_text(info_port, "/metrics")
     assert 'kb_rpc_stage_seconds_bucket{' in body
     assert 'stage="queue_wait"' in body
-    assert 'stage="device_compute"' in body
+    assert 'stage="host_scan"' in body
+    assert 'stage="device_compute"' not in body
     assert "kb_sched_depth" in body
     assert "kb_sched_dispatch_rtt_seconds" in body
 
@@ -319,7 +319,7 @@ def test_slow_request_log_via_wire(server):
     snap = _http_json(info_port, "/debug/traces")
     assert snap["slow_ms"] == 10000
     assert snap["slow"] == []
-    assert snap["stage_ewma_seconds"].get("device_compute") is not None
+    assert snap["stage_ewma_seconds"].get("host_scan") is not None
 
 
 def test_debug_profile_on_demand(server):
@@ -328,11 +328,11 @@ def test_debug_profile_on_demand(server):
     # the first start_trace of a process initializes the XLA profiler
     # plugin (~15s in this container); later captures take ~the capture time
     out = _http_json(info_port, "/debug/profile?seconds=0.05", timeout=90)
-    assert "trace_dir" in out, out
+    assert "dir" in out, out
     assert out["seconds"] == pytest.approx(0.05)
     import os
 
-    assert os.path.isdir(out["trace_dir"])
+    assert os.path.isdir(out["dir"])
     # malformed query answers with a JSON error, not a 500
     out = _http_json(info_port, "/debug/profile?seconds=bogus")
     assert "error" in out

@@ -74,7 +74,7 @@ def main() -> int:
             print(f"FAIL: no Range span in /debug/traces: {snap}", file=sys.stderr)
             return 1
         stages = {s["stage"] for s in ranges[-1]["stages"]}
-        if len(stages) < 5 or not {"queue_wait", "device_compute"} <= stages:
+        if len(stages) < 5 or not {"queue_wait", "host_scan"} <= stages:
             print(f"FAIL: Range span stages incomplete: {sorted(stages)}",
                   file=sys.stderr)
             return 1
@@ -84,7 +84,7 @@ def main() -> int:
         ) as resp:
             metrics = resp.read().decode()
         for needle in ("kb_rpc_stage_seconds_bucket",
-                       'stage="queue_wait"', 'stage="device_compute"'):
+                       'stage="queue_wait"', 'stage="host_scan"'):
             if needle not in metrics:
                 print(f"FAIL: {needle!r} missing from /metrics", file=sys.stderr)
                 return 1
