@@ -174,26 +174,55 @@ def rows_to_arrays(rows: list[tuple[bytes, int, bytes]], width: int):
     return keys_u8, lens, revs, tomb, arena, offsets
 
 
+def _order_void(keys_u8: np.ndarray, revs: np.ndarray) -> np.ndarray:
+    """Rows' sort key — key bytes + big-endian revision — as a void scalar
+    per row, so numpy orders and searches rows by memcmp."""
+    n = len(keys_u8)
+    rev_be = revs[:, None].astype(">u8").view(np.uint8).reshape(n, 8)
+    return keyops.u8_void(np.concatenate([keys_u8, rev_be], axis=1))
+
+
+def _take_rows(block: tuple, perm: np.ndarray) -> tuple:
+    """Rows ``perm`` of a row-array tuple, values carried along."""
+    *cols, arena, offsets = block
+    return (*(c[perm] for c in cols),
+            *keyops.gather_arena(arena, offsets, perm))
+
+
+def sort_arrays(block: tuple) -> tuple:
+    """Sort one row-array tuple (commit order, as the delta records it) by
+    (key, revision): the stable argsort the merges below never need."""
+    return _take_rows(
+        block, np.argsort(_order_void(block[0], block[2]), kind="stable"))
+
+
 def _merge_sorted_blocks(blocks: list[tuple]) -> tuple:
-    """k-way merge of row-array tuples sorted by (key, revision).
+    """k-way merge of row-array tuples, each sorted by (key, revision).
 
     Each block is ``(keys_u8[n, W], *columns, arena, offsets)`` — any
     number of row-aligned 1-D columns between the key matrix and the
     value arena. Sort key = key bytes + big-endian revision (the column
-    right after the keys), compared as a void scalar (memcmp) — a single
-    numpy argsort, no Python comparisons. Shared by the raw-domain
-    :func:`merge_sorted_arrays` and the stored-domain
-    :func:`merge_sorted_stored` so the two merge paths cannot diverge."""
-    ncols = len(blocks[0]) - 3  # columns between keys and arena
-    keys_u8 = np.concatenate([b[0] for b in blocks])
-    cols = [np.concatenate([b[1 + c] for b in blocks]) for c in range(ncols)]
-    revs = cols[1]  # (keys, lens, revs, ...) in every caller
-    n, w = keys_u8.shape
-    rev_be = revs[:, None].astype(">u8").view(np.uint8).reshape(n, 8)
-    sort_rows = np.ascontiguousarray(np.concatenate([keys_u8, rev_be], axis=1))
-    void = sort_rows.view([("v", f"V{w + 8}")]).reshape(n)
-    perm = np.argsort(void, kind="stable")
-    # merge arenas (rebase each block's offsets), then reorder by perm
+    right after the lens), compared as a void scalar (memcmp). Sorted
+    inputs are never re-sorted: block after block is PLACED into the
+    merged order by one binary search per row of the incoming block
+    (equal rows keep the earlier block's first, as a stable sort over the
+    concatenation would), so merging a small delta into a large partition
+    costs the delta's searches plus memcpy-class takes, and the values
+    move as the few runs :func:`keyops.gather_arena` finds in the merged
+    order. Shared by the raw-domain :func:`merge_sorted_arrays` and the
+    stored-domain :func:`merge_sorted_stored` so the two merge paths
+    cannot diverge."""
+    ncols = len(blocks[0]) - 2  # keys + columns, before the arena
+    cols = [np.concatenate([b[c] for b in blocks]) for c in range(ncols)]
+    void = _order_void(cols[0], cols[2])  # (keys, lens, revs, ...) everywhere
+    ends = np.cumsum([len(b[0]) for b in blocks]).tolist()
+    perm = np.arange(ends[0])
+    for lo, hi in zip(ends[:-1], ends[1:]):
+        # until a second block has rows, perm is the identity over the first
+        merged = void[:lo] if lo == ends[0] else void[perm]
+        at = np.searchsorted(merged, void[lo:hi], side="right")
+        perm = np.insert(perm, at, np.arange(lo, hi))
+    # one arena (each block's offsets rebased), then the rows by perm
     arena = np.concatenate([b[-2] for b in blocks])
     bases = np.cumsum([0] + [len(b[-2]) for b in blocks[:-1]]).astype(np.int64)
     offsets = np.concatenate(
@@ -201,8 +230,7 @@ def _merge_sorted_blocks(blocks: list[tuple]) -> tuple:
          for b, base in zip(blocks, bases)]
         + [np.array([len(arena)], dtype=np.int64)]
     ).astype(np.uint64)
-    new_arena, new_offsets = keyops.gather_arena(arena, offsets, perm)
-    return (keys_u8[perm], *(c[perm] for c in cols), new_arena, new_offsets)
+    return _take_rows((*cols, arena, offsets), perm)
 
 
 def merge_sorted_arrays(a, b):
@@ -393,7 +421,7 @@ def merge_sorted_stored(blocks: list[tuple]) -> tuple:
     raw packed bytes for a raw mirror, dictionary-encoded rows for an
     encoded one. Encoded lexicographic order equals raw byte order
     (storage/tpu/encode.py order preservation) and the encoding is
-    injective, so ONE void argsort over ``key || rev_be`` merges encoded
+    injective, so the memcmp order of ``key || rev_be`` merges encoded
     blocks as exactly as raw ones — the k-way merge of the write-path
     delta blocks (docs/writes.md). Shares :func:`_merge_sorted_blocks`
     with the raw-domain :func:`merge_sorted_arrays` so the two merge
@@ -415,11 +443,13 @@ def merge_partitions_stored(
     The delta rows arrive already encoded against the published dictionary
     (sealed at write time, PR 9's incremental re-encode moved off the merge
     path), so a dirty partition merges by pure byte interleave: no
-    partition decode, no raw-domain merge, no re-encode — per-merge host
-    work is O(delta + dirty-partition memcpy). TTL flags ride the delta
-    column and the mirror's host TTL column, so the merge never touches the
-    device except for the dirty-shard-only republish
-    (:func:`_assemble_sharded`, PR 7 machinery).
+    partition decode, no raw-domain merge, no re-encode, no re-sort — the
+    delta's rows are placed by binary search and the partition's columns
+    and value arena move around them in runs, so per-merge host work is
+    O(delta x log partition + dirty-partition memcpy), values included.
+    TTL flags ride the delta column and the mirror's host TTL column, so
+    the merge never touches the device except for the dirty-shard-only
+    republish (:func:`_assemble_sharded`, PR 7 machinery).
 
     A partition outgrowing its padded capacity does NOT force the full
     decode → re-dictionary → re-partition host rebuild: the stored-domain
@@ -459,7 +489,8 @@ def merge_partitions_stored(
     # row_part is non-decreasing (sorted delta routed through sorted
     # firsts), so each dirty partition owns ONE contiguous delta slice —
     # locate every slice with two binary searches instead of a full-delta
-    # boolean scan per partition (this runs in the merge critical section)
+    # boolean scan per partition (the build phase: off _mlock, but a merge
+    # is not published until it ends)
     dirty = np.unique(row_part).tolist()
     part_lo = np.searchsorted(row_part, np.asarray(dirty), side="left")
     part_hi = np.searchsorted(row_part, np.asarray(dirty), side="right")

@@ -52,6 +52,7 @@ from .blocks import (
     merge_sorted_arrays,
     merge_sorted_stored,
     rows_to_arrays,
+    sort_arrays,
 )
 from .encode import EncodeOverflow
 
@@ -106,9 +107,8 @@ class _DeltaIndex:
         """Sort one run and move it into the mirror's stored domain. Sealing
         amortizes over writes (one small argsort + encode per ``seal_rows``
         rows) so merge time pays only the k-way interleave."""
-        raw = rows_to_arrays(rows, self._width)
-        k, l, r, t, arena, off = merge_sorted_arrays(
-            rows_to_arrays([], self._width), raw)
+        k, l, r, t, arena, off = sort_arrays(
+            rows_to_arrays(rows, self._width))
         ttl = compute_ttl_flags(k, l)
         if self._encoding is not None and not self._overflow:
             try:
@@ -1130,9 +1130,7 @@ class TpuScanner(Scanner):
         # full rebuild: re-partition (capacity overflow) or re-dictionary
         # (EncodeOverflow at seal time) — flat_arrays decodes to RAW rows,
         # merge there, fresh dictionary sized to the merged keyspace
-        sorted_delta = merge_sorted_arrays(
-            rows_to_arrays([], self._kw),
-            rows_to_arrays(rows_prefix, self._kw))
+        sorted_delta = sort_arrays(rows_to_arrays(rows_prefix, self._kw))
         merged = merge_sorted_arrays(mirror.flat_arrays(), sorted_delta)
         return build_mirror_from_arrays(*merged, self._mesh, self._kw, ts,
                                         n_parts=self._partitions or None,
@@ -2130,8 +2128,7 @@ class TpuScanner(Scanner):
         arena, offsets = keyops.gather_arena(flat[4], flat[5], ki)
         surv = (flat[0][ki], flat[1][ki], flat[2][ki], flat[3][ki],
                 arena, offsets)
-        sorted_delta = merge_sorted_arrays(
-            rows_to_arrays([], self._kw), rows_to_arrays(rows_prefix, self._kw))
+        sorted_delta = sort_arrays(rows_to_arrays(rows_prefix, self._kw))
         merged = merge_sorted_arrays(surv, sorted_delta)
         return build_mirror_from_arrays(
             *merged, self._mesh, self._kw, ts,
