@@ -56,6 +56,7 @@ import etcd  # noqa: E402
 import mergephase  # noqa: E402
 import plugin  # noqa: E402
 import prom  # noqa: E402
+import witness  # noqa: E402
 from state import State  # noqa: E402
 
 BOOT_TIMEOUT_S = 600.0
@@ -298,6 +299,7 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
     workers: list[Worker] = []
     stub = None
     trace_thread = None
+    machine = witness.Witness()
     on_server = opts.sut != "reference"
     try:
         t0 = time.monotonic()
@@ -352,6 +354,9 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
                 break
             if now - begin > 900.0:
                 raise RunFailure("the warm-up never went quiet")
+        # from before the window's first instant until every request that
+        # was due in it is answered: this process only sleeps meanwhile
+        machine.start()
         before = prom.scrape(server.info_port) if server.info_port else None
         t_before = time.monotonic()
         entries_t0 = cache_entries()
@@ -392,13 +397,14 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
 
         # drain: every request that was due is waited for, and judged by
         # what it says
-        traffic, watchers = [], []
-        for w in workers:
-            if "watch" in w.spec["stream"]:
-                watchers.append(w)
-            else:
-                w.expect("done", 200.0)
-                traffic.append(w.result())
+        watchers = [w for w in workers if "watch" in w.spec["stream"]]
+        senders = [w for w in workers if w not in watchers]
+        for w in senders:
+            w.expect("done", 200.0)
+        # stopped before this process does work of its own again (a long
+        # unpickling would hold the witness's thread as a pause would)
+        machine.stop()
+        traffic = [w.result() for w in senders]
         bad_warm = sum(d["warm_failed"] + d["warm_unsent"] for d in traffic)
         if bad_warm:
             # judged by the comparison (a refused write is writes_refused);
@@ -432,7 +438,7 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
 
         ctx = Context(opts, workload, config, state, traffic, watch_dumps,
                       (win0, win1), setup_s, before, after,
-                      entries_t1 - entries_t0, device, rate_scale)
+                      entries_t1 - entries_t0, device, rate_scale, machine)
         if capture:
             log("capture: " + str({k: v for k, v in capture.items()
                                    if k != "scrapes"}))
@@ -479,6 +485,7 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
                              f"{server.log_text()[-6000:]}\n")
         raise
     finally:
+        machine.stop()
         if stub is not None:
             stub.close()
         for w in workers:
@@ -520,7 +527,8 @@ class Context:
     """What a metric's reader may read."""
 
     def __init__(self, opts, workload, config, state, traffic, watches, window,
-                 setup_s, before, after, cache_growth, device, rate_scale=1.0):
+                 setup_s, before, after, cache_growth, device, rate_scale=1.0,
+                 machine=None):
         self.opts, self.workload, self.config, self.state = (
             opts, workload, config, state)
         self.traffic, self.watches, self.window = traffic, watches, window
@@ -528,6 +536,7 @@ class Context:
         self.setup_s, self.before, self.after = setup_s, before, after
         self.cache_growth, self.device = cache_growth, device
         self.rate_scale = rate_scale
+        self.machine = machine or witness.Witness()
         self.trace: dict | None = None
         # rows the mirror holds: the start state's plus every write so far
         self.mirror_rows = state.rows + sum(
@@ -547,6 +556,22 @@ class Context:
                 if (family is None or r[0] == family) and (
                         not due_in_window or lo <= r[2] < hi):
                     yield r
+
+    def watch_pairs(self):
+        """(the write's due time, the event's arrival) for every (event,
+        watcher) pair of the window's acknowledged writes."""
+        due = {r[6]: r[2] for r in self.recs(1, judged_only=False) if r[5]}
+        for dump in self.watches:
+            for w in dump["watches"]:
+                for ev in w["events"]:
+                    if ev[0] in due:
+                        yield due[ev[0]], ev[4]
+
+    def touched(self, a: float, b: float) -> bool:
+        """The pause rule, stated once for every reader: was the machine
+        witnessed pausing (or draining a pause's burst) anywhere in ``[a,
+        b]``, a request's due time and its answer's arrival?"""
+        return witness.touched(self.machine.pauses, a, b)
 
     def merges(self):
         """(counted, fewest designed, most designed) delta merges in the
@@ -602,23 +627,43 @@ def finish(ctx: Context, bench: dict, numbers: dict) -> dict:
 
 
 def summary_lines(ctx: Context) -> list[str]:
-    """The earlier lines: counts and medians, failures by error string, the
-    per-second worst Txn, generator lateness, the merges counted beside the
+    """The earlier lines: each judged family's tails over every request and,
+    beside them, over those no witnessed pause of the machine touched (the
+    pause rule), failures by error string, the per-second worst Txn,
+    generator lateness, the machine's pauses, the merges counted beside the
     designed integer."""
     from stats import percentile
+
+    def tails(lat):
+        return (f"p50={percentile(lat, 50):.2f}ms p75={percentile(lat, 75):.2f}ms "
+                f"p95={percentile(lat, 95):.2f}ms p99={percentile(lat, 99):.2f}ms "
+                f"max={max(lat):.2f}ms")
+
+    def both(label, pairs, rate=""):
+        """One family's line: every request's reading (what is judged) and,
+        beside it, the reading without those a pause touched."""
+        lat = [(b - a) * 1e3 for a, b in pairs]
+        quiet = [(b - a) * 1e3 for a, b in pairs if not ctx.touched(a, b)]
+        return (f"{label}: n={len(lat)}{rate} {tails(lat)}; touched by a pause "
+                f"{len(lat) - len(quiet)}; untouched "
+                + (tails(quiet) if quiet else "nothing"))
 
     out = []
     for fam, label in ((0, "range"), (1, "txn")):
         for judged in (True, False):
-            lat = [(r[4] - r[2]) * 1e3 for d in ctx.traffic
-                   if d["judged"] == judged for r in d["recs"]
-                   if r[0] == fam and r[5] and ctx.window[0] <= r[2] < ctx.window[1]]
-            if lat:
-                out.append(
-                    f"{label}{'' if judged else ' (background)'}: n={len(lat)} "
-                    f"p50={percentile(lat, 50):.2f}ms p95={percentile(lat, 95):.2f}ms "
-                    f"p99={percentile(lat, 99):.2f}ms max={max(lat):.2f}ms "
-                    f"{len(lat) / ctx.window_s:.1f}/s")
+            pairs = [(r[2], r[4]) for d in ctx.traffic
+                     if d["judged"] == judged for r in d["recs"]
+                     if r[0] == fam and r[5] and ctx.window[0] <= r[2] < ctx.window[1]]
+            if pairs and judged:
+                out.append(both(label, pairs,
+                                f" {len(pairs) / ctx.window_s:.1f}/s"))
+            elif pairs:
+                lat = [(b - a) * 1e3 for a, b in pairs]
+                out.append(f"{label} (background): n={len(lat)} {tails(lat)} "
+                           f"{len(lat) / ctx.window_s:.1f}/s")
+    pairs = list(ctx.watch_pairs())
+    if pairs:
+        out.append(both("watch lag (event, watcher) pairs", pairs))
     mid = (ctx.window[0] + ctx.window[1]) / 2
     halves = [[(r[4] - r[2]) * 1e3 for r in ctx.recs(1, loop="open") if r[5]
                and (r[2] < mid) == first] for first in (True, False)]
@@ -648,6 +693,7 @@ def summary_lines(ctx: Context) -> list[str]:
     if late:
         out.append(f"generator lateness (open loops): p50={percentile(late, 50):.3f}ms "
                    f"p95={percentile(late, 95):.3f}ms max={max(late):.3f}ms")
+    out.append(ctx.machine.line(ctx.window))
     counted, lo, hi = ctx.merges()
     rate = mergephase.write_rate(ctx.workload, ctx.rate_scale)
     designed = str(lo) if lo == hi else f"{lo}..{hi}"

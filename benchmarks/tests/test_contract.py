@@ -60,12 +60,12 @@ def test_the_server_is_started_with_the_readme_flags_and_one_more():
 def test_a_new_metric_over_an_existing_reader_is_one_file_and_one_entry(tmp_path, monkeypatch):
     bench = tmp_path / "benchmarks"
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    (bench / "metrics" / "txn_p50_ms.json").write_text(json.dumps(
+    (bench / "metrics" / "txn_median_ms.json").write_text(json.dumps(
         {"reader": "client_percentile", "args": {"family": "txn", "q": 50}}))
     monkeypatch.setattr(run, "HERE", str(bench))
     ctx = SimpleNamespace(recs=lambda fam: [
         (1, "update", 0.0, 0.0, ms / 1e3, True) for ms in (1, 2, 3, 4, 5)])
-    assert run.read_metric("txn_p50_ms", ctx) == 3.0
+    assert run.read_metric("txn_median_ms", ctx) == 3.0
 
 
 def test_a_rate_metric_is_one_file_over_the_reader_that_is_kept_for_it(tmp_path, monkeypatch):
@@ -95,9 +95,9 @@ def test_a_cell_a_configuration_and_a_metric_are_added_as_files_only(tmp_path):
     (tmp_path / "benchmarks" / "traffic" / "trickle.json").write_text(json.dumps({
         "why": "a later PR's mix", "warm_seconds": 1, "warmup_writes": 10,
         "merges_in_window": 0, "streams": [
-            {"name": "w", "loop": "open", "rate": 50, "procs": 1, "judged": True,
+            {"name": "w", "loop": "open", "rate": 64, "procs": 1, "judged": True,
              "ops": [{"op": "create", "table": "kv", "weight": 1}]}]}))
-    (tmp_path / "benchmarks" / "metrics" / "txn_p50_ms.json").write_text(json.dumps(
+    (tmp_path / "benchmarks" / "metrics" / "txn_median_ms.json").write_text(json.dumps(
         {"reader": "client_percentile", "args": {"family": "txn", "q": 50}}))
     bench = json.loads(json.dumps(B))
     bench["configs"].append({"name": "tiny-kv", "source": "test", "reduced": [],
@@ -105,7 +105,7 @@ def test_a_cell_a_configuration_and_a_metric_are_added_as_files_only(tmp_path):
     bench["workloads"].append({"name": "tiny-kv.trickle", "config": "tiny-kv",
                                "traffic": "trickle", "chips": 1, "why": "t"})
     bench["end_to_end"].append({
-        "name": "txn_p50_ms", "unit": "ms", "better": "lower", "bound": 0.1,
+        "name": "txn_median_ms", "unit": "ms", "better": "lower", "bound": 0.1,
         "source": "host_clock", "workloads": ["tiny-kv.trickle"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     out = subprocess.run(
@@ -116,7 +116,9 @@ def test_a_cell_a_configuration_and_a_metric_are_added_as_files_only(tmp_path):
     assert out.returncode == 0, out.stderr[-3000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert RESULT_KEYS <= set(line) and list(line)[-1] == "compared"
-    assert set(line["metrics"]) == {"txn_p50_ms", "setup_s"}
-    assert line["attempted"] == 100 and line["failed"] == 0
+    assert set(line["metrics"]) == {"txn_median_ms", "setup_s"}
+    # 64/s for 2 s: a gap that is exact in binary, so the schedule's sum of
+    # gaps reaches the window's last instant exactly and leaves it out
+    assert line["attempted"] == 128 and line["failed"] == 0
     assert line["rehearsal"]["comparison_passed"] and line["correct"] is False
     assert "merges in window: counted None, designed 0" in out.stdout

@@ -30,7 +30,7 @@ def test_mean_between_two_scrapes():
 
 
 def test_readers_over_the_recorded_scrapes():
-    assert run.read_metric("sched_queue_ms.range", CTX) == pytest.approx(5.0)
+    assert run.read_metric("sched_residency_ms.range", CTX) == pytest.approx(5.0)
     assert run.read_metric("host_copy_ms", CTX) is None
     # 10 batched Ranges in 4 dispatches: 6 rode
     assert run.read_metric("batch_riders_pct", CTX) == pytest.approx(60.0)
