@@ -2,7 +2,10 @@
 into a window. ``TpuScanner`` merges its delta into the device mirror every
 ``T`` written rows, by count alone, and the merge stalls every write; so a
 cell fixes the delta's fill when the window opens (``warmup_writes``, counted)
-and its write rate, and with them the number of merges inside the window.
+and its write rate, and with them the number of threshold crossings inside
+the window. Closed-loop readers of the device path may add follow-up merges
+behind a crossing (``followup_readers``): the rule allows those and does not
+require them.
 """
 
 from __future__ import annotations
@@ -53,12 +56,36 @@ def merges(r: int, w: float, seconds: float, t: int = MERGE_THRESHOLD) -> int:
     return int((r + w * seconds) // t)
 
 
-def expected(traffic: dict, seconds: float, rate_scale: float = 1.0,
-             t: int = MERGE_THRESHOLD) -> tuple[int, int]:
-    """The designed (fewest, most) merges in a window of ``seconds``."""
+def crossings(traffic: dict, seconds: float, rate_scale: float = 1.0,
+              t: int = MERGE_THRESHOLD) -> tuple[int, int]:
+    """The designed (fewest, most) threshold crossings k in a window of
+    ``seconds``: the merges the WRITES kick, one each."""
     r = int(traffic.get("warmup_writes", 0))
     lo, hi = write_rate(traffic, rate_scale)
     return merges(r, lo, seconds, t), merges(r, hi, seconds, t)
+
+
+def followup_readers(traffic: dict) -> int:
+    """R: the clients of the mix's closed-loop streams that hold an operation
+    the device answers (``DEVICE_READ``). ``TpuScanner._ensure_published``
+    lets a reader that finds the delta at or over the threshold merge it
+    itself: while the merge a crossing kicked is in flight the delta still
+    reads full, so each such caller may park on the merge lock once and then
+    merge the tail that has gathered — at most one follow-up merge a caller
+    a crossing. An open loop stays out (its reads have never added a merge in
+    a recorded run), and so does a closed loop of writers."""
+    return sum(int(s["clients"]) for s in traffic["streams"]
+               if s.get("loop") == "closed" and any(
+                   plugin.load(_OPS, op["op"]).DEVICE_READ for op in s["ops"]))
+
+
+def expected(traffic: dict, seconds: float, rate_scale: float = 1.0,
+             t: int = MERGE_THRESHOLD) -> tuple[int, int]:
+    """The (fewest, most) merges a run inside the design counts: the k
+    crossings, and up to R reader follow-ups behind each. A program that
+    does not merge on the read path counts k."""
+    lo, hi = crossings(traffic, seconds, rate_scale, t)
+    return lo, hi * (1 + followup_readers(traffic))
 
 
 def design_faults(traffic: dict, seconds: float, stall_s: float = 7.0,
@@ -72,7 +99,7 @@ def design_faults(traffic: dict, seconds: float, stall_s: float = 7.0,
             one is far: (k+1)*t - r > w*W + t/4."""
     want = traffic["merges_in_window"]
     if isinstance(want, dict):
-        lo, hi = expected(traffic, seconds, t=t)
+        lo, hi = crossings(traffic, seconds, t=t)
         return [] if (want["min"], want["max"]) == (lo, hi) else [
             f"the file's range {want} is not the rule's {lo}..{hi}"]
     r = int(traffic.get("warmup_writes", 0))

@@ -574,13 +574,14 @@ class Context:
         return witness.touched(self.machine.pauses, a, b)
 
     def merges(self):
-        """(counted, fewest designed, most designed) delta merges in the
-        window; counted is None without a ``/metrics`` to read."""
+        """(counted, (fewest, most) crossings designed, most merges the rule
+        allows with the readers' follow-ups: ``mergephase.expected``) for the
+        window's delta merges; counted is None without a ``/metrics`` to
+        read."""
         counted = None if self.before is None else int(prom.delta(
             self.after, self.before, "kb_mirror_merge_seconds_count"))
-        lo, hi = mergephase.expected(self.workload, self.window_s,
-                                     self.rate_scale)
-        return counted, lo, hi
+        args = (self.workload, self.window_s, self.rate_scale)
+        return counted, mergephase.crossings(*args), mergephase.expected(*args)[1]
 
 
 def read_metric(name: str, ctx: Context):
@@ -630,8 +631,8 @@ def summary_lines(ctx: Context) -> list[str]:
     """The earlier lines: each judged family's tails over every request and,
     beside them, over those no witnessed pause of the machine touched (the
     pause rule), failures by error string, the per-second worst Txn,
-    generator lateness, the machine's pauses, the merges counted beside the
-    designed integer."""
+    generator lateness, the machine's pauses, the compile cache's growth, the
+    merges counted beside the designed crossings and the follow-ups allowed."""
     from stats import percentile
 
     def tails(lat):
@@ -694,15 +695,18 @@ def summary_lines(ctx: Context) -> list[str]:
         out.append(f"generator lateness (open loops): p50={percentile(late, 50):.3f}ms "
                    f"p95={percentile(late, 95):.3f}ms max={max(late):.3f}ms")
     out.append(ctx.machine.line(ctx.window))
-    counted, lo, hi = ctx.merges()
+    out.append(f"compile cache: +{ctx.cache_growth} entries in the window")
+    counted, (lo, hi), most = ctx.merges()
     rate = mergephase.write_rate(ctx.workload, ctx.rate_scale)
+    readers = mergephase.followup_readers(ctx.workload)
     designed = str(lo) if lo == hi else f"{lo}..{hi}"
     line = (f"merges in window: counted {counted}, designed {designed} "
+            f"crossings, up to {hi} x {readers} reader follow-ups "
             f"(T={mergephase.MERGE_THRESHOLD}, warm-up writes "
             f"{ctx.workload.get('warmup_writes', 0)}, writes/s "
             f"{rate[0]:g}" + (f"..{rate[1]:g}" if rate[1] != rate[0] else "")
             + f", window {ctx.window_s:g} s)")
-    if counted is not None and not lo <= counted <= hi:
+    if counted is not None and not lo <= counted <= most:
         line += "  *** MERGE PHASE OFF THE DESIGN: this run measured another cell ***"
     out.append(line)
     return out

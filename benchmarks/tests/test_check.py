@@ -43,6 +43,9 @@ def test_the_reference_in_the_programs_place_reads_correct(cell):
 @pytest.mark.parametrize("cell,broken,caught_by", [
     ("k8s-2500.relist", "stale_read", "range_stale"),
     ("k8s-2500.relist", "altered_row", "range_rows_wrong"),
+    ("k8s-2500.relist-merge", "stale_read", "range_stale"),
+    ("k8s-2500.relist-merge", "altered_row", "range_rows_wrong"),
+    ("k8s-2500.relist-merge", "lost_write", "writes_refused"),
     ("k8s-2500.steady", "stale_read", "readback_wrong"),
     ("k8s-2500.steady", "lost_write", "watch_wrong"),
     ("k8s-2500.steady", "dropped_event", "watch_wrong"),
@@ -132,14 +135,15 @@ def test_the_account_reads_the_stage_only_the_kernel_path_records():
         "dispatches": 6.0, "riders": 14.0, "coalesced": 3.0}
 
 
-def test_the_program_with_its_device_path_off_reads_not_correct():
+@pytest.mark.parametrize("cell", ["k8s-2500.relist", "k8s-2500.relist-merge"])
+def test_the_program_with_its_device_path_off_reads_not_correct(cell):
     """The control of the device account, through the whole run on the CPU:
     the program's own host path (``--storage=native``) answers every Range
     byte for byte, and ``correct`` still comes out false."""
-    opts = SimpleNamespace(workload="k8s-2500.relist", seed=43, seconds=3.0,
+    opts = SimpleNamespace(workload=cell, seed=43, seconds=3.0,
                            trace=0, sut="hostpath", broken="", scale=0.04,
                            keep_trace="", chips=1)
-    workload, config = run.cell_files(CELLS["k8s-2500.relist"])
+    workload, config = run.cell_files(CELLS[cell])
     workload["warm_seconds"] = 1.0
     out = run.run_once(opts, workload, config, BENCHMARK)
     n = out["numbers"]

@@ -57,7 +57,7 @@ def test_write_rate_counts_only_the_operations_that_write():
               "streams": [{"loop": "closed", "clients": 300, "ops": [
                   {"op": "create", "table": "kv", "weight": 1}]}]}
     assert mergephase.write_rate(closed) == (440.0, 660.0)
-    assert mergephase.expected(closed, 50) == (5, 8)
+    assert mergephase.crossings(closed, 50) == (5, 8)
     assert mergephase.design_faults(closed, 50) == []
 
 
@@ -65,10 +65,68 @@ def test_write_rate_counts_only_the_operations_that_write():
 def test_every_traffic_file_keeps_the_rule_at_run_seconds(cell):
     traffic = run.load_json("traffic", cell.split(".", 1)[1] + ".json")
     assert mergephase.design_faults(traffic, B["run_seconds"]) == []
-    lo, hi = mergephase.expected(traffic, B["run_seconds"])
+    lo, hi = mergephase.crossings(traffic, B["run_seconds"])
     want = traffic["merges_in_window"]
     assert (lo, hi) == ((want["min"], want["max"]) if isinstance(want, dict)
                         else (want, want))
+
+
+def _mix(*streams, warmup_writes=0, **more):
+    return dict(more, warmup_writes=warmup_writes, streams=list(streams))
+
+
+_WRITERS = {"loop": "open", "rate": 270, "ops": [
+    {"op": "update", "table": "leases", "weight": 1}]}
+_CLOSED_WRITERS = {"loop": "closed", "clients": 300, "ops": [
+    {"op": "create", "table": "kv", "weight": 1}]}
+_COUNT_POLL = {"loop": "open", "rate": 0.05, "ops": [
+    {"op": "count", "table": "leases", "weight": 1}]}
+
+
+def _listers(clients):
+    return {"loop": "closed", "clients": clients, "ops": [
+        {"op": "range_unpaged", "table": "pods", "weight": 1}]}
+
+
+@pytest.mark.parametrize("traffic,seconds,want", [
+    # the three cells: k crossings, up to R follow-ups behind each
+    ("steady", 50, (3, 3)),          # no closed-loop reader: R = 0
+    ("relist", 50, (0, 0)),          # 3 listers, but no crossing to follow
+    ("relist-merge", 50, (3, 12)),   # 3 crossings x (1 + 3 listers)
+    # a closed loop of writers alone reads what it read: R = 0
+    (_mix(_CLOSED_WRITERS, warmup_writes=600,
+          closed_loop_writes_per_s={"min": 440, "max": 660}), 50, (5, 8)),
+    # 5 closed-loop readers at k = 2: 2 x (1 + 5)
+    (_mix(_WRITERS, _listers(5), warmup_writes=800), 30, (2, 12)),
+    # two reader streams add up; a paged list (host iterator) is no device read
+    (_mix(_WRITERS, _listers(2), _listers(1), {"loop": "closed", "clients": 9, "ops": [
+        {"op": "range_paged", "table": "pods", "page": 500, "weight": 1}]},
+          warmup_writes=800), 30, (2, 8)),
+    # an open-loop Count adds nothing: an open loop stays out of R
+    (_mix(_WRITERS, _COUNT_POLL, warmup_writes=1000), 50, (3, 3)),
+    # the fewest stays the fewest crossings where a closed loop sets the rate
+    (_mix(_CLOSED_WRITERS, _listers(3), warmup_writes=600,
+          closed_loop_writes_per_s={"min": 440, "max": 660}), 50, (5, 32)),
+])
+def test_the_rule_allows_reader_followups_and_never_requires_them(
+        traffic, seconds, want):
+    """``TpuScanner._ensure_published`` lets a closed-loop device reader that
+    meets a merge in flight merge the tail itself: up to R follow-ups a
+    crossing. A program without read-path merges counts k and is inside."""
+    if isinstance(traffic, str):
+        traffic = run.load_json("traffic", traffic + ".json")
+    assert mergephase.expected(traffic, seconds) == want
+    assert want[0] == mergephase.crossings(traffic, seconds)[0]
+
+
+def test_relist_merge_keeps_the_design_and_reads_r_from_its_file():
+    mix = run.load_json("traffic", "relist-merge.json")
+    assert mergephase.design_faults(mix, 50) == []
+    assert mergephase.followup_readers(mix) == 3
+    assert mergephase.crossings(mix, 50) == (3, 3)
+    # its two streams are the other two cells', unchanged
+    assert mix["streams"][0] == run.load_json("traffic", "relist.json")["streams"][0]
+    assert mix["streams"][1] == run.load_json("traffic", "steady.json")["streams"][0]
 
 
 def test_a_design_on_the_edge_is_refused():

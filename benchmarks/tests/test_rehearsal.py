@@ -45,10 +45,13 @@ def test_cell_on_the_cpu_backend(cell, trace):
     # and merge_stall_ms needs a merge, write_group_size a group of two: a
     # 3 s window at this size has neither for sure, and a reader that finds
     # nothing to read returns nothing, never 0
-    want = _metrics("per_layer" if trace else "end_to_end", cell) - {
-        m["name"] for m in B["per_layer"] if m["source"] == "device_trace"
-    } - {"merge_stall_ms", "write_group_size"}
+    mine = _metrics("per_layer" if trace else "end_to_end", cell)
+    want = {n for n in mine if n.split(".")[0] not in (
+        "merge_stall_ms", "write_group_size")} - {
+        m["name"] for m in B["per_layer"] if m["source"] == "device_trace"}
     assert want <= set(line["metrics"]), want - set(line["metrics"])
+    # and none of another cell's, nor of the other list
+    assert set(line["metrics"]) <= mine, set(line["metrics"]) - mine
     assert all(m["value"] is not None and m["unit"] for m in line["metrics"].values())
     # the earlier lines that make a refusal readable
     text = "\n".join(lines[:-1])
