@@ -29,7 +29,6 @@ from typing import Iterator
 from .. import coder
 from ..storage import CASFailedError, KvStorage, Partition, UncertainResultError
 from ..storage.errors import KeyNotFoundError, RevisionDriftBackError
-from ..trace import TRACER
 from ..util.env import txn_log
 from . import creator
 from .common import (
@@ -747,16 +746,15 @@ class Backend:
     def list_wire(self, start: bytes, end: bytes, revision: int = 0,
                   limit: int = 0):
         """Range read returning ready RangeResponse.kvs wire bytes when the
-        engine scanner has a C wire encoder; None otherwise. Returns
-        (kvs_blob, count, more, read_rev)."""
+        engine scanner has a wire encoder (the native store's C scan, the
+        TPU mirror's gather); None otherwise. Returns (kvs_blob, count,
+        more, read_rev). The scanner records its own stages, as in
+        ``list_``."""
         fast = getattr(self.scanner, "list_wire", None)
         if fast is None:
             return None
         read_rev = self._read_revision_checked(revision)
-        # one C call does scan + wire encode on the host: the engine's
-        # iteration stage, so the raw fast path still shows up in traces
-        with TRACER.stage("host_scan"):
-            blob, n, more = fast(start, end, read_rev, limit)
+        blob, n, more = fast(start, end, read_rev, limit)
         return blob, n, more, read_rev
 
     def count(self, start: bytes, end: bytes, revision: int = 0) -> tuple[int, int]:
@@ -766,9 +764,11 @@ class Backend:
     def list_batch(self, queries: list) -> list:
         """Batched range reads — the scheduler's batch executor. ``queries``
         is a list of ``("list", start, end, revision, limit)`` /
+        ``("wire", start, end, revision, limit)`` /
         ``("count", start, end, revision)`` tuples; the return list is
-        aligned with it, each element a RangeResult, a ``(count,
-        read_rev)`` tuple, or an Exception instance to raise to that
+        aligned with it, each element a RangeResult, ``list_wire``'s
+        ``(kvs_blob, count, more, read_rev)``, a ``(count, read_rev)``
+        tuple, or an Exception instance to raise to that
         query's waiter alone (a compacted revision fails its query, not
         the batch). Read revisions resolve here, at execution start — the
         same point a sequential execution would resolve them, so rev-0
@@ -784,32 +784,37 @@ class Backend:
                 resolved.append((i, q, self._read_revision_checked(q[3])))
             except Exception as e:
                 out[i] = e
+
+        def shaped(q, rr, res):
+            if q[0] == "count":
+                return res, rr
+            if q[0] == "wire":
+                return (*res, rr)
+            kvs, more = res
+            return RangeResult(kvs=kvs, revision=rr, more=more, count=len(kvs))
+
         scan_batch = getattr(self.scanner, "scan_batch", None)
         if scan_batch is not None and len(resolved) > 1:
             specs = [
                 ("count", q[1], q[2], rr) if q[0] == "count"
-                else ("range", q[1], q[2], rr, q[4])
+                else ("wire" if q[0] == "wire" else "range",
+                      q[1], q[2], rr, q[4])
                 for _i, q, rr in resolved
             ]
             results = scan_batch(specs)
             for (i, q, rr), res in zip(resolved, results):
-                if isinstance(res, BaseException):
-                    out[i] = res
-                elif q[0] == "count":
-                    out[i] = (res, rr)
-                else:
-                    kvs, more = res
-                    out[i] = RangeResult(kvs=kvs, revision=rr, more=more,
-                                         count=len(kvs))
+                out[i] = (res if isinstance(res, BaseException)
+                          else shaped(q, rr, res))
             return out
         for i, q, rr in resolved:  # engine-generic sequential fallback
             try:
                 if q[0] == "count":
-                    out[i] = (self.scanner.count(q[1], q[2], rr), rr)
+                    res = self.scanner.count(q[1], q[2], rr)
+                elif q[0] == "wire":
+                    res = self.scanner.list_wire(q[1], q[2], rr, q[4])
                 else:
-                    kvs, more = self.scanner.range_(q[1], q[2], rr, q[4])
-                    out[i] = RangeResult(kvs=kvs, revision=rr, more=more,
-                                         count=len(kvs))
+                    res = self.scanner.range_(q[1], q[2], rr, q[4])
+                out[i] = shaped(q, rr, res)
             except Exception as e:
                 out[i] = e
         return out

@@ -23,7 +23,7 @@ import grpc.aio
 
 from ..proto import rpc_pb2
 from ..server.etcd import shim
-from ..server.etcd.kv import KVService
+from ..server.etcd.kv import KVService, serialize_reply
 from ..server.etcd.misc import ClusterService, LeaseService, MaintenanceService
 
 
@@ -379,16 +379,17 @@ def make_aio_handlers(backend, peers=None, identity="kubebrain-tpu"):
     watch = AioWatchService(backend, peers)
     p = rpc_pb2
 
-    def unary(fn, req, resp):
+    def unary(fn, req, resp, serializer=None):
         return grpc.unary_unary_rpc_method_handler(
             _wrap_unary(fn),
             request_deserializer=req.FromString,
-            response_serializer=resp.SerializeToString,
+            response_serializer=serializer or resp.SerializeToString,
         )
 
     return [
         grpc.method_handlers_generic_handler("etcdserverpb.KV", {
-            "Range": unary(kv.Range, p.RangeRequest, p.RangeResponse),
+            "Range": unary(kv.Range, p.RangeRequest, p.RangeResponse,
+                           serialize_reply),
             "Txn": unary(kv.Txn, p.TxnRequest, p.TxnResponse),
             "Compact": unary(kv.Compact, p.CompactionRequest, p.CompactionResponse),
             "Put": unary(kv.Put, p.PutRequest, p.PutResponse),

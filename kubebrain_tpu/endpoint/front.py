@@ -33,7 +33,7 @@ import threading
 import grpc
 
 from ..proto import brain_pb2, rpc_pb2
-from ..server.etcd.kv import KVService
+from ..server.etcd.kv import KVService, serialize_reply
 from ..server.etcd.misc import ClusterService, LeaseService, MaintenanceService
 from .aio import AioBridgeQueue, AioWatchService, _AbortError, _SyncContextAdapter
 
@@ -49,9 +49,6 @@ def _status_num(code) -> int:
 
 
 _SYNC_CTX = _SyncContextAdapter()
-# the backhaul forwards pre-serialized responses verbatim, so handlers may
-# take the raw wire fast path (kv.py _list / _RawResponse)
-_SYNC_CTX.kb_raw_ok = True
 _END_OK = struct.pack("<IH", 0, 0)  # END payload: status 0, empty message
 
 
@@ -395,7 +392,7 @@ class FrontServer:
         response message or raises."""
         try:
             resp = result()
-            out = bytes(resp) if isinstance(resp, bytes) else resp.SerializeToString()
+            out = serialize_reply(resp)
             w = self._writer
             if w is not None and not w.is_closing():
                 # MSG + END corked as one frame pair; counted against the

@@ -530,6 +530,7 @@ class RequestScheduler:
         return self.submit(
             lambda: self.backend.list_wire(start, end, revision, limit),
             lane, client, key, deterministic=revision != 0,
+            bargs=("wire", start, end, revision, limit),
         )
 
     def list_by_stream(self, start: bytes, end: bytes, revision: int = 0,

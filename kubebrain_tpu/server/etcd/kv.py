@@ -43,23 +43,26 @@ from ...storage.errors import (
     UncertainResultError,
 )
 from ...proto import rpc_pb2
-from ...trace import TRACER, traceparent_of
+from ...trace import TRACER, emit_counter, traceparent_of
 from . import shim
 from .misc import ERR_LEASE_NOT_FOUND
 
 PARTITION_MAGIC_REVISION = 1888  # reference kv.go:33
 COMPACT_REV_KEY = b"compact_rev_key"  # the apiserver compactor's coordination key
 
+#: list replies by the path they left on: ``wire`` (the scanner's bytes,
+#: forwarded) or ``rows`` (KeyValues built and serialized per row)
+REPLY_METRIC = "kb.range.reply.total"
+
 ERR_COMPACTED = "etcdserver: mvcc: required revision has been compacted"
 ERR_FUTURE_REV = "etcdserver: mvcc: required revision is a future revision"
 
 
-class _RawResponse(bytes):
-    """Pre-serialized response body; the native-front backhaul sends it
-    verbatim (front.py skips SerializeToString for bytes)."""
-
-    def SerializeToString(self) -> bytes:  # grpc-python serializer hook
-        return bytes(self)
+def serialize_reply(reply) -> bytes:
+    """Response serializer of a method whose terminal may answer in wire
+    bytes (:meth:`KVService.Range`, so every front installs it for Range):
+    bytes pass through, a message serializes as ever."""
+    return reply if isinstance(reply, bytes) else reply.SerializeToString()
 
 
 class KVService:
@@ -79,18 +82,18 @@ class KVService:
     _client_of = staticmethod(client_of)  # fair-queuing flow id (sched)
 
     # ------------------------------------------------------------------ Range
-    def Range(self, request: rpc_pb2.RangeRequest, context) -> rpc_pb2.RangeResponse:
+    def Range(self, request: rpc_pb2.RangeRequest,
+              context) -> rpc_pb2.RangeResponse | bytes:
+        """A ``RangeResponse``, or its wire bytes where ``_list`` answers
+        raw: whoever mounts Range serializes with :func:`serialize_reply`."""
         # every Range is one span tree in /debug/traces; the client's W3C
         # traceparent (gRPC metadata) parents it when the transport has one
         with TRACER.span("etcd.KV/Range", traceparent=traceparent_of(context)):
             return self._range(request, context)
 
-    def _range(self, request: rpc_pb2.RangeRequest, context) -> rpc_pb2.RangeResponse:
+    def _range(self, request: rpc_pb2.RangeRequest,
+               context) -> rpc_pb2.RangeResponse | bytes:
         with TRACER.stage("endpoint_recv"):
-            # the native-front backhaul forwards pre-serialized bytes
-            # verbatim; python-grpc listeners reserialize, so the raw path
-            # is front-only
-            raw_ok = bool(getattr(context, "kb_raw_ok", False))
             if self.peers is not None:
                 self.peers.sync_read_revision()
             # etcd range conventions: empty range_end = the single key;
@@ -132,7 +135,7 @@ class KVService:
                 return self._partitions(request)
             if single_key:
                 return self._get(request)
-            return self._list(request, range_end, raw_ok, self._client_of(context))
+            return self._list(request, range_end, self._client_of(context))
         except SchedOverloadError as e:
             # admission control shed this request: the etcd error
             # kube-apiserver's client retries with backoff
@@ -192,14 +195,15 @@ class KVService:
             for kv in resp.kvs:
                 kv.version = kv.mod_revision
 
-    def _list(self, request, range_end: bytes, raw_ok: bool = False,
-              client: str = "") -> rpc_pb2.RangeResponse:
-        # raw fast path: the C engine encodes RangeResponse.kvs wire bytes
-        # directly (kb_mvcc_list_wire) and the native frontend forwards them
-        # without reserialization — no per-row Python anywhere on the list
-        # hot path. Only for the default sort/shape kube-apiserver uses.
-        if (raw_ok
-                and request.sort_target == rpc_pb2.RangeRequest.KEY
+    def _list(self, request, range_end: bytes,
+              client: str = "") -> rpc_pb2.RangeResponse | bytes:
+        # raw fast path: the scanner answers RangeResponse.kvs wire bytes
+        # (the native store's C scan, the TPU mirror's gather) and the front
+        # forwards them without reserialization — no per-row Python anywhere
+        # on the list hot path. Only for the default sort/shape
+        # kube-apiserver uses; None = the engine has no wire encoder.
+        fast = None
+        if (request.sort_target == rpc_pb2.RangeRequest.KEY
                 and request.sort_order == rpc_pb2.RangeRequest.NONE
                 and not request.keys_only
                 and request.key != COMPACT_REV_KEY):
@@ -207,13 +211,16 @@ class KVService:
                 request.key, range_end, request.revision, int(request.limit),
                 client=client,
             )
-            if fast is not None:
-                blob, n, more, read_rev = fast
-                with TRACER.stage("response_encode"):
-                    scalar = rpc_pb2.RangeResponse(
-                        header=shim.header(read_rev), more=more, count=n
-                    ).SerializeToString()
-                    return _RawResponse(scalar + blob)
+        # the one place a list's reply path is decided: how many Ranges
+        # left as bytes, how many as rows
+        emit_counter(REPLY_METRIC, path="rows" if fast is None else "wire")
+        if fast is not None:
+            blob, n, more, read_rev = fast
+            with TRACER.stage("response_encode"):
+                scalar = rpc_pb2.RangeResponse(
+                    header=shim.header(read_rev), more=more, count=n
+                ).SerializeToString()
+                return scalar + blob
         res = self.limiter.list_(
             request.key, range_end, request.revision, int(request.limit),
             client=client,

@@ -11,15 +11,15 @@ from __future__ import annotations
 import grpc
 
 from ...proto import rpc_pb2
-from .kv import KVService
+from .kv import KVService, serialize_reply
 from .misc import ClusterService, LeaseService, MaintenanceService
 from .watch import WatchService
 
 
-def _unary(fn, req_cls, resp_cls):
+def _unary(fn, req_cls, resp_cls, serializer=None):
     return grpc.unary_unary_rpc_method_handler(
         fn, request_deserializer=req_cls.FromString,
-        response_serializer=resp_cls.SerializeToString,
+        response_serializer=serializer or resp_cls.SerializeToString,
     )
 
 
@@ -44,7 +44,8 @@ def make_etcd_handlers(backend, peers=None, identity="kubebrain-tpu",
     p = rpc_pb2
     return [
         grpc.method_handlers_generic_handler("etcdserverpb.KV", {
-            "Range": _unary(kv.Range, p.RangeRequest, p.RangeResponse),
+            "Range": _unary(kv.Range, p.RangeRequest, p.RangeResponse,
+                            serialize_reply),
             "Txn": _unary(kv.Txn, p.TxnRequest, p.TxnResponse),
             "Compact": _unary(kv.Compact, p.CompactionRequest, p.CompactionResponse),
             "Put": _unary(kv.Put, p.PutRequest, p.PutResponse),

@@ -2,8 +2,8 @@
 
 The embedded single-host engine (the role Badger plays for the reference,
 pkg/storage/badger) and the default authoritative host store under the TPU
-mirror. Build with ``make -C native``; the adapter auto-builds on first use
-when the toolchain is present.
+mirror. Build with ``make -C native``; the adapter builds the library
+itself (that target alone) where it is missing or older than its source.
 
 Mapping to the engine contract:
 - TSO            → kb_tso (commit counter; badger.go:41-46 uses ReadTs)
@@ -24,6 +24,7 @@ import threading
 from .. import coder
 from ..backend.common import KeyValue
 from ..backend.scanner import Scanner
+from ..trace import TRACER
 from . import BatchWrite, Iter, KvStorage, Partition, register_engine
 from .errors import CASFailedError, Conflict, KeyNotFoundError, StorageError
 
@@ -32,20 +33,60 @@ _lib = None
 _lib_lock = threading.Lock()
 
 
-def _load_lib() -> ctypes.CDLL:
+#: the newest entry points: a library without them predates this adapter
+_REQUIRED_SYMBOLS = ("kb_mvcc_list_wire", "kb_wire_gather")
+
+
+def _lib_stale(path: str) -> bool:
+    """No library yet, or one older than its source."""
+    src = os.path.join(os.path.dirname(path), "kbstore.cc")
+    try:
+        return os.path.getmtime(path) < os.path.getmtime(src)
+    except OSError:
+        return not os.path.exists(path)
+
+
+def _build_lib(path: str) -> None:
+    """``make -C native libkbstore.so`` — that target alone: the fronts and
+    the store daemon beside it want nghttp2 and ssl, which a process that
+    needs the library must not — under a file lock: the test workers and a
+    server's children all come here first, and one of them builds (the
+    Makefile moves the finished library into place, so a process that has
+    the old one mapped is not disturbed)."""
+    import fcntl
+
+    native_dir = os.path.dirname(path)
+    with open(os.path.join(native_dir, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _lib_stale(path):
+            subprocess.run(["make", "-C", native_dir, os.path.basename(path)],
+                           check=True, capture_output=True)
+
+
+def load_lib() -> ctypes.CDLL:
+    """The library, built first where it is missing or older than its
+    source. Whoever will call into it loads it when constructed
+    (``NativeKv``, ``TpuScanner``), so a toolchain that is not there or a
+    stale build stops the boot, never a request."""
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
         path = os.path.abspath(_LIB_PATH)
-        if not os.path.exists(path):
+        if _lib_stale(path):
             # first-use auto-build must be single-flight; every caller
             # needs the lib before it can proceed anyway
-            # kblint: disable=KB102 -- deliberate build-under-lock
-            subprocess.run(
-                ["make", "-C", os.path.dirname(path)], check=True, capture_output=True
-            )
+            # kblint: disable=KB102,KB112 -- deliberate build-under-lock
+            _build_lib(path)
         lib = ctypes.CDLL(path)
+        missing = [n for n in _REQUIRED_SYMBOLS if not hasattr(lib, n)]
+        if missing:
+            # a library older than this adapter (kept by a copy that lost
+            # the sources' times, say) must never load: a caller probing
+            # for a fast path would quietly take the slow one
+            raise StorageError(
+                f"{path} lacks {', '.join(missing)}: a stale build; "
+                f"run `make -C {os.path.dirname(path)}`")
         u8p = ctypes.POINTER(ctypes.c_uint8)
         lib.kb_open.restype = ctypes.c_void_p
         lib.kb_open_at.argtypes = [ctypes.c_char_p, ctypes.c_int]
@@ -129,6 +170,11 @@ def _load_lib() -> ctypes.CDLL:
             ctypes.POINTER(ctypes.c_size_t), ctypes.POINTER(ctypes.c_int),
         ]
         lib.kb_mvcc_list_wire.restype = ctypes.c_uint64
+        lib.kb_wire_gather.argtypes = [
+            ctypes.c_void_p, ctypes.c_size_t,  # run descriptors, how many
+            ctypes.c_void_p, ctypes.c_size_t,  # out, its capacity
+        ]
+        lib.kb_wire_gather.restype = ctypes.c_size_t
         lib.kb_split_keys.argtypes = [
             ctypes.c_void_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_size_t,
             ctypes.POINTER(ctypes.c_size_t),
@@ -193,9 +239,54 @@ def _load_lib() -> ctypes.CDLL:
         return lib
 
 
+#: what ``kb_wire_gather`` reads through raw pointers: a wire source's
+#: arrays, in order, each C-contiguous in exactly this dtype
+_WIRE_SOURCE_DTYPES = ("uint8", "int32", "uint64", "uint8", "uint64", "int64")
+
+
+def wire_gather(sources: list[tuple], runs: list[tuple[int, int, int]]) -> bytes:
+    """``RangeResponse.kvs`` wire bytes of ``runs`` — ``(source, from, to)``
+    row ranges, in the order they go out — through ``kb_wire_gather``. A
+    source is six numpy arrays: a decoded key matrix ``uint8[n, W]`` with
+    its ``int32`` lengths and ``uint64`` revisions (row-aligned), then a
+    ``uint8`` value arena with its ``uint64`` offsets and the ``int64``
+    rows that index them (``rows[i]`` is row i's value). Two ``CDLL`` calls
+    a reply, whatever its runs (the size, then the bytes): the GIL is
+    released while they copy, and given up no more often than that."""
+    import numpy as np
+
+    bases = []
+    for src in sources:
+        k_u8, k_lens, revs, arena, offsets, rows = src
+        if (tuple(str(a.dtype) for a in src) != _WIRE_SOURCE_DTYPES
+                or not all(a.flags.c_contiguous for a in src)
+                or not len(k_u8) == len(k_lens) == len(revs) == len(rows)):
+            # kb_wire_gather reads raw pointers: a wrong dtype or stride
+            # would be wrong bytes on the wire, or a read out of bounds
+            raise ValueError(
+                "wire source: want C-contiguous "
+                f"{', '.join(_WIRE_SOURCE_DTYPES)} with row-aligned keys, "
+                "lens, revs and rows; got "
+                + ", ".join(f"{a.dtype}{list(a.shape)}" for a in src))
+        bases.append((k_u8.ctypes.data, k_u8.strides[0], k_lens.ctypes.data,
+                      revs.ctypes.data, arena.ctypes.data, offsets.ctypes.data,
+                      rows.ctypes.data))
+    table = np.array(
+        [(k + a * stride, stride, kl + 4 * a, rv + 8 * a, ar, of, rw + 8 * a,
+          b - a)
+         for (k, stride, kl, rv, ar, of, rw), a, b in (
+             (bases[s], a, b) for s, a, b in runs)],
+        dtype=np.uint64).reshape(-1, 8)
+    lib = _lib or load_lib()  # every read's hot path: no lock once loaded
+    need = lib.kb_wire_gather(table.ctypes.data, len(table), None, 0)
+    out = np.empty(need, dtype=np.uint8)
+    lib.kb_wire_gather(table.ctypes.data, len(table), out.ctypes.data, need)
+    return out.tobytes()
+
+
 class NativeKv(KvStorage):
     def __init__(self, partitions: int = 1, data_dir: str = "", fsync: bool = False):
-        self._lib = _load_lib()
+        self._lib = load_lib()
         if data_dir:
             os.makedirs(data_dir, exist_ok=True)
             self._store = ctypes.c_void_p(
@@ -543,6 +634,33 @@ class NativeKv(KvStorage):
             self._store = None
 
 
+def list_wire_pages(store, snapshot: int, start: bytes, end: bytes,
+                    read_revision: int, limit: int = 0,
+                    page_rows: int = 4096) -> tuple[bytes, int, bool]:
+    """The visible range of a store that has ``mvcc_list_wire``, page by
+    page, as ``(kvs_blob, n_rows, more)``: scan and wire encoding in C,
+    the engine's iteration stage (``host_scan``) and nothing else."""
+    lo, hi = coder.internal_range(start, end)
+    blobs: list[bytes] = []
+    total = 0
+    cursor = lo
+    with TRACER.stage("host_scan"):
+        while True:
+            want = min(limit - total, page_rows) if limit else page_rows
+            blob, n, more, nxt = store.mvcc_list_wire(
+                cursor, hi, snapshot, read_revision, want
+            )
+            blobs.append(blob)
+            total += n
+            if limit and total >= limit:
+                # the C more flag is exact: set only when a further visible
+                # non-tombstone row exists — etcd's More semantics directly
+                return b"".join(blobs), total, more
+            if not more or not nxt:
+                return b"".join(blobs), total, False
+            cursor = nxt
+
+
 class NativeScanner(Scanner):
     """Generic scanner with the list hot paths served by the engine's C
     MVCC pass (kb_mvcc_list_page) — one FFI call per page instead of a
@@ -589,25 +707,8 @@ class NativeScanner(Scanner):
                   limit: int = 0) -> tuple[bytes, int, bool]:
         """Visible range as ready RangeResponse.kvs wire bytes (C encoder).
         Returns (kvs_blob, n_rows, more)."""
-        lo, hi = coder.internal_range(start, end)
-        snapshot = self._snapshot_checked(read_revision)
-        blobs: list[bytes] = []
-        total = 0
-        cursor = lo
-        while True:
-            want = min(limit - total, self.PAGE_ROWS) if limit else self.PAGE_ROWS
-            blob, n, more, nxt = self._store.mvcc_list_wire(
-                cursor, hi, snapshot, read_revision, want
-            )
-            blobs.append(blob)
-            total += n
-            if limit and total >= limit:
-                # the C more flag is exact: set only when a further visible
-                # non-tombstone row exists — etcd's More semantics directly
-                return b"".join(blobs), total, more
-            if not more or not nxt:
-                return b"".join(blobs), total, False
-            cursor = nxt
+        return list_wire_pages(self._store, self._snapshot_checked(read_revision),
+                               start, end, read_revision, limit, self.PAGE_ROWS)
 
     def range_stream(self, start: bytes, end: bytes, read_revision: int,
                      batch_size: int = 300):

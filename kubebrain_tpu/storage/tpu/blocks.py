@@ -60,6 +60,16 @@ class Mirror:
     # recomputed from (undecodable) encoded keys
     ttl_host: np.ndarray | None = None  # bool[P, N]
 
+    def __post_init__(self):
+        # the wire gather reads the value columns through raw pointers on
+        # every Range: whatever built this mirror, they are held in the
+        # dtype and layout declared above from here on (no copy where they
+        # already are, which is every build and merge path today)
+        self.val_arena = [np.ascontiguousarray(a, dtype=np.uint8)
+                          for a in self.val_arena]
+        self.val_offsets = [np.ascontiguousarray(o, dtype=np.uint64)
+                            for o in self.val_offsets]
+
     @property
     def partitions(self) -> int:
         return self.keys_host.shape[0]
@@ -107,6 +117,22 @@ class Mirror:
         values = [arena[o[i] : o[i + 1]].tobytes() for i in map(int, rows)]
         revs = self.revs_host[p][rows]
         return keys, values, revs
+
+    def wire_source(self, p: int, rows: np.ndarray) -> tuple:
+        """Rows of one partition as the arrays ``native.wire_gather`` reads
+        (keys, lens, revisions — row-aligned with ``rows`` — then the
+        partition's value arena, its offsets, and ``rows``): the wire
+        path's counterpart of :meth:`materialize`, with no object per row.
+        Keys come through the one decode funnel, values are never touched
+        here — the gather copies them arena → wire, and the partition's
+        two value columns go as the mirror holds them (``__post_init__``),
+        never converted on a read."""
+        rows = np.ascontiguousarray(rows, dtype=np.int64)
+        k_u8, k_lens = self.decoded_keys(p, rows)
+        return (np.ascontiguousarray(k_u8),
+                np.ascontiguousarray(k_lens, dtype=np.int32),
+                np.ascontiguousarray(self.revs_host[p][rows], dtype=np.uint64),
+                self.val_arena[p], self.val_offsets[p], rows)
 
     def partition_first_keys(self) -> list[bytes]:
         return [
@@ -172,6 +198,28 @@ def rows_to_arrays(rows: list[tuple[bytes, int, bytes]], width: int):
         offsets[i + 1] = off
     arena = np.frombuffer(b"".join(chunks_vals), dtype=np.uint8).copy() if rows else np.zeros(0, np.uint8)
     return keys_u8, lens, revs, tomb, arena, offsets
+
+
+def rows_wire_source(rows: list[tuple[bytes, bytes, int]]) -> tuple:
+    """Python ``(key, value, revision)`` rows as a :meth:`Mirror.wire_source`
+    tuple — for the few rows that exist as objects (a read's live overlay
+    entries, a host-path page), so they reach the wire through the same
+    encoder as the mirror's."""
+    n = len(rows)
+    keys_u8 = np.zeros((n, max((len(r[0]) for r in rows), default=0) or 1),
+                       dtype=np.uint8)
+    lens = np.zeros(n, dtype=np.int32)
+    revs = np.zeros(n, dtype=np.uint64)
+    offsets = np.zeros(n + 1, dtype=np.uint64)
+    off = 0
+    for i, (k, v, rev) in enumerate(rows):
+        keys_u8[i, : len(k)] = np.frombuffer(k, dtype=np.uint8)
+        lens[i] = len(k)
+        revs[i] = rev
+        off += len(v)
+        offsets[i + 1] = off
+    arena = np.frombuffer(b"".join(r[1] for r in rows), dtype=np.uint8)
+    return keys_u8, lens, revs, arena, offsets, np.arange(n, dtype=np.int64)
 
 
 def _order_void(keys_u8: np.ndarray, revs: np.ndarray) -> np.ndarray:

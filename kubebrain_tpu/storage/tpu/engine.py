@@ -22,6 +22,7 @@ storage sharding through GetPartitions).
 
 from __future__ import annotations
 
+import bisect
 import functools
 import threading
 import time
@@ -41,6 +42,7 @@ from ...trace import TRACER
 from ...util import fieldcheck, lockcheck
 from .. import BatchWrite, CASFailedError, KvStorage, Partition, register_engine
 from ..errors import UncertainResultError
+from ..native import list_wire_pages, load_lib, wire_gather
 from .blocks import (
     Mirror,
     build_mirror,
@@ -52,6 +54,7 @@ from .blocks import (
     merge_sorted_arrays,
     merge_sorted_stored,
     rows_to_arrays,
+    rows_wire_source,
     sort_arrays,
 )
 from .encode import EncodeOverflow
@@ -152,8 +155,6 @@ class _DeltaIndex:
         """Per user key in [start, end): latest delta version <= read_rev.
         None value => tombstoned. Delta revisions all exceed published
         revisions, so any entry here overrides the device result."""
-        import bisect
-
         lo = bisect.bisect_left(self._keys, start)
         hi = bisect.bisect_left(self._keys, end) if end else len(self._keys)
         out: dict[bytes, tuple[int, bytes] | None] = {}
@@ -452,6 +453,9 @@ class TpuScanner(Scanner):
         partitions: int = 0,
         encode_keys: bool | None = None,
     ):
+        # the wire path's gather is in libkbstore.so whatever the inner
+        # engine: a missing or stale library fails here, not on a Range
+        load_lib()
         super().__init__(store, get_compact_revision, retry_min_revision, compact_history, max_workers)
         self._mesh = mesh if mesh is not None else make_mesh()
         # --scan-partitions: mirror partition count decoupled from the mesh
@@ -1329,13 +1333,84 @@ class TpuScanner(Scanner):
         kvs.sort(key=lambda kv: kv.key)
         return kvs
 
-    def range_(self, start: bytes, end: bytes, read_revision: int, limit: int = 0):
-        if limit and limit <= self._host_limit_threshold:
-            return super().range_(start, end, read_revision, limit)
-        if self._degraded():
-            # quarantined/rebuilding mirror: serve from the authoritative
-            # host store (the differential oracle — byte-identical)
-            return Scanner.range_(self, start, end, read_revision, limit)
+    def _materialize_wire(self, mirror: Mirror, idx: np.ndarray, overlay,
+                          limit: int = 0) -> tuple[bytes, int, bool]:
+        """Visible rows (flat p·N + row indices) → ``(RangeResponse.kvs wire
+        bytes, rows, more)`` with the delta overlay merged: what
+        :meth:`_materialize_visible` + ``kvs[:limit]`` + the front's
+        per-row protobuf produce, with no Python object per row — the ONE
+        wire materialization the single and query-batched wire reads share.
+        The mirror's rows arrive in key order, one source of arrays per
+        partition (:meth:`Mirror.wire_source`); each overlay key finds its
+        place among them by binary search (a loop over the OVERLAY's items,
+        a handful in one namespace), the row it supersedes drops out and a
+        live entry is spliced in between two runs; nothing is sorted. The
+        runs go out in one ``kb_wire_gather`` call — keys, revisions and
+        the value bytes copied arena → wire with the GIL released."""
+        n_rows = mirror.keys_host.shape[1]
+        n_vis = len(idx)
+        parts, rows = np.divmod(idx, n_rows)
+        sources: list[tuple] = []
+        starts: list[int] = []  # a source's first row among the visible
+        if n_vis:
+            ps, first = np.unique(parts, return_index=True)
+            starts = [int(a) for a in first]
+            for p, a, b in zip(ps, starts, starts[1:] + [n_vis]):
+                sources.append(mirror.wire_source(int(p), rows[a:b]))
+        runs: list[tuple[int, int, int]] = []  # (source, from, to), key order
+
+        def mirror_run(g0: int, g1: int) -> None:
+            s = bisect.bisect_right(starts, g0) - 1
+            while g0 < g1:
+                stop = min(g1, starts[s + 1] if s + 1 < len(starts) else n_vis)
+                runs.append((s, g0 - starts[s], stop - starts[s]))
+                g0, s = stop, s + 1
+
+        def key_at(g: int) -> bytes:
+            s = bisect.bisect_right(starts, g) - 1
+            k_u8, k_lens = sources[s][0], sources[s][1]
+            i = g - starts[s]
+            return k_u8[i, : k_lens[i]].tobytes()
+
+        if overlay:
+            items = sorted(overlay.items())
+            live = [(uk, e[1], e[0]) for uk, e in items if e is not None]
+            if live:
+                sources.append(rows_wire_source(live))
+            cur = j = 0
+            for uk, entry in items:
+                lo, hi = cur, n_vis
+                while lo < hi:
+                    mid = (lo + hi) // 2
+                    if key_at(mid) < uk:
+                        lo = mid + 1
+                    else:
+                        hi = mid
+                mirror_run(cur, lo)
+                if entry is not None:
+                    runs.append((len(sources) - 1, j, j + 1))
+                    j += 1
+                # the delta supersedes (or deletes) the mirror's row
+                cur = lo + (lo < n_vis and key_at(lo) == uk)
+            mirror_run(cur, n_vis)
+        else:
+            mirror_run(0, n_vis)
+        n = sum(b - a for _s, a, b in runs)
+        more = bool(limit) and n > limit
+        if more:
+            n, room, cut = limit, limit, []
+            for s, a, b in runs:
+                if room <= 0:
+                    break
+                cut.append((s, a, min(b, a + room)))
+                room -= b - a
+            runs = cut
+        return wire_gather(sources, runs), n, more
+
+    def _device_range(self, start: bytes, end: bytes, read_revision: int,
+                      materialize):
+        """One device-path Range, ``materialize(mirror, idx, overlay)`` its
+        host half — the stages ``range_`` and ``list_wire`` share."""
         # attribution: delta_overlay = the delta on the read path (publish
         # check, the wait for the writers' lock, the overlay under it);
         # dispatch = query assembly + async kernel enqueue; compute = the
@@ -1356,29 +1431,74 @@ class TpuScanner(Scanner):
                 mask, counts, mirror.keys_host.shape[1]
             )
         with TRACER.stage("host_copy"):
-            kvs = self._materialize_visible(mirror, idx, overlay)
+            out = materialize(mirror, idx, overlay)
             # the read's device arrays go here, inside the stage: dropping
             # them gives up the GIL, and under three listers getting it
             # back took ~1 ms on average (42 ms at worst) that no stage
             # showed (kb_rpc_unaccounted_seconds over 2 ms, PR 26)
             del mask, counts
+        return out
+
+    def _on_host(self, limit: int) -> bool:
+        """A page small enough that one engine iter beats a kernel launch,
+        or a quarantined/rebuilding mirror: the authoritative host store
+        answers (the differential oracle — byte-identical)."""
+        return bool(limit and limit <= self._host_limit_threshold) \
+            or self._degraded()
+
+    def range_(self, start: bytes, end: bytes, read_revision: int, limit: int = 0):
+        if self._on_host(limit):
+            return Scanner.range_(self, start, end, read_revision, limit)
+        kvs = self._device_range(start, end, read_revision,
+                                 self._materialize_visible)
         if limit:
             return kvs[:limit], len(kvs) > limit
         return kvs, False
+
+    def list_wire(self, start: bytes, end: bytes, read_revision: int,
+                  limit: int = 0) -> tuple[bytes, int, bool]:
+        """``range_`` answered as ``(RangeResponse.kvs wire bytes, rows,
+        more)``: the same snapshot, stages and rows, gathered from the
+        mirror's host arrays straight into wire bytes
+        (:meth:`_materialize_wire`) instead of built row by row."""
+        if self._on_host(limit):
+            return self._host_list_wire(start, end, read_revision, limit)
+        return self._device_range(
+            start, end, read_revision,
+            lambda mirror, idx, overlay: self._materialize_wire(
+                mirror, idx, overlay, limit))
+
+    def _host_list_wire(self, start: bytes, end: bytes, read_revision: int,
+                        limit: int) -> tuple[bytes, int, bool]:
+        """The host path of a wire read, in the same queue round: the inner
+        engine's own wire scan where it has one, else the host scanner's
+        rows through the shared encoder."""
+        if hasattr(self._store, "mvcc_list_wire"):
+            return list_wire_pages(
+                self._store, self._snapshot_checked(read_revision),
+                start, end, read_revision, limit)
+        kvs, more = Scanner.range_(self, start, end, read_revision, limit)
+        with TRACER.stage("host_copy"):
+            src = rows_wire_source([(kv.key, kv.value, kv.revision) for kv in kvs])
+            return wire_gather([src], [(0, 0, len(kvs))]), len(kvs), more
 
     def scan_batch(self, queries):
         """B concurrent distinct Range/Count queries against ONE mirror
         snapshot = ONE device dispatch (the ROADMAP query-batched
         ``_dev_mask`` lever). ``queries`` is a list of
         ``("range", start, end, read_rev, limit)`` /
+        ``("wire", start, end, read_rev, limit)`` /
         ``("count", start, end, read_rev)`` tuples. Returns a list aligned
         with ``queries`` whose elements are ``(kvs, more)`` for range,
-        ``int`` for count, or an Exception instance — per-query demux, so
-        e.g. one compacted read revision fails its own query, never the
-        batch. Results are byte-identical to sequential ``range_``/
-        ``count`` calls: bounds/revision packing, index extraction, and
-        host materialization all reuse the single-query code paths."""
+        ``(kvs_blob, rows, more)`` for wire, ``int`` for count, or an
+        Exception instance — per-query demux, so e.g. one compacted read
+        revision fails its own query, never the batch. Results are
+        byte-identical to sequential ``range_``/``list_wire``/``count``
+        calls: bounds/revision packing, index extraction, and host
+        materialization all reuse the single-query code paths."""
         out: list = [None] * len(queries)
+        host = {"range": functools.partial(Scanner.range_, self),
+                "wire": self._host_list_wire}
         if self._degraded():
             # degraded-mode serving: per-query host-store scans with the
             # same per-query error demux (the engine-generic shape)
@@ -1387,8 +1507,8 @@ class TpuScanner(Scanner):
                     if spec[0] == "count":
                         out[i] = Scanner.count(self, spec[1], spec[2], spec[3])
                     else:
-                        out[i] = Scanner.range_(self, spec[1], spec[2],
-                                                spec[3], spec[4])
+                        out[i] = host[spec[0]](spec[1], spec[2], spec[3],
+                                               spec[4])
                 except Exception as e:
                     out[i] = e
             return out
@@ -1396,11 +1516,11 @@ class TpuScanner(Scanner):
         for i, spec in enumerate(queries):
             kind, start, end, read_rev = spec[0], spec[1], spec[2], spec[3]
             try:
-                if (kind == "range" and spec[4]
+                if (kind != "count" and spec[4]
                         and spec[4] <= self._host_limit_threshold):
                     # same small-page host fallback as range_: one engine
                     # iter beats a kernel launch for a 500-row page
-                    out[i] = Scanner.range_(self, start, end, read_rev, spec[4])
+                    out[i] = host[kind](start, end, read_rev, spec[4])
                     continue
                 self._snapshot_checked(read_rev)
             except Exception as e:  # demuxed to this query's waiter
@@ -1415,6 +1535,8 @@ class TpuScanner(Scanner):
             try:
                 if spec[0] == "count":
                     out[i] = self.count(spec[1], spec[2], spec[3])
+                elif spec[0] == "wire":
+                    out[i] = self.list_wire(spec[1], spec[2], spec[3], spec[4])
                 else:
                     out[i] = self.range_(spec[1], spec[2], spec[3], spec[4])
             except Exception as e:
@@ -1432,7 +1554,7 @@ class TpuScanner(Scanner):
                 mirror, [(s[1], s[2], s[3]) for _, s in device])
             sel = np.zeros(int(mask.shape[0]), dtype=bool)
             for k, (_, s) in enumerate(device):
-                sel[k] = s[0] == "range"  # counts (and pow2 pad) stay off-wire
+                sel[k] = s[0] != "count"  # counts (and pow2 pad) pull no rows
         n_rows = mirror.keys_host.shape[1]
         # both kernels emit [Qpad, P, N] with N == the host row width; the
         # flat-index split below silently corrupts results if that drifts
@@ -1470,9 +1592,12 @@ class TpuScanner(Scanner):
                     continue
                 lo = np.searchsorted(idx, k * stride)
                 hi = np.searchsorted(idx, (k + 1) * stride)
-                kvs = self._materialize_visible(
-                    mirror, idx[lo:hi] - k * stride, overlays[k])
-                limit = spec[4]
+                q_idx, limit = idx[lo:hi] - k * stride, spec[4]
+                if spec[0] == "wire":
+                    out[qi] = self._materialize_wire(
+                        mirror, q_idx, overlays[k], limit)
+                    continue
+                kvs = self._materialize_visible(mirror, q_idx, overlays[k])
                 out[qi] = (kvs[:limit], len(kvs) > limit) if limit else (kvs, False)
             del mask, counts  # released inside a stage, as in range_
         return out
@@ -2158,6 +2283,9 @@ class TpuKvStorage(KvStorage):
             self.mvcc_delete = self._mvcc_delete_tracked
         if hasattr(inner, "write_batch"):
             self.write_batch = self._write_batch_tracked
+        if hasattr(inner, "mvcc_list_wire"):
+            # a read: nothing to track, the host path of a wire Range
+            self.mvcc_list_wire = inner.mvcc_list_wire
 
     # ---- scanner wiring (Backend calls make_scanner, storage/__init__.py)
     def make_scanner(self, **kw) -> TpuScanner:

@@ -399,6 +399,13 @@ def emit_histogram(name: str, value: float, **tags: Any) -> None:
         m.emit_histogram(name, value, **tags)
 
 
+def emit_counter(name: str, value: float = 1, **tags: Any) -> None:
+    """The counter twin of :func:`emit_histogram`."""
+    m = TRACER.metrics
+    if m is not None:
+        m.emit_counter(name, value, **tags)
+
+
 def traceparent_of(context: Any) -> str | bytes | None:
     """The ``traceparent`` metadata value of a gRPC(-ish) server context,
     if the transport exposes invocation metadata (grpcio does; the native

@@ -1180,12 +1180,50 @@ static inline size_t varint_len(uint64_t v) {
   return n;
 }
 
-static inline void put_varint(std::string& o, uint64_t v) {
+static inline uint8_t* put_varint(uint8_t* p, uint64_t v) {
   while (v >= 0x80) {
-    o.push_back(static_cast<char>(v | 0x80));
+    *p++ = static_cast<uint8_t>(v | 0x80);
     v >>= 7;
   }
-  o.push_back(static_cast<char>(v));
+  *p++ = static_cast<uint8_t>(v);
+  return p;
+}
+
+// THE wire layout of one list row: one `repeated KeyValue kvs = 2` element
+// of an etcd RangeResponse (mvccpb: key=1, create_revision=2,
+// mod_revision=3, version=4, value=5; create = mod = rev, version = 1 —
+// what the python shim's to_kv builds). kb_mvcc_list_wire (the store's own
+// scan) and kb_wire_gather (rows of the device mirror's host arrays) both
+// encode through these two functions and nothing else does.
+static inline size_t wire_row_body(size_t kl, size_t vl, uint64_t rev) {
+  return 1 + varint_len(kl) + kl + 1 + varint_len(vl) + vl +
+         2 * (1 + varint_len(rev)) + 2;
+}
+
+static inline size_t wire_row_size(size_t kl, size_t vl, uint64_t rev) {
+  size_t body = wire_row_body(kl, vl, rev);
+  return 1 + varint_len(body) + body;
+}
+
+// Writes exactly wire_row_size(kl, vl, rev) bytes at p; returns the end.
+static inline uint8_t* wire_put_row(uint8_t* p, const void* k, size_t kl,
+                                    const void* v, size_t vl, uint64_t rev) {
+  *p++ = 0x12;  // RangeResponse.kvs
+  p = put_varint(p, wire_row_body(kl, vl, rev));
+  *p++ = 0x0A;  // KeyValue.key
+  p = put_varint(p, kl);
+  if (kl) memcpy(p, k, kl);
+  p += kl;
+  *p++ = 0x10;  // create_revision
+  p = put_varint(p, rev);
+  *p++ = 0x18;  // mod_revision
+  p = put_varint(p, rev);
+  *p++ = 0x20;  // version
+  *p++ = 1;
+  *p++ = 0x2A;  // value
+  p = put_varint(p, vl);
+  if (vl) memcpy(p, v, vl);
+  return p + vl;
 }
 
 extern "C" {
@@ -1243,9 +1281,8 @@ uint64_t kb_mvcc_list_page(void* s, const uint8_t* start, size_t slen,
 }
 
 // One MVCC list page as READY protobuf wire bytes: the `repeated KeyValue
-// kvs = 2` field of an etcd RangeResponse (mvccpb layout: key=1,
-// create_revision=2, mod_revision=3, version=4, value=5; create=mod=rev,
-// version=1 — matching the python shim). The caller prepends the scalar
+// kvs = 2` field of an etcd RangeResponse, each row by wire_put_row above
+// (the one definition of the layout). The caller prepends the scalar
 // fields (header/more/count) encoded by python-protobuf; field order is
 // free in protobuf, so concatenation is a valid message. *out is malloc'd
 // (kb_free it). Returns rows encoded.
@@ -1270,23 +1307,10 @@ uint64_t kb_mvcc_list_wire(void* s, const uint8_t* start, size_t slen,
   auto emit = [&](const char* k, size_t kl, const std::string& v,
                   uint64_t rev) -> bool {
     if (row >= max_rows || blob.size() >= byte_cap) return false;
-    size_t rvl = varint_len(rev);
-    size_t body = 1 + varint_len(kl) + kl + 1 + varint_len(v.size()) +
-                  v.size() + 2 * (1 + rvl) + 2;
-    blob.push_back(0x12);  // RangeResponse.kvs
-    put_varint(blob, body);
-    blob.push_back(0x0A);  // KeyValue.key
-    put_varint(blob, kl);
-    blob.append(k, kl);
-    blob.push_back(0x10);  // create_revision
-    put_varint(blob, rev);
-    blob.push_back(0x18);  // mod_revision
-    put_varint(blob, rev);
-    blob.push_back(0x20);  // version
-    blob.push_back(1);
-    blob.push_back(0x2A);  // value
-    put_varint(blob, v.size());
-    blob.append(v);
+    size_t at_byte = blob.size();
+    blob.resize(at_byte + wire_row_size(kl, v.size(), rev));
+    wire_put_row(reinterpret_cast<uint8_t*>(&blob[at_byte]), k, kl, v.data(),
+                 v.size(), rev);
     ++row;
     return true;
   };
@@ -1305,6 +1329,65 @@ uint64_t kb_mvcc_list_wire(void* s, const uint8_t* start, size_t slen,
   memcpy(next_start, resume.data(), resume.size());
   *next_len = resume.size();
   return rows;
+}
+
+// Rows of the device mirror's host arrays as the same READY wire bytes: a
+// pure function over plain arrays (no Store*), so a ctypes caller runs it
+// with the GIL released and concurrent listers gather in parallel. A reply
+// is a sequence of RUNS, each the rows [0, n) of one source of arrays, 8
+// words a run: keys (the decoded key matrix of the source's visible rows),
+// the matrix's row stride, key_lens (int32), revs (uint64) — all three
+// row-aligned — then val_arena, val_offsets (uint64) and rows (int64): row
+// i takes its value from val_arena[val_offsets[rows[i]] ..
+// val_offsets[rows[i] + 1]] (one partition's arena, by row index), and n.
+// One call writes the whole reply, so the overlay's entries spliced
+// between the mirror's runs cost no further round through the caller.
+// Returns the bytes the runs need; they are written to out only when that
+// fits out_cap — a short buffer is never written past, the caller reads
+// the size and calls again.
+size_t kb_wire_gather(const uint64_t* runs, size_t n_runs, uint8_t* out,
+                      size_t out_cap) {
+  struct Run {
+    const uint8_t* keys;
+    size_t key_stride;
+    const int32_t* key_lens;
+    const uint64_t* revs;
+    const uint8_t* val_arena;
+    const uint64_t* val_offsets;
+    const int64_t* rows;
+    size_t n;
+  };
+  auto run_at = [runs](size_t r) {
+    const uint64_t* w = runs + 8 * r;
+    return Run{reinterpret_cast<const uint8_t*>(w[0]),
+               static_cast<size_t>(w[1]),
+               reinterpret_cast<const int32_t*>(w[2]),
+               reinterpret_cast<const uint64_t*>(w[3]),
+               reinterpret_cast<const uint8_t*>(w[4]),
+               reinterpret_cast<const uint64_t*>(w[5]),
+               reinterpret_cast<const int64_t*>(w[6]),
+               static_cast<size_t>(w[7])};
+  };
+  size_t need = 0;
+  for (size_t r = 0; r < n_runs; ++r) {
+    Run u = run_at(r);
+    for (size_t i = 0; i < u.n; ++i) {
+      size_t vl = u.val_offsets[u.rows[i] + 1] - u.val_offsets[u.rows[i]];
+      need += wire_row_size(static_cast<size_t>(u.key_lens[i]), vl, u.revs[i]);
+    }
+  }
+  if (need > out_cap) return need;
+  uint8_t* p = out;
+  for (size_t r = 0; r < n_runs; ++r) {
+    Run u = run_at(r);
+    for (size_t i = 0; i < u.n; ++i) {
+      uint64_t vo = u.val_offsets[u.rows[i]];
+      p = wire_put_row(p, u.keys + i * u.key_stride,
+                       static_cast<size_t>(u.key_lens[i]), u.val_arena + vo,
+                       u.val_offsets[u.rows[i] + 1] - vo, u.revs[i]);
+    }
+  }
+  return need;
 }
 
 // Paged columnar export for the kbstored EXPORT op (the bulk path that lets

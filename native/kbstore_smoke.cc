@@ -56,6 +56,8 @@ uint64_t kb_prune(void* s, uint64_t keep_after_ts);
 int kb_dump_wire(void* s, uint8_t** out, size_t* out_len, uint64_t* ts_out);
 int kb_apply_record(void* s, const uint8_t* rec, size_t len, int reset,
                     uint64_t* applied_ts);
+size_t kb_wire_gather(const uint64_t* runs, size_t n_runs, uint8_t* out,
+                      size_t out_cap);
 }
 
 #define CHECK(cond)                                                     \
@@ -201,8 +203,67 @@ static void smoke_wal_cycle(const char* dir) {
   kb_close(s);
 }
 
+// The mirror's wire gather: three rows of a five-row arena, picked out of
+// order of the arena (rows index the value offsets, not the key matrix), an
+// empty value, a revision that needs a multi-byte varint, as TWO runs of
+// one source (rows 0-1, then row 2: what a spliced overlay entry makes of a
+// reply) — into heap buffers of EXACTLY the size it asks for, and one byte
+// short of it, so ASan sees any write past the end and any read past the
+// inputs.
+static void smoke_wire_gather() {
+  const size_t stride = 8;
+  uint8_t* keys = static_cast<uint8_t*>(calloc(3, stride));
+  memcpy(keys, "/a", 2);
+  memcpy(keys + stride, "/bb", 3);
+  memcpy(keys + 2 * stride, "/ccccccc", 8);  // a key as wide as the matrix
+  int32_t* lens = static_cast<int32_t*>(malloc(3 * sizeof(int32_t)));
+  lens[0] = 2, lens[1] = 3, lens[2] = 8;
+  uint64_t* revs = static_cast<uint64_t*>(malloc(3 * sizeof(uint64_t)));
+  revs[0] = 7, revs[1] = 300, revs[2] = (1ULL << 40) + 5;
+  const char* vals = "v0v1-longerv3";  // rows 0..4: "v0" "v1-longer" "" "v3" ""
+  uint8_t* arena = static_cast<uint8_t*>(malloc(13));
+  memcpy(arena, vals, 13);
+  uint64_t* offs = static_cast<uint64_t*>(malloc(6 * sizeof(uint64_t)));
+  offs[0] = 0, offs[1] = 2, offs[2] = 11, offs[3] = 11, offs[4] = 13,
+  offs[5] = 13;
+  int64_t* rows = static_cast<int64_t*>(malloc(3 * sizeof(int64_t)));
+  rows[0] = 1, rows[1] = 2, rows[2] = 4;
+  auto word = [](const void* p) { return reinterpret_cast<uint64_t>(p); };
+  uint64_t* runs = static_cast<uint64_t*>(malloc(16 * sizeof(uint64_t)));
+  const uint64_t table[16] = {
+      word(keys), stride, word(lens), word(revs),
+      word(arena), word(offs), word(rows), 2,
+      word(keys + 2 * stride), stride, word(lens + 2), word(revs + 2),
+      word(arena), word(offs), word(rows + 2), 1};
+  memcpy(runs, table, sizeof(table));
+
+  size_t need = kb_wire_gather(runs, 2, nullptr, 0);
+  // row: 0x12 len | 0x0A kl key | 0x10 rev | 0x18 rev | 0x20 1 | 0x2A vl val
+  size_t want = (2 + 2 + 2 + 2 * 2 + 2 + 2 + 9) + (2 + 2 + 3 + 2 * 3 + 2 + 2) +
+                (2 + 2 + 8 + 2 * 7 + 2 + 2);
+  if (need != want) {
+    fprintf(stderr, "wire gather size %zu != %zu\n", need, want);
+    abort();
+  }
+  uint8_t* small = static_cast<uint8_t*>(malloc(need - 1));
+  if (kb_wire_gather(runs, 2, small, need - 1) != need)
+    abort();  // too short: the size again, nothing written
+  free(small);
+  uint8_t* out = static_cast<uint8_t*>(malloc(need));
+  if (kb_wire_gather(runs, 2, out, need) != need) abort();
+  const uint8_t first[] = {0x12, 21,   0x0A, 2,   '/', 'a', 0x10, 7, 0x18,
+                           7,    0x20, 1,    0x2A, 9,  'v', '1',  '-'};
+  if (memcmp(out, first, sizeof(first)) != 0 || out[need - 2] != 0x2A ||
+      out[need - 1] != 0)
+    abort();
+  if (kb_wire_gather(runs, 0, out, need) != 0) abort();
+  free(out), free(runs), free(rows), free(offs), free(arena), free(revs),
+      free(lens), free(keys);
+}
+
 int main(int argc, char** argv) {
   smoke_memory_engine();
+  smoke_wire_gather();
   if (argc > 1) smoke_wal_cycle(argv[1]);
   printf("SMOKE OK\n");
   return 0;

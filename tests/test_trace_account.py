@@ -281,6 +281,48 @@ def test_tpu_read_records_delta_overlay_and_accounts_for_itself(tpu_server,
                       rpc=RANGE) == 5
 
 
+def test_a_wire_range_tiles_its_span_and_counts_its_reply_path(tpu_server):
+    """An unpaged default-sort Range leaves the mirror as wire bytes
+    (``TpuScanner.list_wire``): the stages a rows Range records, every one
+    still observed — ``host_copy`` around the gather, ``response_encode``
+    around the scalar header — one ``device_dispatch`` a dispatch, and
+    ``kb_range_reply_total`` says which path a list took."""
+    client, info_port, _backend, _ep = tpu_server
+    _list(client)  # warm
+    before = _metrics(info_port)
+    best = 1e9
+    for _ in range(4):
+        wire = _list(client)
+        span = _last_span(info_port)
+        best = min(best, _unaccounted_ms(span))
+    names = [s["stage"] for s in span["stages"]]
+    assert names == ["endpoint_recv", "queue_wait", "delta_overlay",
+                     "device_dispatch", "device_compute", "host_copy",
+                     "result_deliver", "response_encode"]
+    assert best < 0.5
+    rows = client.range_(rpc_pb2.RangeRequest(
+        key=b"/registry/pods/", range_end=b"/registry/pods0", keys_only=True))
+    assert [kv.key for kv in rows.kvs] == [kv.key for kv in wire.kvs]
+    assert wire.count == rows.count == len(wire.kvs) and not wire.more
+    page = _list(client, limit=5)  # a small page: the host path, still wire
+    assert [kv.key for kv in page.kvs] == [kv.key for kv in wire.kvs[:5]]
+    after = _metrics(info_port)
+
+    def moved(name, **labels):
+        return prom.delta(after, before, name, **labels)
+
+    assert moved("kb_range_reply_total", path="wire") == 5
+    assert moved("kb_range_reply_total", path="rows") == 1
+    assert {lb["path"] for lb, _v in after["kb_range_reply_total"]} == {
+        "wire", "rows"}
+    for stage, n in (("host_copy", 6), ("response_encode", 6),
+                     ("device_dispatch", 5), ("device_compute", 5),
+                     ("host_scan", 1)):
+        assert moved("kb_rpc_stage_seconds_count", stage=stage, rpc=RANGE) == n
+    # no device read without its dispatch: what check.device_account holds
+    assert moved("kb_sched_batch_size_sum") - moved("kb_sched_batch_size_count") == 0
+
+
 def test_small_page_is_the_host_scanners_and_says_so(tpu_server):
     client, info_port, _backend, _ep = tpu_server
     resp = _list(client, limit=5)  # under the host-limit threshold

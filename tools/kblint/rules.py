@@ -519,8 +519,8 @@ _HOST_CONVERTERS = {
 #: storage/tpu/ — everything else must go through `_host_pull` (which both
 #: blocks correctly and meters the bytes for the transfer-budget tests)
 _HOST_TRANSFER_ALLOWED = {
-    "_host_pull", "_materialize_visible", "_host_visible",
-    "_host_visible_batch", "_pallas_ttl8", "_pull_victim_indices",
+    "_host_pull", "_materialize_visible", "_materialize_wire",
+    "_host_visible", "_host_visible_batch", "_pallas_ttl8", "_pull_victim_indices",
     "merge_partitions_incremental",
     # the compaction pipeline's named funnels (docs/compaction.md): the
     # victim-only decode point and the stored-domain mirror-maintenance
@@ -558,7 +558,7 @@ class HostTransferOnlyAtMaterializationPoints(Rule):
     rule_id = "KB111"
     summary = ("storage/tpu/: jax.device_get / host conversion of device "
                "arrays only inside the named materialization points "
-               "(_host_pull, _materialize_visible, _host_visible*, "
+               "(_host_pull, _materialize_visible/_wire, _host_visible*, "
                "_pallas_ttl8, _pull_victim_indices)")
 
     def applies(self, relpath: str) -> bool:
@@ -621,6 +621,9 @@ _DECODE_PRIMITIVE_FUNNELS = {"decoded_keys", "user_key"}
 _DECODE_FUNNEL_CALLERS = {
     "materialize", "flat_arrays", "merge_partitions_incremental",
     "_compact_victim_rows", "_materialize_visible",
+    # the wire path's twin of ``materialize``: the visible rows of one
+    # partition, decoded for ``kb_wire_gather`` (never the whole mirror)
+    "wire_source",
 }
 
 
@@ -677,7 +680,7 @@ class DecodeOnlyAtMaterializationFunnels(Rule):
                     yield node, (
                         f"decoded_keys(){where}: decoded key bytes only "
                         "leave the mirror through the named materialization"
-                        "/rebuild paths (materialize, flat_arrays, "
+                        "/rebuild paths (materialize, wire_source, flat_arrays, "
                         "merge_partitions_incremental, _compact_victim_rows)"
                     )
 
