@@ -151,9 +151,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "(reference endpoint secure modes, config.go:159)")
     p.add_argument("--sched-depth", type=int, default=4,
                    help="request scheduler: bounded in-flight device scan "
-                        "dispatches (pipelined; bench pipelined_rows_per_sec "
-                        "saturates by ~8). 0 = auto: sized from the tracer's "
-                        "measured dispatch-RTT EWMA, clamped 2-16")
+                        "dispatches (pipelined). 0 = auto: sized from the "
+                        "tracer's measured dispatch-RTT EWMA, clamped 2-16")
     p.add_argument("--trace-slow-ms", type=float, default=500.0,
                    help="request tracer: RPCs slower than this land in the "
                         "slow-request log (/debug/traces \"slow\") and a "
@@ -168,13 +167,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="request scheduler: max distinct ready Range/Count "
                         "requests drained into one dispatch slot — over the "
                         "TPU engine they become ONE query-batched kernel "
-                        "launch (bench batched_rows_per_sec); 1 disables")
+                        "launch; 1 disables")
     p.add_argument("--sched-write-batch", type=int, default=8,
                    help="request scheduler: max queued write ops (create/"
                         "update/delete) drained into one group commit — a "
                         "contiguous revision block + ONE engine round trip "
-                        "with per-op conflict demux (bench "
-                        "write_txns_per_sec; docs/writes.md); 1 disables")
+                        "with per-op conflict demux (docs/writes.md); "
+                        "1 disables")
     p.add_argument("--grpc-workers", type=int, default=256,
                    help="gRPC worker threads; each open watch stream holds one")
     p.add_argument("--aio-port", type=int, default=0,

@@ -12,11 +12,12 @@ Three pieces, mirroring the reference's robustness posture (the whole
   storage error classes (latency / definite error / *uncertain*
   outcome) under any engine.
 
-The chaos runner (``make bench-cluster FAULTS=<preset>``) replays a
-workload against a fault-armed server and proves the keystone invariant:
-every client-acknowledged write is present in a final authoritative scan
-and every definite error is absent — ambiguous outcomes may be either
-(the linearizability discipline of tests/test_linearizability.py).
+The chaos runner (``python -m kubebrain_tpu.workload.runner --faults
+<preset>``) replays a workload against a fault-armed server and proves
+the keystone invariant: every client-acknowledged write is present in a
+final authoritative scan and every definite error is absent — ambiguous
+outcomes may be either (the linearizability discipline of
+tests/test_linearizability.py).
 """
 
 from .inject import FaultyStorage, wrap_engine

@@ -36,15 +36,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-import os as _os
-
-# Rows per grid step. Grid iteration overhead dominates at small tiles (a
-# 20M-row scan is ~20k steps at 1024) and VMEM per step is only ~66B * TILE.
-# tools/tile_sweep.py re-measures the optimum on the attached chip.
-LANE_TILE = int(_os.environ.get("KB_PALLAS_TILE", "4096"))
-if LANE_TILE <= 0 or LANE_TILE % 128:
-    raise ValueError(
-        f"KB_PALLAS_TILE={LANE_TILE} must be a positive multiple of 128 lanes")
+# Rows per grid step (a multiple of 128 lanes). Grid iteration overhead
+# dominates at small tiles (a 20M-row scan is ~20k steps at 1024) and VMEM
+# per step is only ~66B * TILE.
+LANE_TILE = 4096
 
 
 def flip_sign(chunks: np.ndarray) -> np.ndarray:

@@ -26,13 +26,3 @@ def make_mesh(
 
 def partition_spec(mesh: Mesh, *axis_names: str | None) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec(*axis_names))
-
-
-def shard_rows(mesh: Mesh, arr, axis: str = "part") -> jax.Array:
-    """Put an array on the mesh sharded along its leading axis."""
-    spec = PartitionSpec(axis, *(None,) * (arr.ndim - 1))
-    return jax.device_put(arr, NamedSharding(mesh, spec))
-
-
-def replicate(mesh: Mesh, arr) -> jax.Array:
-    return jax.device_put(arr, NamedSharding(mesh, PartitionSpec()))

@@ -1,13 +1,13 @@
 """Device-aware request scheduler (the service→storage admission layer).
 
-The serving path used to issue one blocking device scan per Range RPC;
-the same kernel sustains ~3.8x the single-dispatch rate when dispatches
-are pipelined (bench.py pipelined_rows_per_sec). This package closes that
-gap at the serving layer: concurrent Range/Count requests are queued into
-APF-style priority lanes, coalesced when identical, and dispatched with a
-bounded in-flight depth so the device pipeline stays full while the host
-overlays deltas for earlier requests. Overload is handled by bounded
-queues + deadline shedding (etcd ``ResourceExhausted`` on the wire).
+The serving path used to issue one blocking device scan per Range RPC,
+although JAX dispatches asynchronously and the device can hold several
+scans in flight. This package pipelines them at the serving layer:
+concurrent Range/Count requests are queued into APF-style priority lanes,
+coalesced when identical, and dispatched with a bounded in-flight depth so
+the device pipeline stays full while the host overlays deltas for earlier
+requests. Overload is handled by bounded queues + deadline shedding (etcd
+``ResourceExhausted`` on the wire).
 
 See docs/scheduler.md for the queue model, lanes, and shedding policy.
 """

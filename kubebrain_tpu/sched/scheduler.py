@@ -9,9 +9,8 @@ Queue model (docs/scheduler.md):
 - ONE dispatcher thread pops strictly by lane priority and hands requests
   to a worker pool whose in-flight count is bounded by ``depth``. Workers
   block on their own result, so up to ``depth`` device dispatches are in
-  flight at once — the async-dispatch pipelining the bench proves out
-  (bench.py pipelined_rows_per_sec), with host-side overlay/materialize
-  work overlapping device compute for neighbors;
+  flight at once (JAX's async dispatch), with host-side
+  overlay/materialize work overlapping device compute for neighbors;
 - identical queued requests coalesce: followers attach to the queued
   leader and share its one execution. This is revision-safe for rev-0
   reads because the leader resolves its read revision at *execution*
@@ -253,7 +252,7 @@ class RequestScheduler:
     """Admission + coalescing + bounded-depth pipelined dispatch.
 
     ``backend`` may be None for generic use (``submit``/``submit_async``
-    only, e.g. the bench microharness).
+    only, as tests/test_sched.py drives it).
     """
 
     def __init__(self, backend: Any = None,

@@ -492,7 +492,7 @@ class TpuScanner(Scanner):
         self._gauge_regs: list[tuple[str, dict]] = []
         # merge accounting (also exported as kb_mirror_merge_* metrics):
         # steady state must show merge_rows_total accounting every delta row
-        # with full_rebuild_total flat (bench write phase asserts this)
+        # with full_rebuild_total flat (tests/test_write_batch.py asserts it)
         self.merge_count = 0
         self.merge_rows_total = 0
         self.full_rebuild_total = 0
@@ -512,9 +512,9 @@ class TpuScanner(Scanner):
         self.merge_escalations_total = 0
         self._merge_max_retries = 4
         # compaction accounting (docs/compaction.md; also exported through
-        # encoding_stats() and the kb_compact_* metrics): the bench compact
-        # phase asserts full_rebuild_total stays flat while compact_count
-        # advances — the steady path never decodes/re-encodes the keyspace
+        # encoding_stats() and the kb_compact_* metrics): full_rebuild_total
+        # stays flat while compact_count advances — the steady path never
+        # decodes/re-encodes the keyspace (tests/test_compact_device.py)
         self.compact_count = 0
         self.compact_victims_total = 0
         self.compact_survivor_rows_total = 0
@@ -522,11 +522,6 @@ class TpuScanner(Scanner):
         self.compact_escalations_total = 0
         self.compact_errors = 0
         self._compact_last_error: Exception | None = None
-        # bench/legacy comparator (make bench-compact): force the mirror
-        # half onto the decode-everything full-rebuild rung — the
-        # pre-stored-domain compact shape — so the stored-domain win is
-        # measurable on identical marking + GC work. Never set in serving.
-        self.compact_force_full = False
         # True while a compaction holds _merge_lock across its whole pass
         # (mark → gc → mirror apply): read-path threshold merges SKIP
         # instead of blocking on the lock for the compact's duration —
@@ -638,7 +633,7 @@ class TpuScanner(Scanner):
         return float(total)
 
     def encoding_stats(self) -> dict:
-        """Mirror footprint of the PUBLISHED mirror for bench reports:
+        """Mirror footprint of the PUBLISHED mirror, for the tests:
         per-row device bytes and the key-compression ratio (raw packed key
         bytes / stored key bytes; 1.0 when the mirror is raw)."""
         mirror = self._mirror
@@ -650,11 +645,9 @@ class TpuScanner(Scanner):
         cap = mirror.keys_host.shape[0] * mirror.keys_host.shape[1]
         return {
             "rows": rows,
-            # exact per-valid-row bytes — same definition as
-            # bench.key_encoding_info, so BENCH and MULTICHIP JSONs track
-            # one comparable "mirror_bytes_per_row" series; the padded
-            # variant (includes pow2 partition-capacity rounding) is what
-            # the device actually holds
+            # exact per-valid-row bytes; the padded variant (includes
+            # pow2 partition-capacity rounding) is what the device
+            # actually holds
             "mirror_bytes_per_row": float(per_row),
             "mirror_bytes_per_row_padded": round(per_row * cap / rows, 2)
             if rows else 0.0,
@@ -1050,8 +1043,8 @@ class TpuScanner(Scanner):
         ``_mlock`` and keeps every row appended after the snapshot in the
         successor overlay. Falls back to the full re-partitioning (and,
         when a delta key no longer fits the dictionary, re-dictionary)
-        rebuild — counted separately so a bench can assert the steady
-        state never takes it."""
+        rebuild — counted separately (``full_rebuild_total``), because
+        the steady state must never take it."""
         plane = self._fault_plane
         if plane is not None and plane.merge_fault():
             # chaos: the merge fails here, BEFORE any state mutation —
@@ -1141,7 +1134,7 @@ class TpuScanner(Scanner):
                                         encode=self._encode), True
 
     def publish(self) -> None:
-        """Force the mirror fully up to date (bench/startup hook)."""
+        """Force the mirror fully up to date (the tests' hook)."""
         self._ensure_published(full=True)
 
     # -------------------------------------------------------------- queries
@@ -2194,7 +2187,7 @@ class TpuScanner(Scanner):
         ts = self._store.get_timestamp_oracle()
         # an overflowed delta already commits us to the full rebuild —
         # don't pay the stored-domain gather just to discard it
-        go_full = self.compact_force_full or (n_rows and overflow)
+        go_full = n_rows and overflow
         m = (None if go_full
              else compact_partitions_stored(mirror, keep_idx, self._mesh, ts))
         if m is not None and n_rows:
@@ -2237,8 +2230,8 @@ class TpuScanner(Scanner):
         """The width-drift/dict-overflow fallback: decode every surviving
         row (``flat_arrays`` is the allowed whole-mirror decode path), drop
         the victims, merge the raw delta, re-partition and (when enabled)
-        re-dictionary. Steady-state compaction never comes here — the
-        compact bench asserts ``full_rebuild_total`` stays flat."""
+        re-dictionary. Steady-state compaction never comes here:
+        ``full_rebuild_total`` stays flat."""
         flat = mirror.flat_arrays()
         keepm = np.ones(len(flat[0]), dtype=bool)
         base = 0

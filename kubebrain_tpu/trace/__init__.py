@@ -201,7 +201,7 @@ class Tracer:
         self._annotate = factory
 
     def reset(self) -> None:
-        """Drop recorded traces and EWMAs (tests / bench isolation)."""
+        """Drop recorded traces and EWMAs (test isolation)."""
         with self._lock:
             self._ring.clear()
             self._slow.clear()
@@ -259,7 +259,7 @@ class Tracer:
             # span-attached stage histograms are emitted here, once, after
             # the clock stops: an inline prometheus observe per stage
             # boundary costs ~tens of µs that would show up as unattributed
-            # time *inside* the span (and as tracing overhead on the bench).
+            # time *inside* the span.
             # One observation per stage NAME: a stage entered twice (a
             # Count's delta_overlay, before and after the kernel) is one
             # share of this RPC, so a stage's mean is per RPC that had it

@@ -2,9 +2,8 @@
 
 Every server start otherwise recompiles every kernel, every pow2 index
 bucket and every query-batch width. One rule, applied by every entry point
-that uses JAX (``cli.build_endpoint``, ``bench.main``, ``__graft_entry__``,
-``tools/tile_sweep.py``, ``tests/conftest.py``) before its first backend
-touch: ``JAX_COMPILATION_CACHE_DIR`` decides when it is set — JAX reads it
+that uses JAX (``cli.build_endpoint``, ``__graft_entry__``,
+``tests/conftest.py``) before its first backend touch: ``JAX_COMPILATION_CACHE_DIR`` decides when it is set — JAX reads it
 itself — and otherwise the cache lives in ``<checkout>/.jax_cache``. The
 directory is part of the cache key, so it never depends on a pid, a port,
 a timestamp or a temp dir.

@@ -21,8 +21,7 @@ The report reconciles client-side RPC counts against the server's own
 kb_watch_backlog series) — a replay whose numbers don't add up is a
 harness bug, not a benchmark.
 
-CLI: ``python -m kubebrain_tpu.workload.runner --nodes 5000`` (or
-``make bench-cluster N=5000``).
+CLI: ``python -m kubebrain_tpu.workload.runner --nodes 5000``.
 """
 
 from __future__ import annotations
@@ -1464,8 +1463,7 @@ class WorkloadRunner:
         # box without a core per process the topology cannot express its
         # parallelism (leader + followers + clients time-share the same
         # cores, so the extra processes are pure overhead): the bar is
-        # stamped pending_multicore there, the same machine-visible
-        # discipline as the pending_tpu hardware bars (docs/multichip.md)
+        # stamped pending_multicore there
         base_rows = float(
             os.environ.get("KB_REPLICA_BASELINE_ROWS", 0) or 0)
         cores = os.cpu_count() or 1

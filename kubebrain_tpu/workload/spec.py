@@ -180,9 +180,9 @@ class WorkloadSpec:
     # ------------------------------------------------------------ factories
     @classmethod
     def for_cluster(cls, nodes: int, **overrides: Any) -> "WorkloadSpec":
-        """The ``make bench-cluster N=...`` shape: namespaces scale with the
-        node count, and at >= 100 nodes the relist storms are expected to
-        form query batches (kb_sched_batch_size must move)."""
+        """The runner's default ``--scenario cluster`` shape: namespaces
+        scale with the node count, and at >= 100 nodes the relist storms are
+        expected to form query batches (kb_sched_batch_size must move)."""
         namespaces = max(4, min(100, nodes // 10))
         bounds = overrides.pop(
             "bounds",

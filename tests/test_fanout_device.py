@@ -159,7 +159,8 @@ def test_progress_mark_ordering_across_block_delivery():
     """post_progress after a block stream lands AFTER every event of the
     block on the subscriber queue (FIFO carries the ordering), with the
     hub routed through the device block path."""
-    hub = WatcherHub(fanout_matcher=DeviceFanout())
+    matcher = DeviceFanout()
+    hub = WatcherHub(fanout_matcher=matcher)
     assert hub.prefers_blocks
     qs = {}
     for i in range(8):
@@ -174,6 +175,7 @@ def test_progress_mark_ordering_across_block_delivery():
         for i in range(512)
     ]
     hub.stream(batch)
+    assert matcher.stats["blocks"] == 1, matcher.stats
     top = max(e.revision for e in batch)
     for wid in qs:
         hub.post_progress(wid, top)

@@ -21,18 +21,16 @@ def test_entry_compiles_and_runs():
 
 @pytest.mark.parametrize("n", [2, 8])
 def test_dryrun_multichip_serves_and_emits_metric(n, capsys):
-    """The dry run's tail is now the measured ``multichip_rows_per_sec``
-    metric from real traffic served through the scheduler at mesh sizes
-    {1, n} — not the old ``dryrun ok: ...`` line."""
+    """The dry run's last line records real traffic served through the
+    scheduler at mesh sizes {1, n}, byte-identical across them, with the
+    platform stamp — and no rate: a CPU run gives none."""
     import __graft_entry__ as g
 
     g.dryrun_multichip(n)
     tail = capsys.readouterr().out.strip().splitlines()[-1]
     rec = json.loads(tail)
-    assert rec["metric"] == "multichip_rows_per_sec"
-    assert rec["value"] > 0
+    assert rec["mesh_sizes"] == [1, n]
+    assert rec["byte_identical"] is True
+    assert rec["served_through_scheduler"] is True
     assert rec["platform"]["platform"] == "cpu"
-    assert rec["detail"]["mesh_sizes"] == ([1, n] if n > 1 else [1])
-    assert rec["detail"]["byte_identical"] is True
-    assert rec["detail"]["served_through_scheduler"] is True
-    assert str(n) in rec["detail"]["rows_per_sec"]
+    assert not {"value", "rows_per_sec", "metric", "detail"} & set(rec)

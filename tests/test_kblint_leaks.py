@@ -593,7 +593,7 @@ def test_kb126_with_statement_discharges_by_construction():
 # ------------------------------------------------------ machinery contracts
 
 def test_leak_rules_only_scope_kubebrain_package():
-    """tools/ and bench.py feed the call graph but leak findings are scoped
+    """tools/ feeds the call graph but leak findings are scoped
     to the serving tree, like the other deep rules."""
     assert leak_ids({"tools/helper.py": KB123_LEAKY}) == []
 

@@ -1,7 +1,6 @@
 """Multichip sharded serving tests (the promoted `part`-axis path).
 
-What the MULTICHIP_r01–r05 dry runs never proved, proven here on the
-8-device virtual CPU mesh (conftest.py):
+Proven here on the 8-device virtual CPU mesh (conftest.py):
 
 - sharded-vs-single-device byte identity under LIVE delta overlays and
   mixed Range/Count batches, across both the jnp and pallas-interpret

@@ -12,9 +12,10 @@
 #   2. make typecheck   — mypy (or compileall fallback)
 #   3. scheduler gate   — sched semantics + query-batched scan tests
 #                         (batched == sequential byte-identical, incl. the
-#                         batched Pallas kernel cases) + bench-smoke; fast,
-#                         and a scheduler regression should fail before the
-#                         long tier-1 run, not 10 minutes into it
+#                         batched Pallas kernel cases) + the write-path
+#                         group commit under the field-write sanitizer;
+#                         fast, and a scheduler regression should fail
+#                         before the long tier-1 run, not 10 minutes into it
 #   4. observability    — trace/span tests + a live-server smoke: one Range
 #                         must populate /debug/traces and the
 #                         kb_rpc_stage_seconds histogram
@@ -29,16 +30,17 @@
 #                         (conftest's xla_force_host_platform_device_count):
 #                         sharded-vs-single byte identity, O(visible-rows)
 #                         host transfer, dirty-shard-only republish, the
-#                         served dry-run emitting multichip_rows_per_sec,
-#                         and the encoded-mirror differential suite
+#                         served dry run (mesh {1, n} byte-identical
+#                         through the scheduler), and the encoded-mirror
+#                         differential suite
 #                         (encoded == raw byte-identity incl. overlays,
 #                         adversarial bounds, pallas-vs-jnp, P=N/P=2N)
 #   8. compaction       — device-side stored-domain compaction
 #                         (docs/compaction.md): differential vs the
 #                         engine-generic compactor, victim-only decode,
-#                         dirty-shard-only republish, retry→escalate, and
-#                         a small-N bench-compact smoke asserting store +
-#                         serving byte-identity vs the sequential oracle
+#                         dirty-shard-only republish, retry→escalate,
+#                         and the full-rebuild rung against the same
+#                         generic-engine oracle
 #   9. replica          — read scale-out (docs/replication.md): follower
 #                         fence-read correctness (byte-identical to the
 #                         leader under concurrent writers), bounded-
@@ -61,11 +63,9 @@
 #                         segment-index oracles under churn, the sharded
 #                         wat-table identity on 8 simulated devices,
 #                         NUL-bound single-key exactness, overflow regrow,
-#                         version-regression rebuild, KB127 confinement
-#                         self-tests (via step 1), and bench-fanout at the
-#                         full 10k-watcher acceptance config enforcing the
-#                         >=2x block-vs-per-batch bar plus the live-hub
-#                         lag p99 bar
+#                         version-regression rebuild, the hub's block
+#                         route, and KB127 confinement self-tests (via
+#                         step 1)
 #  12. tier-1 pytest    — the ROADMAP.md verify command
 # Run from anywhere; operates on the repo this script lives in.
 
@@ -83,7 +83,7 @@ env JAX_PLATFORMS=cpu python -m pytest tests/test_kblint.py \
 echo "=== [2/12] make typecheck"
 make typecheck || exit 1
 
-echo "=== [3/12] scheduler semantics + query-batched scan + write group commit + bench-smoke (CPU fallback)"
+echo "=== [3/12] scheduler semantics + query-batched scan + write group commit (+ field-write sanitizer)"
 env JAX_PLATFORMS=cpu python -m pytest tests/test_sched.py \
     tests/test_sched_batch.py tests/test_scan_pallas.py \
     tests/test_write_batch.py -q -m 'not slow' \
@@ -95,7 +95,6 @@ env JAX_PLATFORMS=cpu python -m pytest tests/test_sched.py \
 env JAX_PLATFORMS=cpu KB_FIELDCHECK=1 KB_FIELDCHECK_STRICT=1 \
     python -m pytest tests/test_write_batch.py -q -m 'not slow' \
     -p no:cacheprovider || exit 1
-make bench-smoke || exit 1
 
 echo "=== [4/12] request tracing: span tests + live-server /debug/traces smoke"
 env JAX_PLATFORMS=cpu python -m pytest tests/test_trace.py -q -m 'not slow' \
@@ -115,12 +114,10 @@ env JAX_PLATFORMS=cpu python -m pytest tests/test_multichip.py \
     tests/test_encode.py \
     tests/test_graft_entry.py -q -m 'not slow' -p no:cacheprovider || exit 1
 
-echo "=== [8/12] device-side compaction: stored-domain differential + victim-only decode + bench-compact smoke"
+echo "=== [8/12] device-side compaction: stored-domain differential + victim-only decode + full-rebuild rung"
 env JAX_PLATFORMS=cpu python -m pytest tests/test_compact_device.py \
     tests/test_compact_faults.py -q -m 'not slow' \
     -p no:cacheprovider || exit 1
-env JAX_PLATFORMS=cpu KB_BENCH_METRIC=compact KB_BENCH_KEYS=4000 \
-    python bench.py || exit 1
 
 echo "=== [9/12] replica: fence reads + bounded staleness + watch resume + two-replica gRPC smoke"
 env JAX_PLATFORMS=cpu python -m pytest tests/test_replica.py -q -m 'not slow' \
@@ -138,18 +135,10 @@ env JAX_PLATFORMS=cpu KB_SANITIZE=1 KB_SANITIZE_STRICT=1 \
     python -m pytest tests/test_faults.py -q -m 'not slow' \
     -p no:cacheprovider || exit 1
 
-echo "=== [11/12] watch fan-out: block-batched dispatch differentials + sharded wat table + bench-fanout bars"
+echo "=== [11/12] watch fan-out: block-batched dispatch differentials + sharded wat table + hub block route"
 env JAX_PLATFORMS=cpu python -m pytest tests/test_fanout_device.py \
     tests/test_fanout_integration.py -q -m 'not slow' \
     -p no:cacheprovider || exit 1
-# bench-fanout at the full acceptance config (docs/watch.md; ~25s on one
-# CPU core — the >=2x block-vs-per-batch bar is defined at 10k watchers
-# and small-N would let fixed overheads eat it): identity vs the brute
-# and segment-index oracles, the speedup bar, the live-hub lag p99 bar;
-# the report lands in /tmp, not the repo
-env JAX_PLATFORMS=cpu KB_BENCH_METRIC=fanout \
-    KB_FANOUT_OUT=/tmp/FANOUT_ci.json \
-    python bench.py || exit 1
 
 echo "=== [12/12] tier-1 tests (ROADMAP.md verify, one definition: make test-tier1)"
 exec make test-tier1

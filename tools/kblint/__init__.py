@@ -21,7 +21,7 @@ Two tiers (see docs/static_analysis.md for the full catalogue):
   funnels — kernels never see a bound from the wrong key domain
 
 **Interprocedural** (``--deep``: whole-program call graph + context
-propagation over kubebrain_tpu/ + tools/ + bench.py; graph.py/contexts.py):
+propagation over kubebrain_tpu/ + tools/; graph.py/contexts.py):
 
 - KB112  blocking call *transitively* reachable while a lock is held
 - KB113  host sync *transitively* reachable from jit/shard_map-traced code

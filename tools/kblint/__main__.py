@@ -4,7 +4,7 @@ Two tiers (docs/static_analysis.md):
 
 - default: the syntactic per-file rules KB101–KB111 over ``paths``
 - ``--deep``: additionally builds the whole-program call graph over
-  ``kubebrain_tpu/ + tools/ + bench.py`` and runs the interprocedural
+  ``kubebrain_tpu/ + tools/`` and runs the interprocedural
   rules KB112–KB122 plus the CFG/typestate leak rules KB123–KB126,
   filtered through tools/kblint/baseline.json and held to a wall-clock
   budget (CI fails if the analysis outgrows it).
@@ -48,7 +48,7 @@ def main(argv: list[str] | None = None) -> int:
                         help="repo root for relative paths (default: cwd)")
     parser.add_argument("--deep", action="store_true",
                         help="run the interprocedural tier (KB112-KB122) "
-                             "over kubebrain_tpu/ + tools/ + bench.py")
+                             "over kubebrain_tpu/ + tools/")
     parser.add_argument("--baseline", default=DEFAULT_BASELINE,
                         help="baseline JSON pinning pre-existing deep "
                              "findings (default: tools/kblint/baseline.json)")

@@ -262,7 +262,7 @@ class Baseline:
 #: syntactic tier keeps whatever paths the caller passes (tests included),
 #: but tests are deliberately NOT in the call graph — fixture code full of
 #: deliberate violations would drown the serving-path signal
-DEEP_ROOTS = ["kubebrain_tpu", "tools", "bench.py"]
+DEEP_ROOTS = ["kubebrain_tpu", "tools"]
 
 
 def deep_analyze_sources(sources: dict[str, str],
