@@ -21,6 +21,8 @@ import os
 import subprocess
 import threading
 
+import numpy as np
+
 from .. import coder
 from ..backend.common import KeyValue
 from ..backend.scanner import Scanner
@@ -34,7 +36,7 @@ _lib_lock = threading.Lock()
 
 
 #: the newest entry points: a library without them predates this adapter
-_REQUIRED_SYMBOLS = ("kb_mvcc_list_wire", "kb_wire_gather")
+_REQUIRED_SYMBOLS = ("kb_mvcc_list_wire", "kb_wire_gather", "kb_wire_read")
 
 
 def _lib_stale(path: str) -> bool:
@@ -175,6 +177,18 @@ def load_lib() -> ctypes.CDLL:
             ctypes.c_void_p, ctypes.c_size_t,  # out, its capacity
         ]
         lib.kb_wire_gather.restype = ctypes.c_size_t
+        lib.kb_wire_read.argtypes = [
+            ctypes.c_void_p, ctypes.c_size_t,  # partitions (8 words), how many
+            ctypes.c_size_t, ctypes.c_void_p,  # key chunks a row, dictionary
+            ctypes.c_size_t,                   # overlay entries, then their
+            ctypes.c_char_p, ctypes.c_void_p,  # keys: blob, offsets
+            ctypes.c_char_p, ctypes.c_void_p,  # values: blob, offsets
+            ctypes.c_void_p, ctypes.c_void_p,  # revisions, deletion flags
+            ctypes.c_uint64,                   # limit
+            ctypes.c_void_p, ctypes.c_size_t,  # out, its capacity
+            ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_int),
+        ]
+        lib.kb_wire_read.restype = ctypes.c_size_t
         lib.kb_split_keys.argtypes = [
             ctypes.c_void_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_size_t,
             ctypes.POINTER(ctypes.c_size_t),
@@ -253,8 +267,6 @@ def wire_gather(sources: list[tuple], runs: list[tuple[int, int, int]]) -> bytes
     rows that index them (``rows[i]`` is row i's value). Two ``CDLL`` calls
     a reply, whatever its runs (the size, then the bytes): the GIL is
     released while they copy, and given up no more often than that."""
-    import numpy as np
-
     bases = []
     for src in sources:
         k_u8, k_lens, revs, arena, offsets, rows = src
@@ -282,6 +294,143 @@ def wire_gather(sources: list[tuple], runs: list[tuple[int, int, int]]) -> bytes
     out = np.empty(need, dtype=np.uint8)
     lib.kb_wire_gather(table.ctypes.data, len(table), out.ctypes.data, need)
     return out.tobytes()
+
+
+#: bytes of one wire row beyond its key and value, at most: five tags, the
+#: row's and the value's length (5 each), the key's (2), two revisions (10
+#: each) and the version's byte
+WIRE_ROW_OVERHEAD = 40
+#: ``kb_wire_read``'s answer to an index, a code or an offset it will not read
+_SIZE_MAX = ctypes.c_size_t(-1).value
+_WIRE_REFUSED = ("wire read: a row index, a key code or a value offset "
+                 "outside the mirror's arrays")
+
+
+def wire_columns(keys: np.ndarray, lens: np.ndarray, revs: np.ndarray,
+                 val_arena: list[np.ndarray],
+                 val_offsets: list[np.ndarray]) -> np.ndarray:
+    """A mirror's host columns as ``kb_wire_read`` reads them: ``uint64[P,
+    6]``, a partition a row — where its keys (``uint32[N, C]`` chunks, as
+    stored), lengths (``int32[N]``), revisions (``uint64[N]``), value arena
+    and value offsets start, and how many rows have a value. Made ONCE a
+    mirror (``Mirror.__post_init__``), so a read converts and checks
+    nothing: the call reads these through raw pointers, and a column of
+    another dtype, stride or length would be wrong bytes on the wire or a
+    read out of bounds — refused here. The addresses hold as long as the
+    mirror keeps these very arrays, which it does for its life."""
+    n_parts, n_rows = lens.shape if lens.ndim == 2 else (-1, -1)
+    ok = (keys.dtype == np.uint32 and keys.ndim == 3
+          and keys.shape[:2] == (n_parts, n_rows)
+          and lens.dtype == np.int32 and revs.dtype == np.uint64
+          and revs.shape == lens.shape
+          and keys.flags.c_contiguous and lens.flags.c_contiguous
+          and revs.flags.c_contiguous
+          and len(val_arena) == len(val_offsets) == n_parts
+          and all(a.dtype == np.uint8 and a.ndim == 1 and a.flags.c_contiguous
+                  for a in val_arena)
+          and all(o.dtype == np.uint64 and o.ndim == 1 and len(o) >= 1
+                  and o.flags.c_contiguous for o in val_offsets))
+    if not ok:
+        raise ValueError(
+            "wire columns: want C-contiguous uint32[P, N, C] keys, int32[P, N] "
+            "lens, uint64[P, N] revs and P uint8 arenas with uint64 offsets; "
+            f"got {keys.dtype}{list(keys.shape)}, {lens.dtype}"
+            f"{list(lens.shape)}, {revs.dtype}{list(revs.shape)}, "
+            + ", ".join(f"{a.dtype}{list(a.shape)}/{o.dtype}{list(o.shape)}"
+                        for a, o in zip(val_arena, val_offsets)))
+    table = np.empty((n_parts, 6), dtype=np.uint64)
+    part = np.arange(n_parts, dtype=np.uint64)
+    table[:, 0] = keys.ctypes.data + part * keys.strides[0]
+    table[:, 1] = lens.ctypes.data + part * lens.strides[0]
+    table[:, 2] = revs.ctypes.data + part * revs.strides[0]
+    table[:, 3] = [a.ctypes.data for a in val_arena]
+    table[:, 4] = [o.ctypes.data for o in val_offsets]
+    table[:, 5] = [min(n_rows, len(o) - 1) for o in val_offsets]
+    return table
+
+
+def _overlay_args(overlay: dict) -> tuple[tuple, int, tuple]:
+    """A read's overlay (user key → ``(revision, value)``, None a deletion)
+    as ``kb_wire_read`` takes it — how many entries, then in key order their
+    keys and values (each one blob with its offsets), revisions and which
+    are deletions — with the bytes they can need on the wire and the arrays
+    the addresses point into (the caller holds them over the call). A fixed
+    number of calls whatever the overlay holds: the comprehensions call
+    nothing, and ``map(len, …)`` runs inside ``np.fromiter``."""
+    if not overlay:
+        return (0, None, None, None, None, None, None), 0, ()
+    keys = sorted(overlay)
+    entries = [overlay[k] for k in keys]
+    values = [b"" if e is None else e[1] for e in entries]
+    n = len(keys)
+    key_blob, val_blob = b"".join(keys), b"".join(values)
+    key_offs = np.zeros(n + 1, dtype=np.uint64)
+    np.cumsum(np.fromiter(map(len, keys), np.uint64, n), out=key_offs[1:])
+    val_offs = np.zeros(n + 1, dtype=np.uint64)
+    np.cumsum(np.fromiter(map(len, values), np.uint64, n), out=val_offs[1:])
+    revs = np.array([0 if e is None else e[0] for e in entries], dtype=np.uint64)
+    dead = np.array([e is None for e in entries], dtype=np.uint8)
+    return ((n, key_blob, key_offs.ctypes.data, val_blob, val_offs.ctypes.data,
+             revs.ctypes.data, dead.ctypes.data),
+            len(key_blob) + len(val_blob) + n * WIRE_ROW_OVERHEAD,
+            (key_offs, val_offs, revs, dead))
+
+
+def wire_read(columns: np.ndarray, val_offsets: list[np.ndarray],
+              key_chunks: int, key_width: int, key_dict: np.ndarray | None,
+              counts: np.ndarray, rows: np.ndarray, overlay: dict,
+              limit: int = 0) -> tuple[bytes, int, bool]:
+    """The host half of a device-path wire read in ONE foreign call
+    (``kb_wire_read``: key decode, overlay merge, cut at ``limit``, wire
+    encoding), the GIL released for all of it: ``(RangeResponse.kvs bytes,
+    rows, more)``. ``columns`` is :func:`wire_columns` of the mirror read
+    and ``val_offsets`` its value offsets, ``key_chunks`` the chunks of a
+    stored key and ``key_width`` the bytes of a user key at most,
+    ``key_dict`` the dictionary's table (``KeyEncoding.wire_table``; None
+    for raw keys: the chunks are the key), ``rows[p, :counts[p]]``
+    partition p's visible rows as the device handed them back. The reply's
+    buffer is sized from what those rows can need at most — a few steps a
+    PARTITION, none a row — so the size needs no call of its own; a buffer
+    that is short all the same is never written past, and the call is made
+    again with the size it answered."""
+    if (rows.dtype != np.int32 or rows.ndim != 2 or not rows.flags.c_contiguous
+            or counts.shape != (len(rows),) or len(columns) != len(rows)
+            or (len(counts) and not 0 <= counts.min() <= counts.max()
+                <= rows.shape[1])):
+        # kb_wire_read reads raw pointers, counts[p] row indices a partition
+        raise ValueError(
+            "wire read: want C-contiguous int32[P, size] row indices and P "
+            f"counts within them; got {rows.dtype}{list(rows.shape)}, "
+            f"{counts.dtype}{list(counts.shape)} for {len(columns)} partitions")
+    ps = np.flatnonzero(counts)
+    parts = np.empty((len(ps), 8), dtype=np.uint64)
+    parts[:, :6] = columns[ps]
+    parts[:, 6] = rows.ctypes.data + ps * rows.strides[0]
+    parts[:, 7] = counts[ps]
+    room = int(parts[:, 7].sum()) * (key_width + WIRE_ROW_OVERHEAD)
+    for p in ps.tolist():
+        # ascending rows: their values lie between the first's start and
+        # the last's end in the partition's arena
+        offs, mine = val_offsets[p], rows[p]
+        if mine[counts[p] - 1] + 1 >= len(offs):
+            raise StorageError(_WIRE_REFUSED)
+        room += int(offs[mine[counts[p] - 1] + 1]) - int(offs[mine[0]])
+    ov_args, ov_room, _held = _overlay_args(overlay)
+    room += ov_room
+    n_rows, more = ctypes.c_uint64(), ctypes.c_int()
+    lib = _lib or load_lib()  # every read's hot path: no lock once loaded
+    while True:
+        out = np.empty(room, dtype=np.uint8)
+        need = lib.kb_wire_read(
+            parts.ctypes.data, len(parts), key_chunks,
+            None if key_dict is None else key_dict.ctypes.data, *ov_args,
+            limit, out.ctypes.data, room, ctypes.byref(n_rows),
+            ctypes.byref(more))
+        if need == _SIZE_MAX:
+            raise StorageError(_WIRE_REFUSED)
+        if need <= room:
+            return out[:need].tobytes(), n_rows.value, bool(more.value)
+        room = need
 
 
 class NativeKv(KvStorage):

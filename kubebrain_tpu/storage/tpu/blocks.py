@@ -16,13 +16,14 @@ kernels need no cross-device carry.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ...ops import keys as keyops
+from ..native import wire_columns
 from .encode import EncodeOverflow, KeyEncoding, build_encoding
 
 TTL_PREFIX = b"/events/"
@@ -59,16 +60,26 @@ class Mirror:
     # pull, and lets merged TTL flags ride the delta instead of being
     # recomputed from (undecodable) encoded keys
     ttl_host: np.ndarray | None = None  # bool[P, N]
+    #: where the wire read finds each partition's columns
+    #: (``native.wire_columns``), made once for the mirror's life
+    wire_cols: np.ndarray = field(init=False, repr=False)  # uint64[P, 6]
 
     def __post_init__(self):
-        # the wire gather reads the value columns through raw pointers on
-        # every Range: whatever built this mirror, they are held in the
-        # dtype and layout declared above from here on (no copy where they
-        # already are, which is every build and merge path today)
+        # the wire read reads the key, length, revision and value columns
+        # through raw pointers on every Range: whatever built this mirror,
+        # they are held in the dtype and layout declared above from here on
+        # (no copy where they already are, which is every build and merge
+        # path today), and never replaced
+        self.keys_host = np.ascontiguousarray(self.keys_host, dtype=np.uint32)
+        self.lens_host = np.ascontiguousarray(self.lens_host, dtype=np.int32)
+        self.revs_host = np.ascontiguousarray(self.revs_host, dtype=np.uint64)
         self.val_arena = [np.ascontiguousarray(a, dtype=np.uint8)
                           for a in self.val_arena]
         self.val_offsets = [np.ascontiguousarray(o, dtype=np.uint64)
                             for o in self.val_offsets]
+        self.wire_cols = wire_columns(
+            self.keys_host, self.lens_host, self.revs_host, self.val_arena,
+            self.val_offsets)
 
     @property
     def partitions(self) -> int:
@@ -97,8 +108,13 @@ class Mirror:
 
     def decoded_keys(self, p: int, rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """(raw_u8, raw_lens) for row indices of one partition — the ONE
-        decode funnel (kblint KB116): encoded key bytes only turn back into
-        raw bytes here, sized by the caller's visible-row set."""
+        decode funnel in Python (kblint KB116): encoded key bytes only turn
+        back into raw bytes here, sized by the caller's visible-row set. The
+        wire read alone decodes elsewhere — in C, inside its one call
+        (``wire_key`` in native/kbstore.cc, over :attr:`wire_cols` and
+        ``KeyEncoding.wire_table``), row by row as it writes the reply; it
+        is this function's twin and tests/test_wire_read.py holds the two
+        together on random dictionaries and the edge shapes."""
         if self.encoding is not None:
             return self.encoding.decode_rows(
                 self.keys_host[p][rows], self.lens_host[p][rows])
@@ -117,22 +133,6 @@ class Mirror:
         values = [arena[o[i] : o[i + 1]].tobytes() for i in map(int, rows)]
         revs = self.revs_host[p][rows]
         return keys, values, revs
-
-    def wire_source(self, p: int, rows: np.ndarray) -> tuple:
-        """Rows of one partition as the arrays ``native.wire_gather`` reads
-        (keys, lens, revisions — row-aligned with ``rows`` — then the
-        partition's value arena, its offsets, and ``rows``): the wire
-        path's counterpart of :meth:`materialize`, with no object per row.
-        Keys come through the one decode funnel, values are never touched
-        here — the gather copies them arena → wire, and the partition's
-        two value columns go as the mirror holds them (``__post_init__``),
-        never converted on a read."""
-        rows = np.ascontiguousarray(rows, dtype=np.int64)
-        k_u8, k_lens = self.decoded_keys(p, rows)
-        return (np.ascontiguousarray(k_u8),
-                np.ascontiguousarray(k_lens, dtype=np.int32),
-                np.ascontiguousarray(self.revs_host[p][rows], dtype=np.uint64),
-                self.val_arena[p], self.val_offsets[p], rows)
 
     def partition_first_keys(self) -> list[bytes]:
         return [
@@ -201,10 +201,10 @@ def rows_to_arrays(rows: list[tuple[bytes, int, bytes]], width: int):
 
 
 def rows_wire_source(rows: list[tuple[bytes, bytes, int]]) -> tuple:
-    """Python ``(key, value, revision)`` rows as a :meth:`Mirror.wire_source`
-    tuple — for the few rows that exist as objects (a read's live overlay
-    entries, a host-path page), so they reach the wire through the same
-    encoder as the mirror's."""
+    """Python ``(key, value, revision)`` rows as the six arrays
+    ``native.wire_gather`` reads — for the rows that exist as objects (a
+    host-path page of an inner engine with no wire scan of its own), so
+    they reach the wire through the same encoder as the mirror's."""
     n = len(rows)
     keys_u8 = np.zeros((n, max((len(r[0]) for r in rows), default=0) or 1),
                        dtype=np.uint8)

@@ -109,6 +109,10 @@ class KeyEncoding:
     _strips_mat: np.ndarray = field(init=False)  # uint8[m+1, max_strip]
     _bounds_width: int = field(init=False)       # boundary pad width
     _bounds_void: np.ndarray = field(init=False)  # void[m] sorted view
+    #: the dictionary as ``kb_wire_read`` decodes through it, made once:
+    #: where ``strip_lens`` and the strips matrix start, the matrix's row
+    #: stride, ``n_codes``, ``suffix_width``, ``raw_width``
+    wire_table: np.ndarray = field(init=False)   # uint64[6]
 
     def __post_init__(self):
         m1 = len(self.strips)
@@ -128,6 +132,10 @@ class KeyEncoding:
         for i, b in enumerate(self.boundaries):
             b_mat[i, : len(b)] = np.frombuffer(b, np.uint8)
         self._bounds_void = b_mat.view(f"V{wb}").reshape(-1)
+        self.wire_table = np.array(
+            [self.strip_lens.ctypes.data, self._strips_mat.ctypes.data,
+             self._strips_mat.strides[0], m1, self.suffix_width,
+             self.raw_width], dtype=np.uint64)
 
     # ------------------------------------------------------------- geometry
     @property
@@ -213,7 +221,9 @@ class KeyEncoding:
                     suffix_lens: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """Encoded chunk rows → (raw_u8[n, raw_width], raw_lens[n]) — the
         inverse of :meth:`encode_keys`, used only at the named host
-        materialization funnels (kblint KB116)."""
+        materialization funnels (kblint KB116). The wire read decodes in C
+        (``wire_key`` in native/kbstore.cc, through :attr:`wire_table`):
+        this function's twin, held to it by tests/test_wire_read.py."""
         enc_u8 = keyops.chunks_to_u8(enc_chunks)
         n = len(enc_u8)
         suffix_lens = np.asarray(suffix_lens, dtype=np.int64)

@@ -42,7 +42,12 @@ from ...trace import TRACER
 from ...util import fieldcheck, lockcheck
 from .. import BatchWrite, CASFailedError, KvStorage, Partition, register_engine
 from ..errors import UncertainResultError
-from ..native import list_wire_pages, load_lib, wire_gather
+from ..native import (
+    list_wire_pages,
+    load_lib,
+    wire_gather,
+    wire_read,
+)
 from .blocks import (
     Mirror,
     build_mirror,
@@ -1282,40 +1287,37 @@ class TpuScanner(Scanner):
         )
 
     def _dev_visible_indices(self, mask, counts, n_rows: int):
-        """(total, flat p·N + row indices) from a device mask [P, N] — the
-        shared two-phase gather: per-partition counts first (tiny
-        transfer), then the SHARD-LOCAL compacted index block [P, size]
-        with size = pow2(max per-partition count). The host transfer is
-        bounded by P·pow2(max visible per shard) index words — O(visible
-        rows), never the [P, N] mask — and no cross-device gather happens
-        on a multi-device mesh (`_part_indices_of_mask` keeps the ``part``
-        axis sharded through the compaction)."""
+        """(per-partition counts [P], row indices [P, size]) from a device
+        mask [P, N]: partition p's visible rows are ``rows[p,
+        :counts[p]]``, ascending — the shared two-phase gather:
+        per-partition counts first (tiny transfer), then the SHARD-LOCAL
+        compacted index block [P, size] with size = pow2(max per-partition
+        count). The host transfer is bounded by P·pow2(max visible per
+        shard) index words — O(visible rows), never the [P, N] mask — and
+        no cross-device gather happens on a multi-device mesh
+        (`_part_indices_of_mask` keeps the ``part`` axis sharded through
+        the compaction). The pieces are handed on as the device gave them:
+        every host materialization reads them a partition at a time."""
         counts_h = _host_pull(counts)  # [P]; blocks on the kernel
-        total = int(counts_h.sum())
-        if total == 0:
-            return 0, np.empty(0, dtype=np.int64)
-        size = _pow2_bucket(int(counts_h.max()), n_rows)
-        out = _host_pull(_part_indices_of_mask(mask, size=size,
-                                               mesh=self._mesh))
-        pieces = [
-            out[p, :c].astype(np.int64) + p * n_rows
-            for p, c in enumerate(counts_h) if c
-        ]
-        return total, np.concatenate(pieces)
+        most = int(counts_h.max())
+        if most == 0:
+            return counts_h, np.empty((len(counts_h), 0), dtype=np.int32)
+        return counts_h, _host_pull(_part_indices_of_mask(
+            mask, size=_pow2_bucket(most, n_rows), mesh=self._mesh))
 
-    def _materialize_visible(self, mirror: Mirror, idx: np.ndarray, overlay):
-        """Visible rows (flat p·N + row indices) → sorted KeyValue list with
-        the delta overlay merged — the ONE host materialization the single
-        and query-batched range paths share, so batched responses cannot
-        drift from sequential ones by construction."""
-        n_rows = mirror.keys_host.shape[1]
+    def _materialize_visible(self, mirror: Mirror, vis, overlay):
+        """Visible rows (``(counts, rows)`` of :meth:`_dev_visible_indices`)
+        → sorted KeyValue list with the delta overlay merged — the ONE host
+        materialization the single and query-batched range paths share, so
+        batched responses cannot drift from sequential ones by
+        construction."""
         from ...backend.common import KeyValue
 
+        counts, rows = vis
         kvs: list[KeyValue] = []
-        parts, rows = np.divmod(idx, n_rows)
-        for p in np.unique(parts):
-            p_rows = rows[parts == p]
-            keys, values, revs = mirror.materialize(int(p), p_rows)
+        for p in np.flatnonzero(counts):
+            keys, values, revs = mirror.materialize(
+                int(p), rows[p, : counts[p]])
             for uk, val, rv in zip(keys, values, revs):
                 if uk in overlay:
                     continue  # delta supersedes
@@ -1326,83 +1328,37 @@ class TpuScanner(Scanner):
         kvs.sort(key=lambda kv: kv.key)
         return kvs
 
-    def _materialize_wire(self, mirror: Mirror, idx: np.ndarray, overlay,
+    def _materialize_wire(self, mirror: Mirror, vis, overlay,
                           limit: int = 0) -> tuple[bytes, int, bool]:
-        """Visible rows (flat p·N + row indices) → ``(RangeResponse.kvs wire
-        bytes, rows, more)`` with the delta overlay merged: what
-        :meth:`_materialize_visible` + ``kvs[:limit]`` + the front's
-        per-row protobuf produce, with no Python object per row — the ONE
+        """Visible rows (``(counts, rows)`` of :meth:`_dev_visible_indices`)
+        → ``(RangeResponse.kvs wire bytes, rows, more)`` with the delta
+        overlay merged: what :meth:`_materialize_visible` + ``kvs[:limit]``
+        + the front's per-row protobuf produce, byte for byte — the ONE
         wire materialization the single and query-batched wire reads share.
-        The mirror's rows arrive in key order, one source of arrays per
-        partition (:meth:`Mirror.wire_source`); each overlay key finds its
-        place among them by binary search (a loop over the OVERLAY's items,
-        a handful in one namespace), the row it supersedes drops out and a
-        live entry is spliced in between two runs; nothing is sorted. The
-        runs go out in one ``kb_wire_gather`` call — keys, revisions and
-        the value bytes copied arena → wire with the GIL released."""
-        n_rows = mirror.keys_host.shape[1]
-        n_vis = len(idx)
-        parts, rows = np.divmod(idx, n_rows)
-        sources: list[tuple] = []
-        starts: list[int] = []  # a source's first row among the visible
-        if n_vis:
-            ps, first = np.unique(parts, return_index=True)
-            starts = [int(a) for a in first]
-            for p, a, b in zip(ps, starts, starts[1:] + [n_vis]):
-                sources.append(mirror.wire_source(int(p), rows[a:b]))
-        runs: list[tuple[int, int, int]] = []  # (source, from, to), key order
-
-        def mirror_run(g0: int, g1: int) -> None:
-            s = bisect.bisect_right(starts, g0) - 1
-            while g0 < g1:
-                stop = min(g1, starts[s + 1] if s + 1 < len(starts) else n_vis)
-                runs.append((s, g0 - starts[s], stop - starts[s]))
-                g0, s = stop, s + 1
-
-        def key_at(g: int) -> bytes:
-            s = bisect.bisect_right(starts, g) - 1
-            k_u8, k_lens = sources[s][0], sources[s][1]
-            i = g - starts[s]
-            return k_u8[i, : k_lens[i]].tobytes()
-
-        if overlay:
-            items = sorted(overlay.items())
-            live = [(uk, e[1], e[0]) for uk, e in items if e is not None]
-            if live:
-                sources.append(rows_wire_source(live))
-            cur = j = 0
-            for uk, entry in items:
-                lo, hi = cur, n_vis
-                while lo < hi:
-                    mid = (lo + hi) // 2
-                    if key_at(mid) < uk:
-                        lo = mid + 1
-                    else:
-                        hi = mid
-                mirror_run(cur, lo)
-                if entry is not None:
-                    runs.append((len(sources) - 1, j, j + 1))
-                    j += 1
-                # the delta supersedes (or deletes) the mirror's row
-                cur = lo + (lo < n_vis and key_at(lo) == uk)
-            mirror_run(cur, n_vis)
-        else:
-            mirror_run(0, n_vis)
-        n = sum(b - a for _s, a, b in runs)
-        more = bool(limit) and n > limit
-        if more:
-            n, room, cut = limit, limit, []
-            for s, a, b in runs:
-                if room <= 0:
-                    break
-                cut.append((s, a, min(b, a + room)))
-                room -= b - a
-            runs = cut
-        return wire_gather(sources, runs), n, more
+        All of it is one foreign call with the GIL released
+        (``native.wire_read`` → ``kb_wire_read``): each visible row's key
+        decoded as it is written (through the mirror's dictionary, or as it
+        stands where the mirror has none — the C twin of
+        :meth:`Mirror.decoded_keys`, held to it by tests/test_wire_read.py),
+        the overlay's entries merged in by one walk down both sorted
+        sequences, the cut at ``limit``, values copied arena → wire. What
+        Python keeps is handing over where the mirror's columns are and
+        sizing the reply's buffer, a few steps a PARTITION: nothing runs
+        per row or per overlay entry."""
+        counts, rows = vis
+        encoding = mirror.encoding
+        # as the device handed them back: int32 and contiguous already, so
+        # this copies nothing; the call reads them through a raw pointer
+        rows = np.ascontiguousarray(rows, dtype=np.int32)
+        return wire_read(
+            mirror.wire_cols, mirror.val_offsets, mirror.keys_host.shape[2],
+            mirror.raw_key_width,
+            None if encoding is None else encoding.wire_table,
+            counts, rows, overlay, limit)
 
     def _device_range(self, start: bytes, end: bytes, read_revision: int,
                       materialize):
-        """One device-path Range, ``materialize(mirror, idx, overlay)`` its
+        """One device-path Range, ``materialize(mirror, vis, overlay)`` its
         host half — the stages ``range_`` and ``list_wire`` share."""
         # attribution: delta_overlay = the delta on the read path (publish
         # check, the wait for the writers' lock, the overlay under it);
@@ -1420,11 +1376,11 @@ class TpuScanner(Scanner):
         with TRACER.stage("device_dispatch"):
             mask, counts = self._dev_mask(mirror, start, end, read_revision)
         with TRACER.stage("device_compute"):
-            total, idx = self._dev_visible_indices(
+            vis = self._dev_visible_indices(
                 mask, counts, mirror.keys_host.shape[1]
             )
         with TRACER.stage("host_copy"):
-            out = materialize(mirror, idx, overlay)
+            out = materialize(mirror, vis, overlay)
             # the read's device arrays go here, inside the stage: dropping
             # them gives up the GIL, and under three listers getting it
             # back took ~1 ms on average (42 ms at worst) that no stage
@@ -1451,15 +1407,15 @@ class TpuScanner(Scanner):
     def list_wire(self, start: bytes, end: bytes, read_revision: int,
                   limit: int = 0) -> tuple[bytes, int, bool]:
         """``range_`` answered as ``(RangeResponse.kvs wire bytes, rows,
-        more)``: the same snapshot, stages and rows, gathered from the
-        mirror's host arrays straight into wire bytes
+        more)``: the same snapshot, stages and rows, written from the
+        mirror's host arrays straight into wire bytes by one native call
         (:meth:`_materialize_wire`) instead of built row by row."""
         if self._on_host(limit):
             return self._host_list_wire(start, end, read_revision, limit)
         return self._device_range(
             start, end, read_revision,
-            lambda mirror, idx, overlay: self._materialize_wire(
-                mirror, idx, overlay, limit))
+            lambda mirror, vis, overlay: self._materialize_wire(
+                mirror, vis, overlay, limit))
 
     def _host_list_wire(self, start: bytes, end: bytes, read_revision: int,
                         limit: int) -> tuple[bytes, int, bool]:
@@ -1549,12 +1505,10 @@ class TpuScanner(Scanner):
             for k, (_, s) in enumerate(device):
                 sel[k] = s[0] != "count"  # counts (and pow2 pad) pull no rows
         n_rows = mirror.keys_host.shape[1]
-        # both kernels emit [Qpad, P, N] with N == the host row width; the
-        # flat-index split below silently corrupts results if that drifts
+        # both kernels emit [Qpad, P, N] with N == the host row width: the
+        # row indices below index the host columns
         assert int(mask.shape[2]) == n_rows, (mask.shape, n_rows)
-        n_parts = int(mask.shape[1])
-        stride = n_parts * n_rows
-        idx = np.empty(0, dtype=np.int64)
+        idx_parts = np.empty((*mask.shape[:2], 0), dtype=np.int32)
         with TRACER.stage("device_compute"):
             counts_h = _host_pull(counts)  # blocks on the kernel; [Qpad, P]
             want = int(counts_h[sel].max()) if sel.any() else 0
@@ -1566,31 +1520,20 @@ class TpuScanner(Scanner):
                 size = _pow2_bucket(want, n_rows)
                 idx_parts = _host_pull(_part_indices_of_mask_sel(
                     mask, jnp.asarray(sel), size=size, mesh=self._mesh))
-                pieces = []
-                for k in np.nonzero(sel)[0]:
-                    base = int(k) * stride
-                    for p in range(n_parts):
-                        c = int(counts_h[k, p])
-                        if c:
-                            pieces.append(
-                                idx_parts[k, p, :c].astype(np.int64)
-                                + base + p * n_rows)
-                if pieces:
-                    idx = np.concatenate(pieces)
         with TRACER.stage("host_copy"):
             for k, (qi, spec) in enumerate(device):
                 if spec[0] == "count":
                     out[qi] = self._overlay_corrected_count(
                         mirror, int(counts_h[k].sum()), overlays[k], spec[3])
                     continue
-                lo = np.searchsorted(idx, k * stride)
-                hi = np.searchsorted(idx, (k + 1) * stride)
-                q_idx, limit = idx[lo:hi] - k * stride, spec[4]
+                # query k's pieces, as the single read's: one
+                # materialization, both callers
+                vis, limit = (counts_h[k], idx_parts[k]), spec[4]
                 if spec[0] == "wire":
                     out[qi] = self._materialize_wire(
-                        mirror, q_idx, overlays[k], limit)
+                        mirror, vis, overlays[k], limit)
                     continue
-                kvs = self._materialize_visible(mirror, q_idx, overlays[k])
+                kvs = self._materialize_visible(mirror, vis, overlays[k])
                 out[qi] = (kvs[:limit], len(kvs) > limit) if limit else (kvs, False)
             del mask, counts  # released inside a stage, as in range_
         return out
@@ -1609,10 +1552,9 @@ class TpuScanner(Scanner):
             mirror = self._mirror
             overlay = self._delta.overlay(start, end, read_revision)
         mask, counts = self._dev_mask(mirror, start, end, read_revision)
-        total, idx = self._dev_visible_indices(
+        counts_h, rows = self._dev_visible_indices(
             mask, counts, mirror.keys_host.shape[1]
         )
-        n_rows = mirror.keys_host.shape[1]
         extra = sorted(
             (k, v) for k, v in overlay.items() if v is not None
         )  # (key, (rev, value)) insertions, key-ascending
@@ -1630,13 +1572,9 @@ class TpuScanner(Scanner):
                     return out
                 return None
 
-            pos = 0
-            while pos < len(idx):
-                chunk = idx[pos : pos + 4096]
-                pos += 4096
-                parts, rows = np.divmod(chunk, n_rows)
-                for p in np.unique(parts):
-                    p_rows = rows[parts == p]
+            for p in np.flatnonzero(counts_h):
+                for pos in range(0, int(counts_h[p]), 4096):
+                    p_rows = rows[p, pos : min(pos + 4096, counts_h[p])]
                     keys, values, revs = mirror.materialize(int(p), p_rows)
                     for uk, val, rv in zip(keys, values, revs):
                         while ei < len(extra) and extra[ei][0] < uk:

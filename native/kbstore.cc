@@ -1390,6 +1390,237 @@ size_t kb_wire_gather(const uint64_t* runs, size_t n_runs, uint8_t* out,
   return need;
 }
 
+}  // extern "C"
+
+namespace {
+
+// The mirror's key dictionary as the wire read sees it (storage/tpu/
+// encode.py, KeyEncoding.wire_table): bucket `code` strips `strip_lens[code]`
+// leading bytes, kept in row `code` of the `strips` matrix.
+struct WireDict {
+  const int64_t* strip_lens;
+  const uint8_t* strips;
+  size_t stride, n_codes, suffix_width, raw_width;
+};
+
+// One partition's columns as the mirror holds them, and the rows of it that
+// the device found visible.
+struct WirePart {
+  const uint32_t* keys;  // [N, chunks], each chunk a big-endian 4 bytes
+  const int32_t* lens;   // key bytes (raw mirror) or suffix bytes (encoded)
+  const uint64_t* revs;
+  const uint8_t* val_arena;
+  const uint64_t* val_offsets;
+  size_t n_rows;  // rows that have a value: val_offsets holds n_rows + 1
+  const int32_t* idx;
+  size_t n_idx;
+};
+
+// n_bytes of a chunk row, rounded up to whole chunks: dst has that room.
+inline void wire_chunk_bytes(const uint32_t* chunks, size_t n_bytes,
+                             uint8_t* dst) {
+  for (size_t j = 0; 4 * j < n_bytes; ++j) {
+    uint32_t v = chunks[j];
+    dst[4 * j] = static_cast<uint8_t>(v >> 24);
+    dst[4 * j + 1] = static_cast<uint8_t>(v >> 16);
+    dst[4 * j + 2] = static_cast<uint8_t>(v >> 8);
+    dst[4 * j + 3] = static_cast<uint8_t>(v);
+  }
+}
+
+// A stored row's user key into dst (room: raw_width + 4 * chunks): the twin
+// of Mirror.decoded_keys — keys.chunks_to_u8 for a raw mirror (no
+// dictionary), KeyEncoding.decode_rows for an encoded one, strip then
+// suffix, zeros where decode_rows leaves its zeros — held to it by
+// tests/test_wire_read.py. With dst null only the length. Returns the key's
+// length, SIZE_MAX for a code the dictionary does not have.
+inline size_t wire_key(const uint32_t* row, size_t chunks, int32_t len,
+                       const WireDict* d, uint8_t* dst) {
+  size_t n = len > 0 ? static_cast<size_t>(len) : 0;
+  if (d == nullptr) {
+    if (n > 4 * chunks) n = 4 * chunks;
+    if (dst) wire_chunk_bytes(row, n, dst);
+    return n;
+  }
+  size_t code = row[0];
+  if (code >= d->n_codes || d->strip_lens[code] < 0) return SIZE_MAX;
+  size_t s = static_cast<size_t>(d->strip_lens[code]);
+  if (s > d->raw_width || s > d->stride) return SIZE_MAX;
+  size_t kl = s + n < d->raw_width ? s + n : d->raw_width;
+  if (dst == nullptr) return kl;
+  if (s) memcpy(dst, d->strips + code * d->stride, s);
+  size_t take = d->raw_width - s;
+  if (take > d->suffix_width) take = d->suffix_width;
+  if (take > 4 * (chunks - 1)) take = 4 * (chunks - 1);
+  if (take > kl - s) take = kl - s;
+  wire_chunk_bytes(row + 1, take, dst + s);
+  if (s + take < kl) memset(dst + s + take, 0, kl - s - take);
+  return kl;
+}
+
+inline int wire_key_cmp(const uint8_t* a, size_t al, const uint8_t* b,
+                        size_t bl) {
+  int c = memcmp(a, b, al < bl ? al : bl);
+  return c ? c : (al < bl ? -1 : al > bl);
+}
+
+// The overlay's entries in key order: what the delta holds for the range,
+// newer than anything in the mirror. A dead entry is a deletion.
+struct WireOverlay {
+  size_t n;
+  const uint8_t* keys;
+  const uint64_t* key_offs;
+  const uint8_t* vals;
+  const uint64_t* val_offs;
+  const uint64_t* revs;
+  const uint8_t* dead;
+};
+
+// The rows of one reply in key order: the partitions' visible rows and the
+// overlay's entries walked once, side by side — an entry goes out in its
+// place if it lives, and the mirror row of its key drops out. emit(key,
+// key_len, value, value_len, rev) for each row until `limit` are out (0: no
+// limit); *more says another row was there. With `bytes` false emit gets
+// the mirror rows' key LENGTHS alone (a null key) once no overlay entry is
+// left to compare with. False for a row index, a key code or a pair of
+// value offsets that the arrays cannot hold.
+template <typename Emit>
+bool wire_walk(const WirePart* parts, size_t n_parts, size_t chunks,
+               const WireDict* d, const WireOverlay& ov, uint64_t limit,
+               bool bytes, uint8_t* scratch, Emit emit, uint64_t* rows,
+               int* more) {
+  uint64_t n = 0;
+  size_t j = 0;
+  *more = 0;
+  auto put = [&](const uint8_t* k, size_t kl, const uint8_t* v, size_t vl,
+                 uint64_t rev) {
+    if (limit && n == limit) {
+      *more = 1;
+      return false;
+    }
+    emit(k, kl, v, vl, rev);
+    ++n;
+    return true;
+  };
+  auto put_entry = [&](size_t e) {
+    if (ov.dead[e]) return true;
+    return put(ov.keys + ov.key_offs[e], ov.key_offs[e + 1] - ov.key_offs[e],
+               ov.vals + ov.val_offs[e], ov.val_offs[e + 1] - ov.val_offs[e],
+               ov.revs[e]);
+  };
+  *rows = 0;
+  for (size_t p = 0; p < n_parts; ++p) {
+    const WirePart& u = parts[p];
+    for (size_t i = 0; i < u.n_idx; ++i) {
+      if (u.idx[i] < 0 || static_cast<size_t>(u.idx[i]) >= u.n_rows)
+        return false;
+      size_t r = static_cast<size_t>(u.idx[i]);
+      uint64_t vo = u.val_offsets[r], ve = u.val_offsets[r + 1];
+      if (ve < vo) return false;
+      bool decode = bytes || j < ov.n;
+      size_t kl = wire_key(u.keys + r * chunks, chunks, u.lens[r], d,
+                           decode ? scratch : nullptr);
+      if (kl == SIZE_MAX) return false;
+      bool superseded = false;
+      while (j < ov.n) {
+        int c = wire_key_cmp(ov.keys + ov.key_offs[j],
+                             ov.key_offs[j + 1] - ov.key_offs[j], scratch, kl);
+        if (c > 0) break;
+        if (!put_entry(j)) goto done;
+        ++j;
+        if (c == 0) {
+          superseded = true;
+          break;
+        }
+      }
+      if (superseded) continue;
+      if (!put(decode ? scratch : nullptr, kl, u.val_arena + vo, ve - vo,
+               u.revs[r]))
+        goto done;
+    }
+  }
+  for (; j < ov.n; ++j)
+    if (!put_entry(j)) break;
+done:
+  *rows = n;
+  return true;
+}
+
+}  // namespace
+
+extern "C" {
+
+// The host half of one device-path wire read, whole: from the row indices
+// the device handed back to the READY wire bytes, one call and no Python
+// between — the key decode (wire_key), the overlay merge and the cut at
+// `limit` (wire_walk), each row through wire_put_row. A pure function over
+// plain arrays (no Store*), so a ctypes caller runs it with the GIL
+// released: a lister gives the GIL up once a reply, the writers' handlers
+// have it meanwhile, and concurrent listers run in parallel.
+//
+// parts: 8 words a partition that has visible rows, in key order — keys
+// (uint32[N, key_chunks], as the mirror stores them), lens (int32[N]), revs
+// (uint64[N]), val_arena, val_offsets (uint64[n_rows + 1]), n_rows, idx
+// (int32: the visible rows, ascending), how many. dict: null for a raw
+// mirror, else 6 words — strip_lens (int64[n_codes]), the strips matrix
+// (uint8[n_codes, stride]), stride, n_codes, suffix_width, raw_width.
+// The overlay: n_ov entries in key order, keys and values each one blob
+// with n_ov + 1 offsets, revisions, and a flag a deletion (its value empty).
+//
+// Returns the bytes the reply needs, with *rows and *more; they are written
+// to out only when that fits out_cap — a short buffer is never written
+// past, the caller reads the size and calls again. SIZE_MAX: an index, a
+// key code or a pair of offsets outside the arrays; nothing was written.
+size_t kb_wire_read(const uint64_t* parts, size_t n_parts, size_t key_chunks,
+                    const uint64_t* dict, size_t n_ov, const uint8_t* ov_keys,
+                    const uint64_t* ov_key_offs, const uint8_t* ov_vals,
+                    const uint64_t* ov_val_offs, const uint64_t* ov_revs,
+                    const uint8_t* ov_dead, uint64_t limit, uint8_t* out,
+                    size_t out_cap, uint64_t* rows, int* more) {
+  std::vector<WirePart> ps(n_parts);
+  for (size_t p = 0; p < n_parts; ++p) {
+    const uint64_t* w = parts + 8 * p;
+    ps[p] = WirePart{reinterpret_cast<const uint32_t*>(w[0]),
+                     reinterpret_cast<const int32_t*>(w[1]),
+                     reinterpret_cast<const uint64_t*>(w[2]),
+                     reinterpret_cast<const uint8_t*>(w[3]),
+                     reinterpret_cast<const uint64_t*>(w[4]),
+                     static_cast<size_t>(w[5]),
+                     reinterpret_cast<const int32_t*>(w[6]),
+                     static_cast<size_t>(w[7])};
+  }
+  WireDict dict_s{};
+  const WireDict* d = nullptr;
+  if (dict != nullptr) {
+    dict_s = WireDict{reinterpret_cast<const int64_t*>(dict[0]),
+                      reinterpret_cast<const uint8_t*>(dict[1]),
+                      static_cast<size_t>(dict[2]),
+                      static_cast<size_t>(dict[3]),
+                      static_cast<size_t>(dict[4]),
+                      static_cast<size_t>(dict[5])};
+    d = &dict_s;
+    if (key_chunks == 0) return SIZE_MAX;  // no chunk for the code
+  }
+  WireOverlay ov{n_ov,        ov_keys, ov_key_offs, ov_vals,
+                 ov_val_offs, ov_revs, ov_dead};
+  std::vector<uint8_t> scratch((d ? d->raw_width : 0) + 4 * key_chunks + 4);
+  size_t need = 0;
+  if (!wire_walk(
+          ps.data(), n_parts, key_chunks, d, ov, limit, false, scratch.data(),
+          [&need](const uint8_t*, size_t kl, const uint8_t*, size_t vl,
+                  uint64_t rev) { need += wire_row_size(kl, vl, rev); },
+          rows, more))
+    return SIZE_MAX;
+  if (need > out_cap) return need;
+  uint8_t* at = out;
+  wire_walk(
+      ps.data(), n_parts, key_chunks, d, ov, limit, true, scratch.data(),
+      [&at](const uint8_t* k, size_t kl, const uint8_t* v, size_t vl,
+            uint64_t rev) { at = wire_put_row(at, k, kl, v, vl, rev); },
+      rows, more);
+  return need;
+}
+
 // Paged columnar export for the kbstored EXPORT op (the bulk path that lets
 // a remote TPU mirror rebuild without per-row Python; reference analogue:
 // the TiKV adapter feeding the scanner's partition map, tikv.go:38-153).

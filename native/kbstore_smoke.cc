@@ -58,6 +58,12 @@ int kb_apply_record(void* s, const uint8_t* rec, size_t len, int reset,
                     uint64_t* applied_ts);
 size_t kb_wire_gather(const uint64_t* runs, size_t n_runs, uint8_t* out,
                       size_t out_cap);
+size_t kb_wire_read(const uint64_t* parts, size_t n_parts, size_t key_chunks,
+                    const uint64_t* dict, size_t n_ov, const uint8_t* ov_keys,
+                    const uint64_t* ov_key_offs, const uint8_t* ov_vals,
+                    const uint64_t* ov_val_offs, const uint64_t* ov_revs,
+                    const uint8_t* ov_dead, uint64_t limit, uint8_t* out,
+                    size_t out_cap, uint64_t* rows, int* more);
 }
 
 #define CHECK(cond)                                                     \
@@ -261,9 +267,125 @@ static void smoke_wire_gather() {
       free(lens), free(keys);
 }
 
+// The wire read, whole (key decode, overlay merge, cut): one encoded
+// partition of four rows in key order under a two-bucket dictionary, three
+// of them visible, every array on the heap at EXACTLY its size so ASan sees
+// any read past an input and any write past the reply — with an empty
+// overlay, a buffer one byte short, an overlay that inserts, supersedes and
+// deletes, a cut at `limit`, an overlay-only reply, a raw mirror, and a row
+// index and a key code the arrays do not hold.
+static void smoke_wire_read() {
+  auto heap = [](const void* src, size_t n) {
+    void* p = malloc(n ? n : 1);
+    memcpy(p, src, n);
+    return p;
+  };
+  auto word = [](const void* p) { return reinterpret_cast<uint64_t>(p); };
+  // rows "/x/aa", "/yy/", "/yy/b" (not visible), "/yy/cd": code 0 strips
+  // "/x/", code 1 "/yy/"; suffix_width 4, so 2 chunks a row
+  const uint32_t keys_v[8] = {0, 0x61610000u, 1, 0,
+                              1, 0x62000000u, 1, 0x63640000u};
+  const int32_t lens_v[4] = {2, 0, 1, 2};
+  const uint64_t revs_v[4] = {5, 6, 7, 300};
+  const uint64_t offs_v[5] = {0, 2, 2, 5, 9};  // "v0" "" "v-2" "v--3"
+  const int32_t idx_v[3] = {0, 1, 3};
+  const int64_t strip_lens_v[2] = {3, 4};
+  const uint8_t strips_v[8] = {'/', 'x', '/', 0, '/', 'y', 'y', '/'};
+  auto* keys = static_cast<uint32_t*>(heap(keys_v, sizeof keys_v));
+  auto* lens = static_cast<int32_t*>(heap(lens_v, sizeof lens_v));
+  auto* revs = static_cast<uint64_t*>(heap(revs_v, sizeof revs_v));
+  auto* arena = static_cast<uint8_t*>(heap("v0v-2v--3", 9));
+  auto* offs = static_cast<uint64_t*>(heap(offs_v, sizeof offs_v));
+  auto* idx = static_cast<int32_t*>(heap(idx_v, sizeof idx_v));
+  auto* strip_lens =
+      static_cast<int64_t*>(heap(strip_lens_v, sizeof strip_lens_v));
+  auto* strips = static_cast<uint8_t*>(heap(strips_v, sizeof strips_v));
+  const uint64_t part_v[8] = {word(keys), word(lens), word(revs), word(arena),
+                              word(offs), 4,          word(idx),  3};
+  auto* part = static_cast<uint64_t*>(heap(part_v, sizeof part_v));
+  const uint64_t dict_v[6] = {word(strip_lens), word(strips), 4, 2, 4, 16};
+  auto* dict = static_cast<uint64_t*>(heap(dict_v, sizeof dict_v));
+  // the overlay, in key order: an insert before every row, a new value for
+  // "/yy/" (the mirror's row drops out), "/yy/cd" deleted, an insert last
+  const uint64_t okoffs_v[5] = {0, 2, 6, 12, 14};
+  const uint64_t ovoffs_v[5] = {0, 1, 4, 4, 4};  // "A" "new" (dead) ""
+  const uint64_t orevs_v[4] = {900, 901, 0, 903};
+  const uint8_t odead_v[4] = {0, 0, 1, 0};
+  auto* ok = static_cast<uint8_t*>(heap("/a/yy//yy/cd/z", 14));
+  auto* oko = static_cast<uint64_t*>(heap(okoffs_v, sizeof okoffs_v));
+  auto* ovl = static_cast<uint8_t*>(heap("Anew", 4));
+  auto* ovo = static_cast<uint64_t*>(heap(ovoffs_v, sizeof ovoffs_v));
+  auto* orv = static_cast<uint64_t*>(heap(orevs_v, sizeof orevs_v));
+  auto* odd = static_cast<uint8_t*>(heap(odead_v, sizeof odead_v));
+  uint64_t rows = 0;
+  int more = -1;
+  uint8_t* out = nullptr;
+  // one read into a heap buffer of exactly `cap` bytes (none for 0)
+  auto read = [&](const uint64_t* parts, size_t n_parts, const uint64_t* d,
+                  size_t n_ov, uint64_t limit, size_t cap) {
+    free(out);
+    out = cap ? static_cast<uint8_t*>(malloc(cap)) : nullptr;
+    return kb_wire_read(parts, n_parts, 2, d, n_ov, ok, oko, ovl, ovo, orv,
+                        odd, limit, out, cap, &rows, &more);
+  };
+
+  // an empty overlay, the size first: three rows, each key decoded
+  // row: 0x12 len | 0x0A kl key | 0x10 rev | 0x18 rev | 0x20 1 | 0x2A vl val
+  size_t want = (2 + 2 + 5 + 4 + 2 + 2 + 2) + (2 + 2 + 4 + 4 + 2 + 2) +
+                (2 + 2 + 6 + 6 + 2 + 2 + 4);
+  size_t need = read(part, 1, dict, 0, 0, 0);
+  if (need != want) fprintf(stderr, "wire read size %zu != %zu\n", need, want);
+  CHECK(need == want && rows == 3 && more == 0);
+  // a buffer too short: the size again, nothing written
+  CHECK(read(part, 1, dict, 0, 0, need - 1) == need);
+  CHECK(read(part, 1, dict, 0, 0, need) == need);
+  const uint8_t first[] = {0x12, 17,   0x0A, 5,    '/', 'x', '/', 'a', 'a', 0x10,
+                           5,    0x18, 5,    0x20, 1,   0x2A, 2,  'v', '0'};
+  CHECK(memcmp(out, first, sizeof first) == 0);
+  CHECK(out[need - 4] == 'v' && out[need - 15] == 'd' && out[need - 1] == '3');
+
+  // the overlay merged: "/a", "/x/aa", "/yy/" as the overlay has it, "/z"
+  need = read(part, 1, dict, 4, 0, 0);
+  CHECK(need != SIZE_MAX && rows == 4 && more == 0);
+  CHECK(read(part, 1, dict, 4, 0, need) == need && rows == 4);
+  const uint8_t head[] = {0x12, 15, 0x0A, 2, '/', 'a', 0x10, 0x84, 0x07};
+  CHECK(memcmp(out, head, sizeof head) == 0);
+  CHECK(out[need - 1] == 0 && out[need - 11] == 'z');
+
+  // a cut at `limit`: two rows out and more set; a limit on the last row
+  need = read(part, 1, dict, 4, 2, 0);
+  CHECK(rows == 2 && more == 1);
+  CHECK(read(part, 1, dict, 4, 2, need) == need && rows == 2 && more == 1);
+  read(part, 1, dict, 4, 4, 0);
+  CHECK(rows == 4 && more == 0);
+  read(part, 1, dict, 4, 3, 0);
+  CHECK(rows == 3 && more == 1);
+
+  // an overlay-only reply: no partition at all, the three live entries
+  need = read(nullptr, 0, dict, 4, 0, 0);
+  CHECK(rows == 3 && more == 0);
+  CHECK(read(nullptr, 0, nullptr, 4, 0, need) == need && rows == 3);
+
+  // a raw mirror (no dictionary): the chunks are the key, cut at its length
+  need = read(part, 1, nullptr, 0, 0, 0);
+  CHECK(read(part, 1, nullptr, 0, 0, need) == need && rows == 3);
+  CHECK(out[3] == 2 && out[4] == 0 && out[5] == 0 && out[6] == 0x10);
+
+  // a row index past the arrays, a code past the dictionary: refused
+  idx[2] = 4;
+  CHECK(read(part, 1, dict, 0, 0, 0) == SIZE_MAX);
+  idx[2] = 3;
+  keys[6] = 2;
+  CHECK(read(part, 1, dict, 4, 0, 0) == SIZE_MAX);
+  free(out), free(odd), free(orv), free(ovo), free(ovl), free(oko), free(ok),
+      free(dict), free(part), free(strips), free(strip_lens), free(idx),
+      free(offs), free(arena), free(revs), free(lens), free(keys);
+}
+
 int main(int argc, char** argv) {
   smoke_memory_engine();
   smoke_wire_gather();
+  smoke_wire_read();
   if (argc > 1) smoke_wal_cycle(argv[1]);
   printf("SMOKE OK\n");
   return 0;

@@ -1,8 +1,8 @@
 """``TpuScanner.list_wire`` against ``range_``: a differential test.
 
-A wire read answers ``RangeResponse.kvs`` bytes gathered from the mirror's
-host arrays (``kb_wire_gather``), with the delta overlay spliced in by
-binary search. Whatever it answers, ``scalar + blob`` parsed as a
+A wire read answers ``RangeResponse.kvs`` bytes written from the mirror's
+host arrays by one native call (``kb_wire_read``: key decode, the delta
+overlay merged in by one walk, the cut at ``limit``). Whatever it answers, ``scalar + blob`` parsed as a
 ``RangeResponse`` must be what the rows of ``range_`` give through
 ``shim.to_kv`` — and serialize back to the very same bytes — over both key
 encodings, one and several partitions, every way an overlay entry can meet
@@ -67,9 +67,11 @@ def apply_overlay(b: Backend, kind: str, revs: dict) -> None:
 _BACKENDS: dict[tuple, Backend] = {}
 
 
-def backend(encode: bool, partitions: int, overlay: str) -> Backend:
+def backend(encode: bool, partitions: int, overlay: str,
+            apply=apply_overlay) -> Backend:
     """One backend a (key encoding, partition count, overlay): the cases
-    over limits and modes read it, none writes."""
+    over limits and modes read it, none writes. ``apply`` writes the
+    overlay named ``overlay``."""
     if (encode, partitions, overlay) in _BACKENDS:
         return _BACKENDS[encode, partitions, overlay]
     store = TpuKvStorage(new_storage("memkv"), mesh=make_mesh(n_devices=1),
@@ -88,7 +90,7 @@ def backend(encode: bool, partitions: int, overlay: str) -> Backend:
     mirror = sc._mirror
     assert mirror.partitions == partitions and (mirror.encoding is not None) == encode
     assert (mirror.n_valid > 0).sum() == partitions  # rows in every one
-    apply_overlay(b, overlay, revs)
+    apply(b, overlay, revs)
     assert sc.merge_count == 0 and (len(sc._delta) > 0) == (overlay != "none")
     _BACKENDS[encode, partitions, overlay] = b
     return b
@@ -165,6 +167,135 @@ def test_wire_reply_is_the_rows_reply(encode, partitions, overlay, limit, mode):
     assert rr == rows.revision == rev and (n, more) == (rows.count, rows.more)
     assert_same(reply(blob, n, more, rr), rows_reply(sc, s1, e1, rev, lim))
     assert cnt == sc.count(s1, e1, rev)
+
+
+EDGE_OVERLAYS = ("equals_first_and_last", "consecutive_deletions",
+                 "between_partitions", "limit_on_overlay_row", "dead_only")
+
+
+def apply_edge_overlay(b: Backend, kind: str, revs: dict) -> None:
+    """The ways an overlay meets the mirror's rows that ``OVERLAYS`` does
+    not reach (``edge-`` + one of ``EDGE_OVERLAYS``)."""
+    kind = kind.removeprefix("edge-")
+
+    def key(ns: int, i: int) -> bytes:
+        return NS % ns + b"pod-%04d" % i
+
+    if kind == "equals_first_and_last":
+        # an entry ON the first and on the last visible row, of namespace
+        # 1's span and of the whole keyspace's: one updated, one deleted
+        for k in (key(1, 0), key(3, ROWS - 1)):
+            revs[k] = b.update(k, b"edge" * 5, revs[k])
+        b.delete(key(1, ROWS - 1), revs.pop(key(1, ROWS - 1)))
+        b.delete(key(2, 0), revs.pop(key(2, 0)))
+    if kind == "consecutive_deletions":
+        # three rows in a row gone, a key made and deleted between two of
+        # them (a dead entry with no row), then two rewritten neighbours
+        for i in (10, 11, 12):
+            b.delete(key(1, i), revs.pop(key(1, i)))
+        b.delete(key(1, 11) + b"x", b.create(key(1, 11) + b"x", b"gone"))
+        for i in (13, 14):
+            revs[key(1, i)] = b.update(key(1, i), b"", revs[key(1, i)])
+    if kind == "between_partitions":
+        # entries that sort after one partition's last row and before the
+        # next one's first, live and dead, at every border
+        mirror = b.scanner._mirror
+        for p in range(mirror.partitions):
+            last = mirror.user_key(p, int(mirror.n_valid[p]) - 1)
+            b.create(last + b"-border", b"between %d" % p)
+            b.delete(last + b"-gone", b.create(last + b"-gone", b"x"))
+    if kind == "limit_on_overlay_row":
+        # rows 11 and 12 of namespace 1 are the overlay's: LIMITS' cut (11)
+        # ends the reply ON an overlay row, with another one behind it
+        b.create(key(1, 9) + b"x", b"the eleventh row")
+        b.create(key(1, 9) + b"y", b"the twelfth")
+    if kind == "dead_only":
+        # nothing but deletions of keys the mirror never had
+        for i in (0, 1):
+            k = NS % 1 + b"never-%d" % i
+            b.delete(k, b.create(k, b"x"))
+
+
+@pytest.mark.parametrize("overlay", EDGE_OVERLAYS)
+@pytest.mark.parametrize("partitions", [1, 4], ids=["one_part", "four_parts"])
+@pytest.mark.parametrize("encode", [False, True], ids=["raw", "encoded"])
+def test_wire_reply_is_the_rows_reply_at_the_overlays_edges(encode, partitions,
+                                                            overlay):
+    """The overlay cases ``OVERLAYS`` leaves out, each over every limit
+    that moves the cut across the rows in question, single and batched."""
+    b = backend(encode, partitions, "edge-" + overlay, apply_edge_overlay)
+    sc, rev = b.scanner, b.current_revision()
+    spans = [span_of(1), (b"/registry/pods/", b"/registry/pods0"), span_of(2)]
+    limits = (0, 10, 11, 12, 13, ROWS - 1, ROWS, ROWS + 1, 3 * ROWS, 1000)
+    for start, end in spans:
+        for lim in limits:
+            blob, n, more = sc.list_wire(start, end, rev, lim)
+            want = rows_reply(sc, start, end, rev, lim)
+            assert (n, more) == (want.count, want.more), (start, lim)
+            assert_same(reply(blob, n, more, rev), want)
+    specs = [("wire", *spans[0], rev, 11), ("wire", *spans[1], rev, 0),
+             ("count", *spans[0], rev), ("wire", *spans[2], rev, ROWS)]
+    for spec, res in zip(specs, sc.scan_batch(specs)):
+        if spec[0] == "wire":
+            assert res == sc.list_wire(*spec[1:])
+            assert_same(reply(*res, rev), rows_reply(sc, *spec[1:]))
+    if overlay == "limit_on_overlay_row":
+        last = rpc_pb2.RangeResponse.FromString(
+            sc.list_wire(*spans[0], rev, 11)[0]).kvs[-1]
+        assert last.value == b"the eleventh row"
+        assert sc.list_wire(*spans[0], rev, 11)[1:] == (11, True)
+
+
+@pytest.mark.parametrize("encode", [False, True], ids=["raw", "encoded"])
+def test_a_wire_read_is_one_foreign_call_and_no_python_per_row(encode, monkeypatch):
+    """The host half of a device-path wire read (``_materialize_wire``) is
+    ONE ``kb_wire_read`` and a fixed number of Python-level calls: a read of
+    three times the rows under three times the overlay entries makes
+    exactly as many (counted through ``sys.setprofile``, Python and C
+    callees alike), and neither the Python decode funnel nor the run
+    gather is on its way."""
+    import sys
+
+    from kubebrain_tpu.storage import native
+    from kubebrain_tpu.storage.tpu.blocks import Mirror
+
+    b = backend(encode, 1, "mixed")
+    sc, rev = b.scanner, b.current_revision()
+    calls = {"foreign": 0, "python": 0}
+    real = native.load_lib().kb_wire_read
+
+    def foreign(*args):
+        calls["foreign"] += 1
+        return real(*args)
+
+    def refuse(*_a, **_k):
+        raise AssertionError("the wire read left its one native call")
+
+    monkeypatch.setattr(native._lib, "kb_wire_read", foreign, raising=False)
+    monkeypatch.setattr(Mirror, "decoded_keys", refuse)
+    monkeypatch.setattr(native, "wire_gather", refuse)
+    materialize = sc._materialize_wire
+
+    def counted(*args):
+        def on_event(_frame, event, _arg):
+            calls["python"] += event in ("call", "c_call")
+        sys.setprofile(on_event)
+        try:
+            return materialize(*args)
+        finally:
+            sys.setprofile(None)
+
+    monkeypatch.setattr(sc, "_materialize_wire", counted)
+    seen = []
+    for start, end in span_of(1), (b"/registry/pods/", b"/registry/pods0"):
+        calls.update(foreign=0, python=0)
+        blob, n, _more = sc.list_wire(start, end, rev, 0)
+        seen.append((n, calls["foreign"], calls["python"]))
+        assert len(rpc_pb2.RangeResponse.FromString(blob).kvs) == n
+    (n1, f1, py1), (n3, f3, py3) = seen
+    assert n3 > 2.5 * n1 > 100  # the second read is three namespaces'
+    assert f1 == f3 == 1
+    assert py1 == py3 and py1 < 100, seen
 
 
 def test_wire_read_at_an_old_revision_ignores_the_later_overlay():
@@ -286,9 +417,10 @@ def test_the_two_fall_backs_answer_from_the_host_in_one_round(inner, why, monkey
 
 def test_a_stale_library_is_rebuilt_or_refused_at_load(tmp_path, monkeypatch):
     """A ``libkbstore.so`` older than its source is rebuilt before it is
-    loaded; one that is there, not older, and lacks the gather's symbol (a
-    build of an earlier tree that kept its place through a copy) is an
-    error at load — never a silent rows path."""
+    loaded; one that is there, not older, and lacks the wire read's symbol
+    (a build of an earlier tree — PR 32's had the gather and not yet
+    ``kb_wire_read`` — that kept its place through a copy) is an error at
+    load — never a silent rows path."""
     import os
     import subprocess
 
@@ -296,7 +428,8 @@ def test_a_stale_library_is_rebuilt_or_refused_at_load(tmp_path, monkeypatch):
     from kubebrain_tpu.storage.errors import StorageError
 
     lib, src = str(tmp_path / "libkbstore.so"), tmp_path / "kbstore.cc"
-    src.write_text('extern "C" { unsigned long kb_mvcc_list_wire() { return 0; } }\n')
+    src.write_text('extern "C" { unsigned long kb_mvcc_list_wire() { return 0; }\n'
+                   'unsigned long kb_wire_gather() { return 0; } }\n')
     assert native._lib_stale(lib)  # no library yet
     built = []
 
@@ -307,8 +440,8 @@ def test_a_stale_library_is_rebuilt_or_refused_at_load(tmp_path, monkeypatch):
     monkeypatch.setattr(native, "_lib", None)
     monkeypatch.setattr(native, "_LIB_PATH", lib)
     monkeypatch.setattr(native, "_build_lib", build)
-    with pytest.raises(StorageError, match="kb_wire_gather"):
-        native.load_lib()
+    with pytest.raises(StorageError, match="lacks kb_wire_read:"):
+        native.load_lib()  # the wire read's symbol alone is missing
     assert built == [lib] and not native._lib_stale(lib)
     with pytest.raises(StorageError, match="stale build"):
         native.load_lib()
@@ -376,16 +509,23 @@ def test_a_wire_source_of_the_wrong_layout_is_refused_not_read(spoil):
 def test_the_mirror_holds_its_value_columns_as_the_gather_reads_them():
     """Whatever built or merged a mirror, its value columns are uint8 /
     uint64 and contiguous from construction on, and a read hands the
-    gather those very arrays: no conversion (a copy of a partition's
-    offsets under the GIL) on a Range."""
+    native call those very arrays (``Mirror.wire_cols``, made once a
+    mirror): no conversion (a copy of a partition's offsets under the GIL)
+    on a Range."""
     import dataclasses
 
     import numpy as np
 
     mirror = backend(True, 3, "none").scanner._mirror
     for p in range(mirror.partitions):
-        src = mirror.wire_source(p, np.arange(int(mirror.n_valid[p])))
-        assert src[3] is mirror.val_arena[p] and src[4] is mirror.val_offsets[p]
+        keys, lens, revs, arena, offsets, n = (int(w) for w in mirror.wire_cols[p])
+        assert arena == mirror.val_arena[p].ctypes.data
+        assert offsets == mirror.val_offsets[p].ctypes.data
+        assert n == len(mirror.val_offsets[p]) - 1 == mirror.n_valid[p]
+        # and the key, length and revision columns where the mirror has them
+        assert (keys, lens, revs) == (mirror.keys_host[p].ctypes.data,
+                                      mirror.lens_host[p].ctypes.data,
+                                      mirror.revs_host[p].ctypes.data)
     as_int64 = dataclasses.replace(
         mirror, val_offsets=[o.astype(np.int64) for o in mirror.val_offsets])
     assert all(o.dtype == np.uint64 and o.flags.c_contiguous

@@ -618,12 +618,14 @@ _DECODE_PRIMITIVE_FUNNELS = {"decoded_keys", "user_key"}
 #: ``_compact_victim_rows``; a whole-partition ``decoded_keys`` call from
 #: ``compact`` (the pre-PR-12 shape) is exactly the host decode tax the
 #: pipeline removed, and must be flagged.
+#: The wire read (``_materialize_wire``) is in neither set: since PR 34 it
+#: calls no Python decode at all — ``kb_wire_read`` decodes each visible row
+#: in C as it writes the reply (``wire_key``, native/kbstore.cc), a twin of
+#: ``decoded_keys`` that tests/test_wire_read.py holds to it; a Python
+#: decode that came back onto the wire path would be flagged here.
 _DECODE_FUNNEL_CALLERS = {
     "materialize", "flat_arrays", "merge_partitions_incremental",
     "_compact_victim_rows", "_materialize_visible",
-    # the wire path's twin of ``materialize``: the visible rows of one
-    # partition, decoded for ``kb_wire_gather`` (never the whole mirror)
-    "wire_source",
 }
 
 
@@ -639,7 +641,10 @@ class DecodeOnlyAtMaterializationFunnels(Rule):
     visible-row/victim-row sizing — the exact cost the prefix-compressed
     mirror (docs/compression.md) and the stored-domain compaction
     (docs/compaction.md) remove. In particular a whole-partition decode
-    from ``compact`` itself — the pre-stored-domain shape — is flagged."""
+    from ``compact`` itself — the pre-stored-domain shape — is flagged.
+    The one decode this rule cannot see is the wire read's, in C inside
+    ``kb_wire_read`` (sized by the visible rows like the funnels, and held
+    equal to ``decoded_keys`` by tests/test_wire_read.py)."""
 
     rule_id = "KB116"
     summary = ("storage/tpu/: encoded-key decode only through the "
@@ -680,7 +685,7 @@ class DecodeOnlyAtMaterializationFunnels(Rule):
                     yield node, (
                         f"decoded_keys(){where}: decoded key bytes only "
                         "leave the mirror through the named materialization"
-                        "/rebuild paths (materialize, wire_source, flat_arrays, "
+                        "/rebuild paths (materialize, flat_arrays, "
                         "merge_partitions_incremental, _compact_victim_rows)"
                     )
 
