@@ -17,6 +17,16 @@ the server to the guarantees the configuration states:
   seeded sample of written keys, read back, differ from the reference;
 - ``watch_wrong``: a watcher's events are not exactly its range's
   acknowledged writes, once each, in revision order, with their keys/values;
+- where the mix holds a Compact (kube-apiserver's compactor, ``ops/
+  compact.py``): ``compact_refused``, a compactor tick not acknowledged;
+  ``compact_readback_wrong``, after the window a seeded sample of each
+  written table's namespaces and a Count, read at the compact revision C,
+  differ from the reference; ``compacted_reads_not_refused``, the same reads
+  at C - 1 did not answer "required revision has been compacted";
+  ``compact_victims_wrong``, the server's ``kb_compact_victims_total``
+  (``superseded`` + ``tombstone``) differs from the revisions the reference
+  removed (etcd's Compact: each key keeps its latest revision at or below
+  C, unless that is a tombstone, which goes too);
 - ``mirror_not_serving`` / ``device_reads_unmoved`` /
   ``readback_device_unmoved``: the server's own account says the device
   mirror was not serving, or it dispatched fewer device scans than the
@@ -39,20 +49,31 @@ import numpy as np
 from state import SPACE, State
 
 CLOCK_SLOP_S = 0.002
-TXN = 1
+RANGE, TXN, COMPACT = 0, 1, 2
+#: etcd's answer to a read below the compact revision
+ERR_COMPACTED = "required revision has been compacted"
 
 
 class Reference:
-    """The start state plus the acknowledged writes, as per-key histories."""
+    """The start state (its whole history: ``State.at_many``) plus the
+    acknowledged writes, as per-key histories, and the acknowledged
+    Compacts."""
 
     def __init__(self, state: State, traffic: list[dict]):
         self.state = state
         self.writes = []       # (rev, key_id, ver, dead, sent, done)
         self.uncertain: set[int] = set()
         self.refused = 0
+        self.compacts = []     # (compact revision, its Txn's revision)
+        self.ticks = self.ticks_failed = 0
         for dump in traffic:
             for (family, _op, _due, sent, done, ok, rev, key_id, ver, _rows,
                  err, dead) in dump["recs"]:
+                if family == COMPACT:
+                    self.ticks += 1
+                    self.ticks_failed += not ok
+                    if ok:
+                        self.compacts.append((rev, ver))
                 if family != TXN:
                     continue
                 if ok:
@@ -66,19 +87,40 @@ class Reference:
         for rev, key_id, ver, dead, _s, _d in self.writes:
             self.history.setdefault(key_id, []).append((rev, ver, not dead))
 
-    def at(self, key_id: int, revision: int):
+    def at(self, key_id: int, revision: int, start=None):
         """(version, mod_revision) of the key at ``revision``, None if it
-        does not exist then."""
-        t, i = self.state.locate(key_id)
-        cur = None
-        if i < t.count and self.state.rev[t.name][i] <= revision:
-            if self.state.live[t.name][i]:
-                cur = (int(self.state.ver[t.name][i]), int(self.state.rev[t.name][i]))
+        does not exist then; ``start``: its start state's, if known."""
+        if start is None:
+            t, i = self.state.locate(key_id)
+            start = self.state.at(t.name, i, revision)
+        cur = start
         for rev, ver, live in self.history.get(key_id, ()):
             if rev > revision:
                 break
             cur = (ver, rev) if live else None
         return cur
+
+    @property
+    def compacted(self) -> int:
+        """The highest compact revision acknowledged, 0 without one."""
+        return max((c for c, _ in self.compacts), default=0)
+
+    def removed(self, revision: int) -> tuple[int, int]:
+        """(superseded, tombstones): the revisions a Compact at ``revision``
+        removes, by etcd's rule — every revision of a key below its latest
+        one at or below ``revision``, and that one too where it is a
+        tombstone. So every tombstone at or below it goes, and of the rest
+        all but one for each key live at ``revision``."""
+        events = tombs = live = 0
+        for name, t in self.state.tables.items():
+            events += self.state.events_upto(name, revision)
+            tombs += self.state.events_upto(name, revision, tombstones=True)
+            live += self.count(t.prefix, revision)
+        for rev, _k, _v, dead, _s, _d in self.writes:
+            if rev <= revision:
+                events += 1
+                tombs += dead
+        return events - tombs - live, tombs
 
     def table_of(self, key: bytes):
         for t in self.state.tables.values():
@@ -90,7 +132,7 @@ class Reference:
         """(key, key_id) of every key the table ever held (the start state's
         and every one a write touched), sorted; built once."""
         if t.name not in self._keys:
-            ids = set(range(t.offset, t.offset + t.count))
+            ids = set(range(t.offset, t.offset + t.ids))
             ids.update(k for k in self.history
                        if t.offset <= k < t.offset + SPACE)
             self._keys[t.name] = sorted((t.key(k - t.offset), k) for k in ids)
@@ -109,25 +151,33 @@ class Reference:
         """The reference's answer to an unlimited Range, without the keys
         whose write failed."""
         rows = []
-        for key, key_id in self.candidates(start, end):
+        cands = self.candidates(start, end)
+        if not cands:
+            return rows
+        t = self.table_of(start)
+        ver, rev, live = self.state.at_many(
+            t.name, [k - t.offset for _key, k in cands], revision)
+        for n, (key, key_id) in enumerate(cands):
             if key_id in self.uncertain:
                 continue
-            cur = self.at(key_id, revision)
+            cur = self.at(key_id, revision,
+                          (int(ver[n]), int(rev[n])) if live[n] else None)
             if cur is not None:
-                t, i = self.state.locate(key_id)
-                rows.append((key, cur[1], self.state.value_crc(t, i, cur[0])))
+                rows.append((key, cur[1], self.state.value_crc(
+                    t, key_id - t.offset, cur[0])))
         return rows
 
     def count(self, start: bytes, revision: int) -> int:
         """The live keys of the table whose prefix is ``start`` at
         ``revision``: the start state's, moved by every write up to it."""
         t = self.table_of(start)
-        n = int(self.state.live[t.name].sum())
+        n = self.state.live_count(t.name, revision)
         for k, hist in self.history.items():
             if not t.offset <= k < t.offset + SPACE:
                 continue
+            # every write of the window comes after the start state's head
             i = k - t.offset
-            live = i < t.count and bool(self.state.live[t.name][i])
+            live = i < t.ids and bool(self.state.live[t.name][i])
             for rev, _ver, alive in hist:
                 if rev > revision:
                     break
@@ -161,7 +211,7 @@ def compare(state: State, traffic: list[dict], watches: list[dict],
             if message:
                 wrong += 1
                 first_wrong = first_wrong or message
-    sent_ranges = any(r[0] != TXN for dump in traffic for r in dump["recs"])
+    sent_ranges = any(r[0] == RANGE for dump in traffic for r in dump["recs"])
     if sent_ranges:
         out["range_rows_wrong"] = _entry(wrong)
         out["compared_range_answers"] = _entry(answers, 1, ">=")
@@ -178,7 +228,7 @@ def compare(state: State, traffic: list[dict], watches: list[dict],
         for (family, _op, _due, sent, _done, ok, rev, _key, pinned,
              *_rest) in dump["recs"]:
             # pages after a list's first are pinned to the first's revision
-            if family == TXN or not ok or pinned:
+            if family != RANGE or not ok or pinned:
                 continue
             n = bisect.bisect_left(ack_t, sent - CLOCK_SLOP_S)
             fresh_checked += 1
@@ -188,8 +238,8 @@ def compare(state: State, traffic: list[dict], watches: list[dict],
         out["range_stale"] = _entry(stale)
         out["compared_range_fresh"] = _entry(fresh_checked, 1, ">=")
 
-    # ---- writes
-    revs = [w[0] for w in ref.writes]
+    # ---- writes (a compactor's Txn is one too)
+    revs = [w[0] for w in ref.writes] + [txn for _c, txn in ref.compacts]
     out["writes_refused"] = _entry(ref.refused)
     out["revisions_reused"] = _entry(
         len(revs) - len(set(revs)) + sum(r <= state.head_revision for r in revs))
@@ -225,6 +275,25 @@ def compare(state: State, traffic: list[dict], watches: list[dict],
         if first:
             out["watch_wrong"]["first"] = first
 
+    # ---- kube-apiserver's compactor: every tick acknowledged; after the
+    # window the reads at C exact and those at C - 1 refused as compacted;
+    # the server's victims the revisions etcd's rule removes
+    if ref.ticks:
+        out["compact_refused"] = _entry(ref.ticks_failed)
+        out["compared_compacts"] = _entry(ref.ticks, 1, ">=")
+        back = readback.get("compaction") or {}
+        out["compact_readback_wrong"] = _entry(back.get("wrong", 0))
+        out["compared_compact_readback"] = _entry(back.get("compared", 0), 1, ">=")
+        out["compacted_reads_not_refused"] = _entry(back.get("not_refused", 0))
+        out["compared_compacted_reads"] = _entry(back.get("below", 0), 1, ">=")
+        if back.get("first"):
+            out["compact_readback_wrong"]["first"] = back["first"]
+        victims = (prom or {}).get("compact_victims")
+        removed = sum(back.get("removed") or ())
+        if victims is not None and back.get("removed") is not None:
+            out["compact_victims_wrong"] = _entry(abs(int(victims) - removed))
+            out["compared_compact_victims"] = _entry(removed, 1, ">=")
+
     # ---- the server's own account: the device served
     if prom is not None:
         out["mirror_not_serving"] = _entry(prom["not_serving"])
@@ -237,21 +306,32 @@ def compare(state: State, traffic: list[dict], watches: list[dict],
     return out
 
 
-def device_account(later: dict, earlier: dict) -> dict:
+def device_account(later: dict, earlier: dict, rpc: str | None = None) -> dict:
     """What the server's ``/metrics`` say of the device read path between
     two scrapes. ``device_dispatch`` is the one stage that only
     ``TpuScanner``'s kernel path records (the host scanner records its
     iteration as ``device_compute``); a batch of n reads is one dispatch
     (``kb_sched_batch_size``: sum - count are its riders) and a read that
-    joined an identical one in flight has none of its own."""
+    joined an identical one in flight has none of its own. ``rpc`` (where
+    the mix holds a Compact: ``READ_RPC``) counts only the dispatches of
+    that RPC's spans, so that no dispatch of other work could stand for a
+    read that skipped the device."""
     import prom
 
+    labels = {"stage": "device_dispatch"}
+    if rpc is not None:
+        labels["rpc"] = rpc
     return {
         "dispatches": prom.delta(later, earlier, "kb_rpc_stage_seconds_count",
-                                 stage="device_dispatch"),
+                                 **labels),
         "riders": prom.delta(later, earlier, "kb_sched_batch_size_sum")
         - prom.delta(later, earlier, "kb_sched_batch_size_count"),
         "coalesced": prom.delta(later, earlier, "kb_sched_coalesced")}
+
+
+#: the span every Range and Count is served in (``kb_rpc_stage_seconds``'s
+#: ``rpc`` label since PR 26)
+READ_RPC = "etcd.KV/Range"
 
 
 def undispatched(reads: int, account: dict) -> int:
@@ -369,4 +449,65 @@ def read_back(stub, etcd, state: State, traffic: list[dict], seed: int,
             first = first or f"Get {t.key(i)!r}: {got} != {want}"
     # the reference, with its key lists built, for ``compare`` to go on with
     return {"wrong": wrong, "compared": compared, "first": first, "top": top,
-            "ref": ref, "device_reads": device_reads}
+            "ref": ref, "device_reads": device_reads,
+            "compaction": read_back_compacted(stub, etcd, ref, rnd)}
+
+
+def read_back_compacted(stub, etcd, ref: Reference, rnd) -> dict | None:
+    """After a Compact to C: a seeded sample of each written table's
+    namespaces (three at most) and a Count over it, read at C, against the
+    reference at C; the same reads at C - 1 have to answer "required
+    revision has been compacted". And what the reference removed at C, by
+    kind (None where a failed write may have landed at or below C)."""
+    import grpc
+
+    c = ref.compacted
+    if not c:
+        return None
+    out = {"wrong": 0, "compared": 0, "not_refused": 0, "below": 0, "first": "",
+           "target": c, "removed": None}
+    if not (ref.uncertain and c > ref.state.head_revision):
+        out["removed"] = ref.removed(c)
+    written = {ref.state.locate(w[1])[0].name for w in ref.writes}
+    for name in sorted(written):
+        t = ref.state.tables[name]
+        prefixes = [t.ns_prefix(ns) for ns in sorted(rnd.sample(
+            range(t.namespaces), min(3, t.namespaces)))]
+        reads = [(p, etcd.prefix_end(p), False) for p in prefixes]
+        reads.append((t.prefix, etcd.prefix_end(t.prefix), True))
+        for start, end, count_only in reads:
+            if count_only and ref.uncertain:
+                continue    # a Count cannot leave a key out
+            resp = stub.range(etcd.range_request(start, end, revision=c,
+                                                 count_only=count_only),
+                              timeout=120.0)
+            if count_only:
+                got, want = resp.count, ref.count(start, c)
+                out["compared"] += 1
+            else:
+                skip = ref.uncertain_keys()
+                got = [(kv.key, kv.mod_revision, zlib.crc32(kv.value))
+                       for kv in resp.kvs if kv.key not in skip]
+                want = ref.rows(start, end, c)
+                out["compared"] += len(want)
+            if got != want:
+                out["wrong"] += 1
+                out["first"] = out["first"] or (
+                    f"{'Count' if count_only else 'Range'} {start!r} at the "
+                    f"compact revision {c}: {got if count_only else len(got)}"
+                    f", the reference holds {want if count_only else len(want)}")
+            out["below"] += 1
+            try:
+                stub.range(etcd.range_request(start, end, revision=c - 1,
+                                              count_only=count_only),
+                           timeout=120.0)
+                out["not_refused"] += 1
+                out["first"] = out["first"] or (
+                    f"{start!r} at {c - 1}, below the compact revision {c}: "
+                    "served")
+            except grpc.RpcError as e:
+                if ERR_COMPACTED not in (e.details() or ""):
+                    out["not_refused"] += 1
+                    out["first"] = out["first"] or (
+                        f"{start!r} at {c - 1}: {e.code().name} {e.details()}")
+    return out

@@ -22,6 +22,9 @@ import rpc_pb2  # noqa: E402
 RANGE = "/etcdserverpb.KV/Range"
 TXN = "/etcdserverpb.KV/Txn"
 WATCH = "/etcdserverpb.Watch/Watch"
+COMPACT = "/etcdserverpb.KV/Compact"
+#: kube-apiserver's compactor coordinates its replicas on this key
+COMPACT_REV_KEY = b"compact_rev_key"
 
 #: a 1.5 MB unpaged namespace list must fit one message
 _CHANNEL_OPTIONS = [("grpc.max_receive_message_length", 256 << 20),
@@ -47,6 +50,9 @@ class Stub:
         self.watch = self.channel.stream_stream(
             WATCH, request_serializer=rpc_pb2.WatchRequest.SerializeToString,
             response_deserializer=rpc_pb2.WatchResponse.FromString)
+        self.compact = self.channel.unary_unary(
+            COMPACT, request_serializer=rpc_pb2.CompactionRequest.SerializeToString,
+            response_deserializer=rpc_pb2.CompactionResponse.FromString)
 
     def close(self) -> None:
         self.channel.close()
@@ -82,6 +88,25 @@ def delete_txn(key: bytes, mod_revision: int):
     req.success.add().request_delete_range.CopyFrom(
         rpc_pb2.DeleteRangeRequest(key=key))
     return req
+
+
+def compactor_txn(token: int, revision: int):
+    """The Txn of one tick of kube-apiserver's compactor (k8s.io/apiserver
+    ``storage/etcd3/compact.go``): ``If(Version(compact_rev_key) == token)
+    Then(Put(compact_rev_key, revision)) Else(Get(compact_rev_key))``."""
+    req = rpc_pb2.TxnRequest()
+    c = req.compare.add()
+    c.result, c.target, c.key, c.version = (
+        rpc_pb2.Compare.EQUAL, rpc_pb2.Compare.VERSION, COMPACT_REV_KEY, token)
+    req.success.add().request_put.CopyFrom(
+        rpc_pb2.PutRequest(key=COMPACT_REV_KEY, value=b"%d" % revision))
+    req.failure.add().request_range.CopyFrom(
+        rpc_pb2.RangeRequest(key=COMPACT_REV_KEY))
+    return req
+
+
+def compaction_request(revision: int):
+    return rpc_pb2.CompactionRequest(revision=revision)
 
 
 def txn_revision(resp) -> int:

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The plain reference put in the program's place: a single-threaded-store,
 dict-and-sorted-list MVCC server that speaks the same etcd3 subset (Range,
-Txn, Watch) over the same start state. It is what ``correct`` is calibrated
+Txn, Watch, Compact) over the same start state, its history included. It is what ``correct`` is calibrated
 against: served whole it must read correct, and with ONE guarantee of the
 configuration broken (``--break``) it must read not correct.
 
@@ -15,6 +15,10 @@ Breaks (each is what a tempting shortcut in the program would do):
     dropped_event  1 watch event in 200 is not delivered
     altered_row    1 Range answer in 4 carries one row with another revision
                    (an answer altered where it is produced)
+    uncompacted    a Compact is acknowledged and nothing is removed: reads
+                   below it are still served (a floor never set)
+    over_compacted a Compact also drops the revision each table's first key
+                   keeps at C (a survivor taken for a victim)
 """
 
 from __future__ import annotations
@@ -32,10 +36,16 @@ sys.path.insert(0, HERE)
 
 import grpc  # noqa: E402
 
-from etcd import kv_pb2, rpc_pb2  # noqa: E402
+from etcd import COMPACT_REV_KEY, kv_pb2, rpc_pb2  # noqa: E402
 from state import State  # noqa: E402
 
-BREAKS = ("stale_read", "lost_write", "dropped_event", "altered_row")
+BREAKS = ("stale_read", "lost_write", "dropped_event", "altered_row",
+          "uncompacted", "over_compacted")
+ERR_COMPACTED = "etcdserver: mvcc: required revision has been compacted"
+
+
+class Compacted(Exception):
+    """A read below the compact revision."""
 
 
 class Store:
@@ -43,13 +53,14 @@ class Store:
         self.lock = threading.Lock()
         self.broken = broken
         self.rev = state.head_revision
-        # key -> [(mod_revision, value or None)], oldest first
+        self.compacted = 0
+        self.state = state
+        # key -> [(mod_revision, value or None)], oldest first: the start
+        # state's every revision, a value made when it is read
         self.hist: dict[bytes, list] = {}
-        for name, t in state.tables.items():
-            for i in range(t.count):
-                if state.live[name][i]:
-                    self.hist[t.key(i)] = [(int(state.rev[name][i]), state.value(
-                        t, i, int(state.ver[name][i])))]
+        for rev, (verb, t, i, ver, _guard) in enumerate(state.start_ops(), 1):
+            self.hist.setdefault(t.key(i), []).append(
+                (rev, None if verb == "delete" else (t, i, ver)))
         self.keys = sorted(self.hist)
         self.watchers: list = []
         self.counter = {"write": 0, "event": 0, "range": 0}
@@ -64,10 +75,15 @@ class Store:
             return self.rev
         return max(self.base, self.rev - 3)
 
+    def _value(self, val):
+        return self.state.value(*val) if isinstance(val, tuple) else val
+
     def range(self, req):
         with self.lock:
             head = self.read_revision()
             at = req.revision or head
+            if req.revision and req.revision < self.compacted:
+                raise Compacted()
             if not req.range_end:
                 keys = [bytes(req.key)] if req.key in self.hist else []
             else:
@@ -82,7 +98,7 @@ class Store:
                         break
                     cur = (rev, val)
                 if cur and cur[1] is not None:
-                    rows.append((k, cur[0], cur[1]))
+                    rows.append((k, cur[0], self._value(cur[1])))
             alter = (self.broken == "altered_row" and rows
                      and self._tick("range", 4))
         resp = rpc_pb2.RangeResponse(count=len(rows))
@@ -103,12 +119,18 @@ class Store:
         with self.lock:
             chain = self.hist.get(key)
             cur = chain[-1] if chain and chain[-1][1] is not None else None
-            if (cur[0] if cur else 0) != c.mod_revision:
+            # the compactor's Version guard: its token is the key's version
+            # here (the number of its writes), a mod_revision elsewhere
+            version = c.target == rpc_pb2.Compare.VERSION
+            have = (len(chain) if cur else 0) if version else (
+                cur[0] if cur else 0)
+            if have != (c.version if version else c.mod_revision):
                 resp = rpc_pb2.TxnResponse(succeeded=False)
                 resp.header.revision = self.rev
                 rr = resp.responses.add().response_range
                 if cur:
-                    rr.kvs.add(key=key, value=cur[1], mod_revision=cur[0])
+                    rr.kvs.add(key=key, value=self._value(cur[1]),
+                               mod_revision=cur[0], version=len(chain))
                 return resp
             self.rev += 1
             rev = self.rev
@@ -132,6 +154,34 @@ class Store:
             resp.responses.add().response_put.header.revision = rev
         else:
             resp.responses.add().response_delete_range.deleted = 1
+        return resp
+
+    def compact(self, req):
+        """etcd's Compact: each key keeps its latest revision at or below C,
+        unless it is a tombstone, which goes too; reads below C refuse."""
+        c = req.revision
+        with self.lock:
+            if c > self.rev:
+                raise ValueError("a future revision")
+            if self.broken != "uncompacted" and c > self.compacted:
+                self.compacted = c
+                # over_compacted: the first key of each table loses its
+                # survivor too
+                spared = {t.prefix for t in self.state.tables.values()}
+                for k in self.keys:
+                    chain = self.hist[k]
+                    keep = bisect.bisect_right(chain, c, key=lambda e: e[0]) - 1
+                    if keep < 0 or k == COMPACT_REV_KEY:
+                        continue
+                    drop = keep + (chain[keep][1] is None)
+                    prefix = next((p for p in spared if k.startswith(p)), None)
+                    if (self.broken == "over_compacted" and prefix
+                            and drop == keep):
+                        spared.discard(prefix)
+                        drop += 1
+                    del chain[:drop]
+        resp = rpc_pb2.CompactionResponse()
+        resp.header.revision = self.rev
         return resp
 
     def watch(self, requests, context):
@@ -161,6 +211,18 @@ class Store:
             yield resp
 
 
+def _refusing(context, call, req):
+    """etcd's error strings for a read below the compact revision and a
+    Compact above the head."""
+    try:
+        return call(req)
+    except Compacted:
+        context.abort(grpc.StatusCode.OUT_OF_RANGE, ERR_COMPACTED)
+    except ValueError:
+        context.abort(grpc.StatusCode.OUT_OF_RANGE,
+                      "etcdserver: mvcc: required revision is a future revision")
+
+
 def serve(config: dict, seed: int, port: int, broken: str = ""):
     """Start the reference on ``port``; returns the grpc server."""
     store = Store(State(config, seed), broken)
@@ -170,13 +232,17 @@ def serve(config: dict, seed: int, port: int, broken: str = ""):
     server.add_generic_rpc_handlers((
         grpc.method_handlers_generic_handler("etcdserverpb.KV", {
             "Range": grpc.unary_unary_rpc_method_handler(
-                lambda req, _ctx: store.range(req),
+                lambda req, ctx: _refusing(ctx, store.range, req),
                 request_deserializer=rpc_pb2.RangeRequest.FromString,
                 response_serializer=rpc_pb2.RangeResponse.SerializeToString),
             "Txn": grpc.unary_unary_rpc_method_handler(
                 lambda req, _ctx: store.txn(req),
                 request_deserializer=rpc_pb2.TxnRequest.FromString,
-                response_serializer=rpc_pb2.TxnResponse.SerializeToString)}),
+                response_serializer=rpc_pb2.TxnResponse.SerializeToString),
+            "Compact": grpc.unary_unary_rpc_method_handler(
+                lambda req, ctx: _refusing(ctx, store.compact, req),
+                request_deserializer=rpc_pb2.CompactionRequest.FromString,
+                response_serializer=rpc_pb2.CompactionResponse.SerializeToString)}),
         grpc.method_handlers_generic_handler("etcdserverpb.Watch", {
             "Watch": grpc.stream_stream_rpc_method_handler(
                 store.watch,

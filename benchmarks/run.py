@@ -439,6 +439,7 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
         ctx = Context(opts, workload, config, state, traffic, watch_dumps,
                       (win0, win1), setup_s, before, after,
                       entries_t1 - entries_t0, device, rate_scale, machine)
+        ctx.drained, ctx.compaction = drained, readback.get("compaction")
         if capture:
             log("capture: " + str({k: v for k, v in capture.items()
                                    if k != "scrapes"}))
@@ -459,6 +460,9 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
         account = None
         if before is not None:
             ops_dir = os.path.join(HERE, "ops")
+            # where the mix compacts, only the dispatches of reads count
+            rpc = check.READ_RPC if mergephase.compacts(
+                workload, seconds, rate_scale) else None
             account = {
                 "not_serving": sum(
                     prom.series_sum(s, "kb_mirror_state", state="serving") != 1.0
@@ -471,8 +475,13 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
                     1 for r in ctx.recs(0, judged_only=False, due_in_window=False)
                     if r[5] and t_before <= r[3] and r[4] <= t_after - SCRAPE_SLOP_S
                     and plugin.load(ops_dir, r[1]).DEVICE_READ),
-                "window": check.device_account(after, before),
-                "readback": check.device_account(final, drained)}
+                "window": check.device_account(after, before, rpc),
+                "readback": check.device_account(final, drained, rpc),
+                # the Compacts' victims by etcd's rule (a TTL's and the
+                # revision records' are another matter)
+                "compact_victims": sum(prom.delta(
+                    drained, before, "kb_compact_victims_total", kind=k)
+                    for k in ("superseded", "tombstone"))}
             log(f"device account: window {account['device_reads']} reads, "
                 f"{account['window']}; read-back {readback['device_reads']} "
                 f"reads, {account['readback']}")
@@ -496,10 +505,14 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
 
 
 def scale_tables(config: dict, scale: float) -> None:
-    """``--scale`` shrinks every table for a rehearsal; a cell runs at 1."""
+    """``--scale`` shrinks every table for a rehearsal, and the rates of its
+    history with it (the history's seconds stay, so that a Compact's target
+    still lies inside it); a cell runs at 1."""
     if scale != 1.0:
         for t in config["tables"]:
             t["count"] = max(t.get("namespaces", 1) * 4, int(t["count"] * scale))
+            if "history" in t:
+                t["history"] = {k: v * scale for k, v in t["history"].items()}
 
 
 def find_file(root: str, suffix: str) -> str:
@@ -538,6 +551,10 @@ class Context:
         self.rate_scale = rate_scale
         self.machine = machine or witness.Witness()
         self.trace: dict | None = None
+        # the scrape once every generator has drained, and the read-back
+        # after a Compact (``check.read_back_compacted``)
+        self.drained: dict | None = None
+        self.compaction: dict | None = None
         # rows the mirror holds: the start state's plus every write so far
         self.mirror_rows = state.rows + sum(
             1 for d in traffic for r in d["recs"] if r[0] == 1 and r[5])
@@ -709,7 +726,35 @@ def summary_lines(ctx: Context) -> list[str]:
     if counted is not None and not lo <= counted <= most:
         line += "  *** MERGE PHASE OFF THE DESIGN: this run measured another cell ***"
     out.append(line)
+    ticks = list(ctx.recs(check.COMPACT, judged_only=False, due_in_window=False))
+    if ticks:
+        out.append(compaction_line(ctx, ticks))
     return out
+
+
+def compaction_line(ctx: Context, ticks: list) -> str:
+    """Where the mix compacts: the compactor's ticks, the target C, the
+    victims by kind (the server's beside the reference's) and the pass's
+    phases."""
+    back = ctx.compaction or {"target": None, "removed": None}
+    took = " ".join(f"{(r[4] - r[2]) * 1e3:.0f}" for r in ticks)
+    line = (f"compaction: {len(ticks)} ticks, {sum(r[5] for r in ticks)} "
+            f"acknowledged, due -> answered (ms) {took}; target "
+            f"{back['target']} (start state's head {ctx.state.head_revision})")
+    ref = back["removed"]
+    if ref is not None:
+        line += (f"; reference removes superseded={ref[0]} tombstone={ref[1]}"
+                 f" of {ctx.mirror_rows} rows")
+    if ctx.drained is not None:
+        kinds = ("superseded", "tombstone", "ttl_expired", "rev_record")
+        victims = " ".join(f"{k}={prom.delta(ctx.drained, ctx.before, 'kb_compact_victims_total', kind=k):.0f}"
+                           for k in kinds)
+        phases = " ".join(f"{ph}={1e3 * prom.delta(ctx.drained, ctx.before, 'kb_compact_seconds_sum', phase=ph):.1f}ms"
+                          for ph in ("mark", "gc", "merge", "publish"))
+        passes = prom.delta(ctx.drained, ctx.before, "kb_compact_seconds_count",
+                            phase="mark")
+        line += f"; server victims {victims}; {passes:.0f} passes: {phases}"
+    return line
 
 
 def main(argv=None) -> int:

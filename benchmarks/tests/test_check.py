@@ -19,6 +19,8 @@ from state import State
 with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as _f:
     BENCHMARK = json.load(_f)
 CELLS = {w["name"]: w for w in BENCHMARK["workloads"]}
+with open(os.path.join(BENCH, "tests", "history_cell.json")) as _f:
+    HISTORY_CELL = json.load(_f)
 
 
 def _run(cell: str, broken: str, seed: int = 41):
@@ -56,6 +58,35 @@ def test_a_broken_guarantee_reads_not_correct(cell, broken, caught_by):
     n = out["numbers"][caught_by]
     assert n["value"] > n["limit"], out["numbers"]
     assert not check.verdict(out["numbers"])
+
+
+# ---- a Compact: the two controls of the new comparisons
+@pytest.mark.parametrize("seed", [2**31 + 101, 2**31 + 102, 2**31 + 103])
+@pytest.mark.parametrize("broken,caught_by", [
+    ("", None),
+    ("uncompacted", "compacted_reads_not_refused"),    # no floor set
+    ("over_compacted", "compact_readback_wrong"),      # a survivor dropped
+])
+def test_a_broken_compact_reads_not_correct(broken, caught_by, seed):
+    """The plain reference in the program's place, on the configuration with
+    a history and the mix with kube-apiserver's compactor: served whole it
+    reads correct; a Compact acknowledged and never applied, or one that
+    takes a survivor with its victims, reads not correct on every seed."""
+    opts = SimpleNamespace(workload="tiny-history.compacting", seed=seed,
+                           seconds=4.0, trace=0, sut="reference", broken=broken,
+                           scale=1.0, keep_trace="", chips=1)
+    workload = json.loads(json.dumps(HISTORY_CELL["traffic"]))
+    out = run.run_once(opts, workload, json.loads(json.dumps(HISTORY_CELL["config"])),
+                       BENCHMARK)
+    n = out["numbers"]
+    assert n["compared_compacts"]["value"] == 1 and n["compact_refused"]["value"] == 0
+    assert n["compared_compact_readback"]["value"] >= 1
+    assert n["compared_compacted_reads"]["value"] >= 1
+    if not broken:
+        assert check.verdict(n), n
+        return
+    assert n[caught_by]["value"] > n[caught_by]["limit"], n
+    assert not check.verdict(n)
 
 
 # ---- a failed write inside a sampled page (the fault of chip sets C and D)
@@ -133,6 +164,28 @@ def test_the_account_reads_the_stage_only_the_kernel_path_records():
     # the host scanner records its iteration as device_compute: not counted
     assert check.device_account(scrape(10, 900, 30, 10, 4), scrape(4, 100, 10, 4, 1)) == {
         "dispatches": 6.0, "riders": 14.0, "coalesced": 3.0}
+
+
+def test_where_the_mix_compacts_only_the_reads_dispatches_count():
+    """A dispatch outside a read's span (``rpc=""``: a Compact's marking, or
+    any background work that took the stage) cannot stand for a read that
+    skipped the device: by ``rpc`` only the Range spans' count. Without the
+    label filter (every cell that does not compact) the sum is the old one."""
+    import prom
+
+    def scrape(reads, other):
+        return prom.parse(
+            f'kb_rpc_stage_seconds_count{{stage="device_dispatch",rpc="etcd.KV/Range"}} {reads}\n'
+            f'kb_rpc_stage_seconds_count{{stage="device_dispatch",rpc=""}} {other}\n'
+            'kb_sched_batch_size_sum 0\nkb_sched_batch_size_count 0\n')
+    later, earlier = scrape(40, 9), scrape(10, 1)
+    assert check.device_account(later, earlier)["dispatches"] == 38.0
+    assert check.device_account(later, earlier, check.READ_RPC)["dispatches"] == 30.0
+    # 30 device reads, 30 dispatches of reads: none unmoved; with 8 of them
+    # sent down the host path, the Compact's 8 dispatches do not cover them
+    assert check.undispatched(30, check.device_account(later, earlier, check.READ_RPC)) == 0
+    assert check.undispatched(38, check.device_account(later, earlier, check.READ_RPC)) == 8
+    assert check.undispatched(38, check.device_account(later, earlier)) == 0
 
 
 @pytest.mark.parametrize("cell", ["k8s-2500.relist", "k8s-2500.relist-merge"])

@@ -18,6 +18,10 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     B = json.load(_f)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+with open(os.path.join(BENCH, "tests", "history_cell.json")) as _f:
+    #: a configuration with a history and a mix with kube-apiserver's
+    #: Compact, as a ``model_config`` PR adds them: files and entries only
+    HISTORY_CELL = json.load(_f)
 
 
 def test_names_and_files():
@@ -67,7 +71,7 @@ def test_a_twin_is_its_bases_definition_under_the_name_of_another_cell():
     assert not set(e2e) & set(layer)
     assert len(e2e) + len(layer) == len(B["end_to_end"]) + len(B["per_layer"])
     twins = [n for n in layer if n.endswith(".beside")]
-    assert len(twins) == 29
+    assert len(twins) == 30
     for twin in twins:
         base = twin[:-len(".beside")]
         entry = layer.get(base) or e2e[base]
@@ -162,3 +166,52 @@ def test_a_cell_a_configuration_and_a_metric_are_added_as_files_only(tmp_path):
     assert line["attempted"] == 128 and line["failed"] == 0
     assert line["rehearsal"]["comparison_passed"] and line["correct"] is False
     assert "merges in window: counted None, designed 0" in out.stdout
+
+
+def test_a_history_and_a_compact_are_added_as_files_only(tmp_path):
+    """The history and the compactor as a later PR adds them, rehearsed on
+    the CPU backend through the whole run: the Compact is acknowledged, the
+    reads at its revision C are the reference's, those at C - 1 refused, and
+    the server's victims are the revisions etcd's rule removes; one merge
+    for the Compact and none for the writes."""
+    shutil.copytree(BENCH, tmp_path / "benchmarks",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    for program in ("kubebrain_tpu", "native"):
+        (tmp_path / program).symlink_to(os.path.join(ROOT, program))
+    (tmp_path / "benchmarks" / "configs" / "tiny-history.json").write_text(
+        json.dumps(HISTORY_CELL["config"]))
+    (tmp_path / "benchmarks" / "traffic" / "compacting.json").write_text(
+        json.dumps(HISTORY_CELL["traffic"]))
+    (tmp_path / "benchmarks" / "metrics" / "list_p95_ms.json").write_text(json.dumps(
+        {"reader": "client_percentile", "args": {"family": "range", "q": 95}}))
+    bench = json.loads(json.dumps(B))
+    bench["configs"].append({"name": "tiny-history", "source": "test", "reduced": [],
+                             "file": "benchmarks/configs/tiny-history.json", "why": "t"})
+    bench["workloads"].append({"name": "tiny-history.compacting",
+                               "config": "tiny-history", "traffic": "compacting",
+                               "chips": 1, "why": "t"})
+    bench["end_to_end"].append({
+        "name": "list_p95_ms", "unit": "ms", "better": "lower", "bound": 0.2,
+        "source": "host_clock", "workloads": ["tiny-history.compacting"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    out = subprocess.run(
+        [sys.executable, str(tmp_path / "benchmarks" / "run.py"), "--workload",
+         "tiny-history.compacting", "--seed", str(2**31 + 35), "--seconds", "4",
+         "--trace", "0", "--sut", "cpu"],
+        capture_output=True, text=True, timeout=600, cwd=tmp_path,
+        env=dict(os.environ, JAX_COMPILATION_CACHE_DIR=os.path.join(ROOT, ".jax_cache")))
+    assert out.returncode == 0, out.stderr[-4000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    numbers = line["compared"]
+    for name in ("compact_refused", "compact_readback_wrong",
+                 "compacted_reads_not_refused", "compact_victims_wrong"):
+        assert numbers[name] == {"value": 0, "limit": 0}, (name, out.stderr[-3000:])
+    for name in ("compared_compacts", "compared_compact_readback",
+                 "compared_compacted_reads", "compared_compact_victims"):
+        assert numbers[name]["value"] >= 1, name
+    assert line["rehearsal"]["comparison_passed"], out.stderr[-3000:]
+    assert line["failed"] == 0
+    text = out.stdout
+    assert "compaction: 1 ticks, 1 acknowledged" in text, text[-3000:]
+    # the Compact's own merge, and no crossing: 1..1
+    assert "merges in window: counted 1, designed 0 crossings" in text, text[-3000:]

@@ -139,3 +139,73 @@ def test_a_design_on_the_edge_is_refused():
     steady["warmup_writes"] = 1000
     steady["streams"][0]["rate"] = 300         # a fourth merge within T/4
     assert mergephase.design_faults(steady, 50)
+
+
+@pytest.mark.parametrize("mix,crossings,expected", [
+    ("relist", (0, 0), (0, 0)), ("steady", (3, 3), (3, 3)),
+    ("relist-merge", (3, 3), (3, 12))])
+def test_the_three_mixes_read_what_they_read_before_the_compact(mix, crossings, expected):
+    """The parent's answers: no Compact in any of the three files, so no
+    stretch but the whole window."""
+    traffic = run.load_json("traffic", mix + ".json")
+    assert mergephase.compacts(traffic, 50) == []
+    assert mergephase.crossings(traffic, 50) == crossings
+    assert mergephase.expected(traffic, 50) == expected
+    assert mergephase.design_faults(traffic, 50) == []
+    assert mergephase.segments(traffic, 50, 270, late=True) == [
+        (0.0, 50, int(traffic["warmup_writes"]))]
+
+
+def _compactor(second, interval=300):
+    """kube-apiserver's compactor placed as a count poll is: due at
+    ``second`` of the window."""
+    return {"name": "compactor", "loop": "open", "rate": 1 / interval,
+            "phase": second / interval, "ops": [{"op": "compact", "interval_s": interval}]}
+
+
+def test_a_compact_publishes_the_delta_and_the_crossings_move():
+    """Steady's writers from a residue of 3,000 rows: crossings at 4.1 s,
+    then the Compact of 12 s publishes the delta (one merge), and the rows
+    count from 0 again — crossings at 27.2 and 42.3 s, whether the pass lasts
+    0 or 7 s. Three crossings and the Compact's merge; with 3 closed-loop
+    listers up to 3 follow-ups a crossing, none behind the Compact (its pass
+    holds the merge lock, ``_compact_active``)."""
+    mix = _mix(_WRITERS, _compactor(12), warmup_writes=3000, merges_in_window=3)
+    assert mergephase.compacts(mix, 50) == [pytest.approx(12.0)]
+    assert [(round(a, 6), round(b, 6), r) for a, b, r in
+            mergephase.segments(mix, 50, 270, late=True)] == [(0, 12, 3000), (19, 50, 0)]
+    assert mergephase.crossings(mix, 50) == (3, 3)
+    assert mergephase.expected(mix, 50) == (4, 4)
+    assert mergephase.design_faults(mix, 50) == []
+    listers = _mix(_WRITERS, _listers(3), _compactor(12), warmup_writes=3000)
+    assert mergephase.expected(listers, 50) == (4, 3 * 4 + 1)
+    # without the Compact the same writes cross four times, at 4.1, 19.2,
+    # 34.4 and 49.5 s: the last one's stall outlasts the window
+    plain = _mix(_WRITERS, warmup_writes=3000, merges_in_window=4)
+    assert mergephase.crossings(plain, 50) == (4, 4)
+    assert any("not over" in f for f in mergephase.design_faults(plain, 50))
+
+
+@pytest.mark.parametrize("second,fault", [
+    (15, "if a Compact's pass lasts 7 s"),        # 2 crossings after it, or 1
+    (8, "not over by the Compact at 8.0 s"),      # the crossing of 4.1 s meets it
+    (45, "its stall is not over by 50 s"),
+])
+def test_a_compact_on_the_edge_is_refused(second, fault):
+    mix = _mix(_WRITERS, _compactor(second), warmup_writes=3000, merges_in_window=3)
+    assert any(fault in f for f in mergephase.design_faults(mix, 50)), \
+        mergephase.design_faults(mix, 50)
+
+
+def test_a_compact_stands_alone_in_an_open_loop():
+    bad = _compactor(12)
+    bad["ops"].append({"op": "count", "table": "pods", "weight": 1})
+    with pytest.raises(ValueError):
+        mergephase.compacts(_mix(_WRITERS, bad), 50)
+
+
+def test_two_compacts_closer_than_a_stall_count_no_rows_between():
+    mix = _mix(_WRITERS, _compactor(10, interval=3), warmup_writes=0)
+    assert mergephase.compacts(mix, 20) == [pytest.approx(c) for c in (10, 13, 16, 19)]
+    assert mergephase.crossings(mix, 20) == (0, 0)
+    assert mergephase.expected(mix, 20) == (4, 4)

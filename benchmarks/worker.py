@@ -50,11 +50,12 @@ from state import State  # noqa: E402
 
 RPC_TIMEOUT_S = 60.0
 DRAIN_S = 60.0
-RANGE, TXN = 0, 1
+RANGE, TXN, COMPACT = 0, 1, 2
 
 
 class Rec:
-    """One RPC as the generator saw it."""
+    """One RPC as the generator saw it (a compactor's tick: its Txn and
+    its Compact, timed together from the tick's due time)."""
 
     __slots__ = ("family", "op", "due", "sent", "done", "ok", "rev", "key_id",
                  "ver", "rows", "walk", "req", "err", "dead", "count_only")
@@ -136,7 +137,12 @@ class Traffic:
         self.warm_failed = 0
         self.pools: dict[str, dict] = {}
         for op in self.stream["ops"]:
-            self._pool(op["table"])
+            if "table" in op:
+                self._pool(op["table"])
+        # the compactor's memory between ticks, as compact.go keeps it
+        self.compact_token = 0
+        self.compact_rev = 0
+        self.window0 = None
         # every block of the schedule holds the same operations, in an order
         # drawn from the seed
         self.block = [i for i, op in enumerate(self.stream["ops"])
@@ -148,15 +154,16 @@ class Traffic:
             return self.pools[name]
         t = self.state.tables[name]
         st = self.state
-        owned = [i for i in range(self.writer, t.count, self.writers)
+        owned = [i for i in range(self.writer, t.ids, self.writers)
                  if st.live[name][i]]
         rnd = random.Random(self.spec["seed"] * 7 + self.writer)
         rnd.shuffle(owned)
         pool = {"table": t, "live": owned, "cursor": 0,
                 "rev": {i: int(st.rev[name][i]) for i in owned},
                 "ver": {i: int(st.ver[name][i]) for i in owned},
-                # fresh indices stay in this writer's residue class
-                "next_new": t.count + (self.writer - t.count) % self.writers,
+                # fresh indices stay in this writer's residue class, after
+                # every index the start state used
+                "next_new": t.ids + (self.writer - t.ids) % self.writers,
                 "cycle": sorted(owned)}
         self.pools[name] = pool
         return pool
@@ -174,7 +181,7 @@ class Traffic:
             if self.warm_budget <= 0:
                 return False
             self.warm_budget -= 1
-        return mod.issue(self, op, self.pools[op["table"]], due)
+        return mod.issue(self, op, self.pools.get(op.get("table")), due)
 
     def pick(self, pool: dict, order: str, remove: bool):
         """A live key of this writer's with no write in flight, in the named
@@ -217,9 +224,50 @@ class Traffic:
         rec.req = (pool, i)
         self._send(rec, self.stub.txn, req)
 
+    def send_compact(self, due, kind, interval_s):
+        """One compactor tick (``ops/compact.py``): its Txn now, its Compact
+        when the Txn has succeeded (``_compactor_leg``). The target: the
+        revision the previous tick's Txn returned or, on the window's first
+        tick, the history's head ``interval_s`` before the due time (the
+        window opens at the history's end, at nominal times)."""
+        target = self.compact_rev or self.state.head_at(
+            self.state.history_seconds + round(due - self.window0, 3)
+            - interval_s)
+        rec = Rec(COMPACT, kind, due)
+        rec.rev, rec.req = target, ("txn", target)
+        self._send(rec, self.stub.txn,
+                   etcd.compactor_txn(self.compact_token, target))
+
+    def _compactor_leg(self, rec: Rec, fut) -> bool:
+        """A compactor tick's leg has landed; True where the Compact is now
+        in flight. As compact.go: the Txn's revision is the next tick's
+        target either way; a refused Txn takes the key's version as the next
+        token and compacts nothing, a succeeded one adds one to the token."""
+        leg, target = rec.req
+        rec.req = None
+        try:
+            resp = fut.result()
+        except grpc.RpcError as e:
+            rec.err = f"{e.code().name}: {e.details()}"[:200]
+            return False
+        if leg == "compact":
+            rec.ok, rec.rows = True, resp.header.revision
+            return False
+        self.compact_rev = etcd.txn_revision(resp)
+        if not resp.succeeded:
+            kvs = resp.responses[0].response_range.kvs if resp.responses else ()
+            self.compact_token = kvs[0].version if kvs else 0
+            rec.err = "refused"
+            return False
+        self.compact_token += 1
+        rec.ver = self.compact_rev
+        rec.req = ("compact", target)
+        self._send(rec, self.stub.compact, etcd.compaction_request(target))
+        return True
+
     def _send(self, rec: Rec, call, req):
         self.inflight += 1
-        rec.sent = time.monotonic()
+        rec.sent = rec.sent or time.monotonic()
         fut = call.future(req, timeout=RPC_TIMEOUT_S)
         fut.add_done_callback(lambda f, rec=rec: self._landed(rec, f))
 
@@ -230,6 +278,10 @@ class Traffic:
     # ---------------------------------------------------------- completion
     def handle(self, rec: Rec, fut) -> None:
         self.inflight -= 1
+        if rec.family == COMPACT:
+            if not self._compactor_leg(rec, fut):
+                self.recs.append(rec)
+            return
         self.recs.append(rec)
         if rec.family == TXN:
             self.writes_inflight -= 1
@@ -303,6 +355,7 @@ class Traffic:
                 # the window opens: its schedule starts at t0, not where the
                 # warm-up's left off
                 self.warming = False
+                self.window0 = window[0]
                 next_due = window[0] + phase
             stopping = window is not None and now >= window[1]
             if now >= begin:
