@@ -35,6 +35,7 @@ from .common import (
     COMPACT_KEY,
     LAST_REV_KEY,
     TOMBSTONE,
+    VERSION_PREFIX,
     KeyValue,
     RangeResult,
     Verb,
@@ -293,6 +294,93 @@ class Backend:
             self.tso.wait_committed(rev, timeout=5.0)
             if revealed:
                 self._await_revealed(revealed)
+
+    def put_counted(self, user_key: bytes, value: bytes, expected: int,
+                    by_version: bool) -> int:
+        """A put of a key whose writes are counted as etcd counts them
+        (:meth:`version`), guarded as etcd guards it: ``by_version``
+        compares ``expected`` with the key's Version, otherwise with its
+        mod_revision; 0 is "absent" either way. Returns the new revision.
+
+        The record, the object row, the watermark and the count go in ONE
+        engine batch, so the count is exactly as durable as the key. A
+        guard that does not hold raises CASRevisionMismatchError before a
+        revision is dealt (etcd's failed Txn moves no revision); a racing
+        put that commits between the read and this batch fails it the same
+        way, through the batch's compare-and-swaps."""
+        rev_key = coder.encode_revision_key(user_key)
+        raw_record = self._get_raw(rev_key)
+        raw_count = self._get_raw(VERSION_PREFIX + user_key)
+        record = coder.decode_rev_value(raw_record) if raw_record else None
+        live = record is not None and not record[1]
+        mod = record[0] if live else 0
+        count = (coder.decode_rev_value(raw_count)[0] if raw_count else 1) if live else 0
+        if (count if by_version else mod) != expected:
+            if mod:
+                self._await_revealed(mod)
+            raise CASRevisionMismatchError(
+                user_key, mod, self._read_object(user_key, mod) if mod else None)
+        ttl = creator.ttl_for_key(user_key)
+        rev = self.tso.deal()
+        event = WatchEvent(
+            revision=rev, verb=Verb.PUT if live else Verb.CREATE, key=user_key,
+            value=value, prev_revision=mod, valid=False,
+        )
+        revealed = 0
+        try:
+            if rev <= mod:
+                # drift-back anomaly (txn.go:171-175), as in update
+                revealed = mod
+                raise FutureRevisionError(rev, mod)
+            batch = self.store.begin_batch_write()
+            new_record = coder.encode_rev_value(rev)
+            if raw_record is None:
+                batch.put_if_not_exist(rev_key, new_record, ttl)
+            else:
+                batch.cas(rev_key, new_record, raw_record, ttl)
+            batch.put(coder.encode_object_key(user_key, rev), value, ttl)
+            batch.put(LAST_REV_KEY, coder.encode_rev_value(rev))
+            new_count = coder.encode_rev_value(count + 1)
+            if raw_count is None:
+                batch.put_if_not_exist(VERSION_PREFIX + user_key, new_count)
+            else:
+                batch.cas(VERSION_PREFIX + user_key, new_count, raw_count)
+            batch.commit()
+            event.valid = True
+            return rev
+        except CASFailedError as e:
+            revealed = -1
+            raise CASRevisionMismatchError(user_key, 0, None) from e
+        except UncertainResultError as e:
+            event.err = e
+            raise
+        finally:
+            self._notify(event)
+            txn_log("put", user_key, rev, event.err or sys.exc_info()[1])
+            self.tso.wait_committed(rev, timeout=5.0)
+            if revealed:
+                self._await_revealed(revealed)
+
+    def version(self, user_key: bytes, revision: int) -> int:
+        """etcd's Version of a key whose writes :meth:`put_counted` counts,
+        as its row at ``revision`` stood: the number of its writes since it
+        was created, 1 for the create. The count is read from the key's
+        ``VERSION_PREFIX`` row, less the key's rows above ``revision`` (each
+        a later write); it outlives the compaction of the key's older
+        revisions, which a count of its rows would not. A key written before
+        its writes were counted reads 1."""
+        raw = self._get_raw(VERSION_PREFIX + user_key)
+        count = coder.decode_rev_value(raw)[0] if raw else 1
+        later = sum(1 for _ in self.store.iter(
+            coder.encode_object_key(user_key, revision + 1),
+            coder.encode_object_key(user_key, coder.MAX_REVISION)))
+        return max(1, count - later)
+
+    def _get_raw(self, key: bytes) -> bytes | None:
+        try:
+            return self.store.get(key)
+        except KeyNotFoundError:
+            return None
 
     def delete(self, user_key: bytes, expected_revision: int = 0) -> tuple[int, KeyValue]:
         """Tombstone write. The reference pays three engine round-trips here

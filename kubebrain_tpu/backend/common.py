@@ -73,3 +73,7 @@ LEASE_STATE_KEY = META_PREFIX + b"lease_state"
 # from TiKV's PD timestamp domain dominating revision counts; an embedded
 # commit-counter clock needs the explicit watermark).
 LAST_REV_KEY = META_PREFIX + b"last_rev"
+# etcd's Version of a key whose writes are counted (Backend.put_counted):
+# one row per key, its write count since creation, beside the key's own
+# rows in every write batch that puts it.
+VERSION_PREFIX = META_PREFIX + b"version/"

@@ -575,6 +575,16 @@ class RequestScheduler:
             bexec=wexec,
         )
 
+    def put_counted(self, key: bytes, value: bytes, expected: int,
+                    by_version: bool, client: str = "") -> Any:
+        """``Backend.put_counted`` (etcd's Version guard) through the write
+        lanes; never part of a commit group, as its count rides its own
+        engine batch."""
+        return self.submit(
+            lambda: self.backend.put_counted(key, value, expected, by_version),
+            classify_write(key), client, key=None,
+        )
+
     def delete(self, key: bytes, expected_revision: int = 0,
                client: str = "") -> Any:
         wexec = self._backend_wexec

@@ -9,11 +9,15 @@ of etcd3 — this service recognizes exactly that subset and rejects the rest:
 - ``Txn`` pattern-matches the four transaction shapes the apiserver emits —
   create (mod/version == 0 guard + put), update (mod == rev guard + put),
   delete (mod == rev guard + delete_range), and the compactor's
-  coordination txn on the literal ``compact_rev_key`` — which under this
-  matcher is just a create/update with a VERSION guard (kv.go:160-230).
-  Version guards are honored with mod-revision semantics: the guard value is
-  an opaque token the compactor reads back from Get, so any per-update
-  changing token satisfies the protocol;
+  coordination txn on the literal ``compact_rev_key`` (kv.go:160-230);
+- ``compact_rev_key`` has etcd's Version: the number of its writes since it
+  was created, counted durably beside the key (``Backend.put_counted``),
+  so it outlives the compaction of the key's older revisions. Its Version
+  guard compares that count and a Get answers it, so kube-apiserver's
+  compactor (storage/etcd3/compact.go), which keeps ``t + 1`` after a
+  success without reading the key back, is acknowledged on every tick.
+  Every other key keeps the MVCC core's semantics: a Version guard there
+  is a mod-revision guard (0 = absent) and a row reads version 1;
 - raw ``Put``/``DeleteRange`` are unsupported (kv.go:142-148);
 - errors map to the etcd error strings clients key on (ErrCompacted /
   ErrFutureRev) so kube-apiserver re-lists correctly.
@@ -180,20 +184,16 @@ class KVService:
         if request.keys_only:
             kv = type(kv)(kv.key, b"", kv.revision)
         resp.kvs.append(shim.to_kv(kv))
-        self._fix_version_token(resp, request.key)
+        self._set_version(resp)
         return resp
 
-    @staticmethod
-    def _fix_version_token(resp, key: bytes) -> None:
-        """The apiserver compactor guards its coordination txns with
-        Version(compact_rev_key) and treats the value as an opaque token read
-        back from Get. The MVCC core doesn't track per-key versions (like the
-        reference, backendshim.go maps only revisions), so for this one key
-        version := mod_revision — a token that changes on every update, which
-        is all the protocol needs (kv.go:211-230)."""
-        if key == COMPACT_REV_KEY:
-            for kv in resp.kvs:
-                kv.version = kv.mod_revision
+    def _set_version(self, resp) -> None:
+        """``compact_rev_key``'s rows carry etcd's Version (the module
+        docstring); other rows keep version 1."""
+        for kv in resp.kvs:
+            if kv.key == COMPACT_REV_KEY:
+                kv.version = self.backend.version(COMPACT_REV_KEY,
+                                                  kv.mod_revision)
 
     def _list(self, request, range_end: bytes,
               client: str = "") -> rpc_pb2.RangeResponse | bytes:
@@ -240,6 +240,8 @@ class KVService:
                 if request.keys_only:
                     kv = type(kv)(kv.key, b"", kv.revision)
                 resp.kvs.append(shim.to_kv(kv))
+            if request.key == COMPACT_REV_KEY:
+                self._set_version(resp)
             return resp
 
     def _partitions(self, request) -> rpc_pb2.RangeResponse:
@@ -281,7 +283,11 @@ class KVService:
             # (contiguous revision block, one engine round trip, per-op
             # conflict demux; docs/writes.md)
             with TRACER.stage("backend_write"):
-                if kind == "create":
+                if kind in ("version", "counted"):
+                    rev = self.limiter.put_counted(
+                        key, value, guard_rev, by_version=kind == "version",
+                        client=client)
+                elif kind == "create":
                     rev = self.limiter.create(key, value, lease=lease,
                                               client=client)
                 elif kind == "update":
@@ -365,6 +371,15 @@ class KVService:
             if op.request_put.key != cmp.key:
                 context.abort(grpc.StatusCode.UNIMPLEMENTED, "etcdserver: key mismatch")
             kind = "create" if guard == 0 else "update"
+            if op.request_put.key == COMPACT_REV_KEY:
+                # every put of the compactor's key is counted (module
+                # docstring): "version" compares the count, "counted" the
+                # mod_revision
+                if op.request_put.lease > 0:
+                    context.abort(grpc.StatusCode.UNIMPLEMENTED,
+                                  "etcdserver: compact_rev_key takes no lease")
+                kind = ("version" if cmp.target == rpc_pb2.Compare.VERSION
+                        else "counted")
             # real lease attachment: PutRequest.lease names a lease granted
             # by LeaseService; the backend write path binds the key to it
             # and the reaper owns expiry (an explicit lease always beats the
@@ -403,7 +418,7 @@ class KVService:
                 kv = self.backend.get(r.key, r.revision)
                 rr = rpc_pb2.RangeResponse(header=shim.header(kv.revision), count=1)
                 rr.kvs.append(shim.to_kv(kv))
-                self._fix_version_token(rr, bytes(r.key))
+                self._set_version(rr)
             except (KeyNotFoundError, CompactedError):
                 rr = rpc_pb2.RangeResponse(
                     header=shim.header(self.backend.current_revision()), count=0

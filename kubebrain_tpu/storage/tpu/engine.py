@@ -349,23 +349,25 @@ def _victim_part_counts(mask, nv):
             jnp.sum(valid, axis=1, dtype=jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("size", "mesh"))
-def _part_survivor_indices(mask, nv, size, mesh=None):
-    """Per-partition compacted SURVIVOR row indices [P, size] (fill = N) of
-    a victim mask [P, N] — the compaction twin of `_part_indices_of_mask`
-    (which serves the victim side directly: the victim kernels already gate
-    validity; only the survivor complement needs the explicit ``valid``
-    conjunction). Shard-local along ``part``: a multi-device mesh never
-    all-gathers the mask, and the host pull is O(survivors per shard)."""
-    def local(m, n):
-        valid = jnp.arange(m.shape[-1], dtype=jnp.int32)[None, :] < n[:, None]
-        keep = valid & ~m
-        per_row = lambda row: jnp.nonzero(
-            row, size=size, fill_value=row.shape[0])[0]
-        return jax.vmap(per_row)(keep)
+@jax.jit
+def _survivor_mask(mask, nv):
+    """The complement of a victim mask [P, N] over the valid rows: the
+    SURVIVORS, whose indices `_part_indices_of_mask` then pulls (the victim
+    kernels gate validity themselves; only the complement needs the
+    explicit ``valid`` conjunction). Elementwise: GSPMD keeps the ``part``
+    axis sharded."""
+    valid = jnp.arange(mask.shape[-1], dtype=jnp.int32)[None, :] < nv[:, None]
+    return valid & ~mask
 
-    f = _maybe_shard_map(local, mesh, n_part_args=2)
-    return f(mask, nv)
+
+def _victim_pull_size(n_rows: int) -> int:
+    """The one index block `_pull_victim_indices` pulls, [P, N/8]: where
+    the victims or the survivors of every partition fit in it, their
+    indices cross as at most half the byte mask's bytes; where neither
+    does, the byte mask is the cheaper pull. One shape per mirror width,
+    which :meth:`TpuScanner._compact_warm` compiles before the first
+    Compact."""
+    return max(1, n_rows // 8)
 
 
 def _resolve_key_encoding(encode_keys: bool | None) -> bool:
@@ -529,7 +531,8 @@ class TpuScanner(Scanner):
         self._compact_last_error: Exception | None = None
         # True while a compaction holds _merge_lock across its whole pass
         # (mark → gc → mirror apply): read-path threshold merges SKIP
-        # instead of blocking on the lock for the compact's duration —
+        # (as beside a merge in flight: _ensure_published) instead of
+        # blocking on the lock for the compact's duration —
         # mirror+overlay stays exact, and the post-compact kick sweeps
         # the delta. Guarded by _mlock.
         self._compact_active = False
@@ -550,6 +553,10 @@ class TpuScanner(Scanner):
         # encode, place on the device): boot's mirror_build phase, paid by
         # the first read (kb_boot_seconds{phase="mirror_build"})
         self.boot_mirror_build_s: float | None = None
+        # the compaction's warm-up (_compact_warm): the shape it last ran
+        # for and its seconds (kb_boot_seconds{phase="compact_warm"})
+        self._warm_key: tuple | None = None
+        self.compact_warm_s: float | None = None
         # the tracer's profiler sink: trace/ imports no JAX, so the engine
         # that does hands it the annotation factory
         TRACER.set_annotator(jax.profiler.TraceAnnotation)
@@ -790,6 +797,7 @@ class TpuScanner(Scanner):
                 self._probe_cache = None
                 self.rebuild_bg_count += 1
                 self._exit_degraded_locked()
+            self._kick_compact_warm(m)
         return True
 
     # ------------------------------------------------------------ write feed
@@ -939,11 +947,13 @@ class TpuScanner(Scanner):
                           and (full or len(self._delta) >= self._merge_threshold))
             if not want_merge:
                 return
-            if not full and self._compact_active:
-                # a compaction holds _merge_lock for its whole pass:
-                # serve mirror+overlay (exact) instead of parking this
-                # reader on the lock; the compaction's own apply merges
-                # the sealed delta prefix anyway
+            if not full and (self._compact_active or self._merge_lock.locked()
+                             or self._merge_kick.locked()):
+                # a compaction or a merge holds _merge_lock (or a kicked
+                # merge is about to take it): serve mirror+overlay (exact)
+                # instead of parking this reader on the lock and then
+                # merging the tail that gathered behind it; the pass in
+                # flight merges the sealed delta prefix anyway
                 return
         if not full and plane is not None and plane.merges_suppressed():
             # chaos: serve mirror+overlay (the overlay stays exact); each
@@ -956,7 +966,7 @@ class TpuScanner(Scanner):
             self._merge_delta()
             return
         try:
-            self._merge_delta()
+            self._merge_delta(threshold=self._merge_threshold)
         except Exception as e:
             # read-path merge failure must not fail the READ: mirror +
             # overlay is still exact, only bigger. Counted like the
@@ -1022,6 +1032,7 @@ class TpuScanner(Scanner):
         self._pallas_ttl_cache = None
         self._probe_cache = None
         self._exit_degraded_locked()
+        self._kick_compact_warm(self._mirror)
         if self.boot_mirror_build_s is None:
             self.boot_mirror_build_s = time.monotonic() - t0
             if self._metrics is not None:
@@ -1036,8 +1047,11 @@ class TpuScanner(Scanner):
         seal = max(64, min(512, self._merge_threshold // 4 or 64))
         return _DeltaIndex(self._kw, encoding=enc, seal_rows=seal)
 
-    def _merge_delta(self) -> None:
+    def _merge_delta(self, threshold: int = 0) -> None:
         """Incremental delta merge, OFF the engine lock (docs/writes.md).
+        ``threshold``: merge only if the delta still holds that many rows
+        once ``_merge_lock`` is ours (the read path's; a merge that took
+        the lock first may have absorbed them).
 
         The delta accumulated into sorted stored-domain blocks at write
         time; here they k-way interleave (:func:`merge_sorted_stored`) and
@@ -1067,6 +1081,8 @@ class TpuScanner(Scanner):
                 lock_wait = time.monotonic() - t0
                 if self._force_rebuild or self._mirror is None:
                     self._rebuild_from_store()
+                    return
+                if len(self._delta) < threshold:
                     return
                 mirror = self._mirror
                 blocks, rows_prefix, overflow = self._delta.snapshot_blocks()
@@ -1118,6 +1134,7 @@ class TpuScanner(Scanner):
                 if not full:
                     self._metrics.emit_counter(
                         "kb.mirror.merge.rows.total", n_rows)
+            self._kick_compact_warm(m)
 
     def _build_merged(self, mirror: Mirror, blocks, rows_prefix,
                       overflow: bool) -> tuple[Mirror, bool]:
@@ -1737,6 +1754,85 @@ class TpuScanner(Scanner):
         return p
 
     # -------------------------------------------------------------- compact
+    def _victim_mask(self, mirror: Mirror, s_user: bytes, e_user: bytes,
+                     compact_revision: int, ttl_cutoff: int):
+        """The victim mask [P, N] of ``[s_user, e_user)`` (``e_user`` empty:
+        unbounded) at ``compact_revision`` through the selected kernel — the
+        one place a Compact and its warm-up (:meth:`_compact_warm`) assemble
+        the kernel's arguments, so the warm compiles what the Compact runs.
+        Padded columns are never victims (valid=False)."""
+        s, e, unb = self._query_bounds(mirror, s_user, e_user)
+        chi, clo = keyops.split_revs(np.array([compact_revision], dtype=np.uint64))
+        thi, tlo = keyops.split_revs(np.array([ttl_cutoff], dtype=np.uint64))
+        revs = (jnp.asarray(chi[0]), jnp.asarray(clo[0]),
+                jnp.asarray(thi[0]), jnp.asarray(tlo[0]))
+        if self._scan_kernel == "jnp":
+            return _victim_batch(
+                mirror.keys_dev, mirror.rh_dev, mirror.rl_dev, mirror.tomb_dev,
+                mirror.ttl_dev, mirror.n_valid_dev, s, e, unb, *revs,
+                with_ttl=ttl_cutoff > 0,
+            )
+        kt, rh31, rl31, t8, _n = self._pallas_layout(mirror)
+        ttl8 = self._pallas_ttl8(mirror, kt.shape[2])
+        return _victim_batch_pallas(
+            kt, rh31, rl31, t8, ttl8, mirror.n_valid_dev, s, e, unb, *revs,
+            with_ttl=ttl_cutoff > 0,
+            interpret=(self._scan_kernel == "pallas_interpret"),
+            mesh=self._kernel_mesh,
+        )
+
+    def _kick_compact_warm(self, mirror: Mirror) -> None:
+        """Warm the compaction's device functions for ``mirror``'s shape on
+        a thread of their own (:meth:`_compact_warm`), once per shape: the
+        first build, and a publish that changes the padded width, start
+        it; no request waits on it."""
+        key = mirror.keys_host.shape  # [P, N, C]
+        with self._mlock:
+            if key == self._warm_key:
+                return
+            self._warm_key = key
+        threading.Thread(target=self._compact_warm, args=(mirror,),
+                         name="kb-compact-warm", daemon=True).start()
+
+    def _compact_warm(self, mirror: Mirror) -> None:
+        """Compile (or load from the compile cache) what a Compact runs on
+        ``mirror``'s shape before a Compact needs it: the victim mark, the
+        victim counts and the victim pull's index block
+        (:func:`_victim_pull_size`), over the victims and over the
+        survivors. It runs them at revision 0, which marks nothing, and
+        pulls nothing back. Its seconds are boot's ``compact_warm`` phase
+        (``kb_boot_seconds``), annotated ``kb.compact.warm``."""
+        with self._mlock:
+            if self._mirror is not mirror:
+                return  # superseded: its successor's publish warms its shape
+        t0 = time.monotonic()
+        try:
+            with TRACER.annotate("compact.warm"):
+                # an engine without native TTLs marks expired rows once the
+                # compaction log is old enough: both variants then run
+                ttls = (False,) if self._store.support_ttl() else (False, True)
+                nv = mirror.n_valid_dev
+                for with_ttl in ttls:
+                    mask = self._victim_mask(mirror, b"", b"", 0, int(with_ttl))
+                    jax.block_until_ready(_victim_part_counts(mask, nv))
+                size = _victim_pull_size(int(mask.shape[-1]))
+                for m in (mask, _survivor_mask(mask, nv)):
+                    jax.block_until_ready(
+                        _part_indices_of_mask(m, size=size, mesh=self._mesh))
+        except Exception:
+            import logging
+
+            # a Compact then compiles what it needs itself, as before
+            logging.getLogger("kubebrain").warning(
+                "compaction warm-up failed", exc_info=True)
+            return
+        seconds = time.monotonic() - t0
+        with self._mlock:
+            self.compact_warm_s = seconds
+        if self._metrics is not None:
+            self._metrics.emit_gauge("kb.boot.seconds", seconds,
+                                     phase="compact_warm")
+
     def _pull_victim_indices(self, mask_dev, mirror) -> dict[int, np.ndarray]:
         """Per-partition victim row indices via the adaptive SHARD-LOCAL
         two-phase transfer — the compact analogue of
@@ -1744,10 +1840,10 @@ class TpuScanner(Scanner):
         funnel. Phase one pulls the per-partition (victims, valid) counts
         (8·P bytes); phase two pulls only the SMALLER index set — victim
         indices on an incremental compact (few victims), survivor indices
-        on a bulk one (few survivors) — as a [P, pow2(max per-partition
-        count)] block compacted INSIDE each shard (`_part_indices_of_mask`
-        / `_part_survivor_indices`: no cross-device mask gather on a
-        multi-device mesh), rebuilding the complement host-locally. The
+        on a bulk one (few survivors) — as a [P, N/8] block compacted
+        INSIDE each shard (`_part_indices_of_mask`, over the mask or its
+        `_survivor_mask`: no cross-device mask gather on a multi-device
+        mesh), rebuilding the complement host-locally. The
         [P, N] byte mask crosses the wire only when the index block would
         be WIDER than the mask itself (victims AND survivors both dense —
         then the mask is the cheaper format, and pulling it is not
@@ -1767,9 +1863,9 @@ class TpuScanner(Scanner):
         surv_h = valid_h - vic_h
         use_survivors = int(surv_h.sum()) < total_vic
         want = int(surv_h.max()) if use_survivors else int(vic_h.max())
-        size = _pow2_bucket(want, n_rows)
+        size = _victim_pull_size(n_rows)
         out: dict[int, np.ndarray] = {}
-        if size * 8 > n_rows:
+        if want > size:
             # dense on both sides: index words would out-weigh the byte
             # mask, so the mask IS the minimal wire format here
             mask_h = _host_pull(mask_dev).astype(bool)
@@ -1778,8 +1874,9 @@ class TpuScanner(Scanner):
                 out[p] = np.nonzero(mask_h[p, : int(valid_h[p])])[0]
             return out
         if use_survivors:
-            idx = _host_pull(_part_survivor_indices(
-                mask_dev, mirror.n_valid_dev, size=size, mesh=self._mesh))
+            idx = _host_pull(_part_indices_of_mask(
+                _survivor_mask(mask_dev, mirror.n_valid_dev), size=size,
+                mesh=self._mesh))
             for p in np.nonzero(vic_h)[0]:
                 p = int(p)
                 pmask = np.ones(int(valid_h[p]), dtype=bool)
@@ -1847,139 +1944,23 @@ class TpuScanner(Scanner):
                 self._compact_active = True
             try:
                 t0 = time.monotonic()
-                # internal borders → user-key bounds for the kernels
-                s_user = coder.decode(start)[0] if coder.is_internal_key(start) else b""
-                unbounded = not coder.is_internal_key(end)
-                e_user = b"" if unbounded else coder.decode(end)[0]
-                s, e, unb = self._query_bounds(mirror, s_user, e_user)
-                chi, clo = keyops.split_revs(np.array([compact_revision], dtype=np.uint64))
-                thi, tlo = keyops.split_revs(np.array([ttl_cutoff], dtype=np.uint64))
-                if self._scan_kernel == "jnp":
-                    mask_dev = _victim_batch(
-                        mirror.keys_dev, mirror.rh_dev, mirror.rl_dev, mirror.tomb_dev,
-                        mirror.ttl_dev, mirror.n_valid_dev, s, e, unb,
-                        jnp.asarray(chi[0]), jnp.asarray(clo[0]),
-                        jnp.asarray(thi[0]), jnp.asarray(tlo[0]),
-                        with_ttl=ttl_cutoff > 0,
-                    )
-                else:
-                    kt, rh31, rl31, t8, _n = self._pallas_layout(mirror)
-                    ttl8 = self._pallas_ttl8(mirror, kt.shape[2])
-                    mask_dev = _victim_batch_pallas(
-                        kt, rh31, rl31, t8, ttl8, mirror.n_valid_dev, s, e, unb,
-                        jnp.asarray(chi[0]), jnp.asarray(clo[0]),
-                        jnp.asarray(thi[0]), jnp.asarray(tlo[0]),
-                        with_ttl=ttl_cutoff > 0,
-                        interpret=(self._scan_kernel == "pallas_interpret"),
-                        mesh=self._kernel_mesh,
-                    )  # padded cols are never victims (valid=False)
-                victims_by_part = self._pull_victim_indices(mask_dev, mirror)
+                with TRACER.annotate("compact.mark"):
+                    # internal borders → user-key bounds for the kernels
+                    s_user = (coder.decode(start)[0]
+                              if coder.is_internal_key(start) else b"")
+                    unbounded = not coder.is_internal_key(end)
+                    e_user = b"" if unbounded else coder.decode(end)[0]
+                    mask_dev = self._victim_mask(mirror, s_user, e_user,
+                                                 compact_revision, ttl_cutoff)
+                    victims_by_part = self._pull_victim_indices(mask_dev, mirror)
                 phases["mark"] = time.monotonic() - t0
 
                 t0 = time.monotonic()
                 stats = CompactStats(scanned=mirror.rows, mirror_path="none",
                                      phase_seconds=phases)
-                retry_min = self._retry_min_revision()
-                bulk = getattr(store, "bulk_gc", None)
-                BATCH = 256
-                pending: list[bytes] = []
-                bulk_victims: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
-                bulk_recs: list[tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]] = []
-                keep_idx: dict[int, np.ndarray] = {}
-                for p in sorted(victims_by_part):
-                    victims = victims_by_part[p]
-                    nv = int(mirror.n_valid[p])
-                    pmask = np.zeros(nv, dtype=bool)
-                    pmask[victims] = True
-                    keys_p = mirror.keys_host[p, :nv]
-                    revs_all = mirror.revs_host[p, :nv]
-                    tomb_all = mirror.tomb_host[p, :nv]
-                    # group structure (one group = one user key's version chain),
-                    # computed on the STORED rows — encoded equality == raw
-                    # equality (the encoding is injective), so no decode here
-                    same_prev = np.zeros(nv, dtype=bool)
-                    same_prev[1:] = (keys_p[1:] == keys_p[:-1]).all(axis=1)
-                    group_starts = np.nonzero(~same_prev)[0]
-                    group_ends = np.append(group_starts[1:], nv)
-                    group_sizes = group_ends - group_starts
-                    doomed_per_group = np.add.reduceat(pmask.astype(np.int64), group_starts)
-                    last_idx = group_ends - 1
-                    gid = np.cumsum(~same_prev) - 1  # group id per row
-
-                    # victim stats, fully vectorized (no per-row Python;
-                    # VERDICT r1 weak #3: 1M-victim sweeps must not loop)
-                    v_tomb = tomb_all[victims].astype(bool)
-                    v_is_last = victims == last_idx[gid[victims]]
-                    stats.deleted_tombstones += int(v_tomb.sum())
-                    stats.deleted_versions += int((~v_tomb & ~v_is_last).sum())
-                    stats.expired_ttl += int((~v_tomb & v_is_last).sum())
-
-                    # rev-record GC candidates: fully-doomed groups whose last
-                    # revision is below the uncertain-retry fence (scanner.go:472-491)
-                    dg = np.nonzero(doomed_per_group == group_sizes)[0]
-                    if len(dg):
-                        d_last = last_idx[dg]
-                        d_rev = revs_all[d_last].astype(np.uint64)
-                        if retry_min:
-                            ok = d_rev < np.uint64(retry_min)
-                            dg, d_last, d_rev = dg[ok], d_last[ok], d_rev[ok]
-                    else:
-                        d_last = np.empty(0, dtype=np.int64)
-                        d_rev = np.empty(0, dtype=np.uint64)
-
-                    # victim-ONLY decode: the rows the store deletes below. A
-                    # fully-doomed group's first row (the rev-record GC key) is
-                    # itself a victim, so the decoded set already covers it.
-                    k_u8_v, lens_v = self._compact_victim_rows(mirror, p, victims)
-                    firsts = group_starts[dg]
-                    f_pos = np.searchsorted(victims, firsts)
-
-                    if bulk is not None:
-                        bulk_victims.append((
-                            k_u8_v, lens_v, revs_all[victims].astype(np.uint64),
-                        ))
-                        bulk_recs.append((
-                            k_u8_v[f_pos], lens_v[f_pos], d_rev,
-                            tomb_all[d_last].astype(np.uint8),
-                        ))
-                    else:
-                        # k_u8_v/lens_v hold the decoded victims — slice them
-                        # instead of decoding one row at a time via mirror.user_key
-                        for j, i in enumerate(victims):
-                            uk = k_u8_v[j, : int(lens_v[j])].tobytes()
-                            pending.append(
-                                coder.encode_object_key(uk, int(revs_all[int(i)]))
-                            )
-                        for j in range(len(dg)):
-                            li = int(d_last[j])
-                            raw = coder.encode_rev_value(
-                                int(d_rev[j]), deleted=bool(tomb_all[li])
-                            )
-                            fj = int(f_pos[j])
-                            uk = k_u8_v[fj, : int(lens_v[fj])].tobytes()
-                            try:
-                                store.del_current(coder.encode_revision_key(uk), raw)
-                                stats.deleted_rev_records += 1
-                            except CASFailedError:
-                                pass  # rewritten since the mirror snapshot
-
-                    keep_idx[p] = np.nonzero(~pmask)[0]
-                if bulk is not None and bulk_victims:
-                    # victims and recs are appended together, once per partition
-                    vk, vl, vr = (np.concatenate([b[i] for b in bulk_victims]) for i in range(3))
-                    rk, rl, rr, rt = (np.concatenate([b[i] for b in bulk_recs]) for i in range(4))
-                    stats.deleted_rev_records += bulk(vk, vl, vr, rk, rl, rr, rt)
-                for b0 in range(0, len(pending), BATCH):
-                    batch = store.begin_batch_write()
-                    for k in pending[b0 : b0 + BATCH]:
-                        batch.delete(k)
-                    batch.commit()
-
-                # engine-level history pruning (see generic scanner): free version
-                # chains the logical GC deletes above made unreachable
-                pruner = getattr(store, "prune_versions", None)
-                if pruner is not None:
-                    pruner(store.get_timestamp_oracle())
+                with TRACER.annotate("compact.gc"):
+                    keep_idx = self._compact_gc(mirror, victims_by_part,
+                                                store, stats)
                 phases["gc"] = time.monotonic() - t0
 
                 n_victims = sum(len(v) for v in victims_by_part.values())
@@ -2032,6 +2013,115 @@ class TpuScanner(Scanner):
                     "kb.mirror.merge.seconds", phases.get("merge", 0.0),
                     kind="full_rebuild")
         return stats
+
+    def _compact_gc(self, mirror: Mirror, victims_by_part: dict, store,
+                    stats: CompactStats) -> dict[int, np.ndarray]:
+        """The compaction's gc phase: the victims' rows, and the revision
+        records of keys left with none, deleted from the store (victim-only
+        decode), the victims counted by kind into ``stats``. Returns each
+        dirty partition's surviving row indices for the mirror half."""
+        retry_min = self._retry_min_revision()
+        bulk = getattr(store, "bulk_gc", None)
+        BATCH = 256
+        pending: list[bytes] = []
+        bulk_victims: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
+        bulk_recs: list[tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]] = []
+        keep_idx: dict[int, np.ndarray] = {}
+        for p in sorted(victims_by_part):
+            victims = victims_by_part[p]
+            nv = int(mirror.n_valid[p])
+            pmask = np.zeros(nv, dtype=bool)
+            pmask[victims] = True
+            keys_p = mirror.keys_host[p, :nv]
+            revs_all = mirror.revs_host[p, :nv]
+            tomb_all = mirror.tomb_host[p, :nv]
+            # group structure (one group = one user key's version chain),
+            # computed on the STORED rows — encoded equality == raw
+            # equality (the encoding is injective), so no decode here
+            same_prev = np.zeros(nv, dtype=bool)
+            same_prev[1:] = (keys_p[1:] == keys_p[:-1]).all(axis=1)
+            group_starts = np.nonzero(~same_prev)[0]
+            group_ends = np.append(group_starts[1:], nv)
+            group_sizes = group_ends - group_starts
+            doomed_per_group = np.add.reduceat(pmask.astype(np.int64), group_starts)
+            last_idx = group_ends - 1
+            gid = np.cumsum(~same_prev) - 1  # group id per row
+
+            # victim stats, fully vectorized (no per-row Python;
+            # VERDICT r1 weak #3: 1M-victim sweeps must not loop)
+            v_tomb = tomb_all[victims].astype(bool)
+            v_is_last = victims == last_idx[gid[victims]]
+            stats.deleted_tombstones += int(v_tomb.sum())
+            stats.deleted_versions += int((~v_tomb & ~v_is_last).sum())
+            stats.expired_ttl += int((~v_tomb & v_is_last).sum())
+
+            # rev-record GC candidates: fully-doomed groups whose last
+            # revision is below the uncertain-retry fence (scanner.go:472-491)
+            dg = np.nonzero(doomed_per_group == group_sizes)[0]
+            if len(dg):
+                d_last = last_idx[dg]
+                d_rev = revs_all[d_last].astype(np.uint64)
+                if retry_min:
+                    ok = d_rev < np.uint64(retry_min)
+                    dg, d_last, d_rev = dg[ok], d_last[ok], d_rev[ok]
+            else:
+                d_last = np.empty(0, dtype=np.int64)
+                d_rev = np.empty(0, dtype=np.uint64)
+
+            # victim-ONLY decode: the rows the store deletes below. A
+            # fully-doomed group's first row (the rev-record GC key) is
+            # itself a victim, so the decoded set already covers it.
+            k_u8_v, lens_v = self._compact_victim_rows(mirror, p, victims)
+            firsts = group_starts[dg]
+            f_pos = np.searchsorted(victims, firsts)
+
+            if bulk is not None:
+                bulk_victims.append((
+                    k_u8_v, lens_v, revs_all[victims].astype(np.uint64),
+                ))
+                bulk_recs.append((
+                    k_u8_v[f_pos], lens_v[f_pos], d_rev,
+                    tomb_all[d_last].astype(np.uint8),
+                ))
+            else:
+                # k_u8_v/lens_v hold the decoded victims — slice them
+                # instead of decoding one row at a time via mirror.user_key
+                for j, i in enumerate(victims):
+                    uk = k_u8_v[j, : int(lens_v[j])].tobytes()
+                    pending.append(
+                        coder.encode_object_key(uk, int(revs_all[int(i)]))
+                    )
+                for j in range(len(dg)):
+                    li = int(d_last[j])
+                    raw = coder.encode_rev_value(
+                        int(d_rev[j]), deleted=bool(tomb_all[li])
+                    )
+                    fj = int(f_pos[j])
+                    uk = k_u8_v[fj, : int(lens_v[fj])].tobytes()
+                    try:
+                        store.del_current(coder.encode_revision_key(uk), raw)
+                        stats.deleted_rev_records += 1
+                    except CASFailedError:
+                        pass  # rewritten since the mirror snapshot
+
+            keep_idx[p] = np.nonzero(~pmask)[0]
+        if bulk is not None and bulk_victims:
+            # victims and recs are appended together, once per partition
+            vk, vl, vr = (np.concatenate([b[i] for b in bulk_victims]) for i in range(3))
+            rk, rl, rr, rt = (np.concatenate([b[i] for b in bulk_recs]) for i in range(4))
+            stats.deleted_rev_records += bulk(vk, vl, vr, rk, rl, rr, rt)
+        for b0 in range(0, len(pending), BATCH):
+            batch = store.begin_batch_write()
+            for k in pending[b0 : b0 + BATCH]:
+                batch.delete(k)
+            batch.commit()
+
+        # engine-level history pruning (see generic scanner): free version
+        # chains the logical GC deletes above made unreachable
+        pruner = getattr(store, "prune_versions", None)
+        if pruner is not None:
+            pruner(store.get_timestamp_oracle())
+        return keep_idx
 
     def _compact_retry_escalate(self, mirror, keep_idx, stats, phases) -> None:
         """Attempts 2..K of the compaction's mirror half with the
@@ -2117,30 +2207,31 @@ class TpuScanner(Scanner):
             # machinery must recover
             raise RuntimeError("injected compact failure (fault plane)")
         t0 = time.monotonic()
-        with self._mlock:
-            if self._force_rebuild or self._mirror is not mirror:
-                return True
-            blocks_, rows_prefix, overflow = self._delta.snapshot_blocks()
-        n_rows = len(rows_prefix)
-        ts = self._store.get_timestamp_oracle()
-        # an overflowed delta already commits us to the full rebuild —
-        # don't pay the stored-domain gather just to discard it
-        go_full = n_rows and overflow
-        m = (None if go_full
-             else compact_partitions_stored(mirror, keep_idx, self._mesh, ts))
-        if m is not None and n_rows:
-            delta7 = merge_sorted_stored(blocks_)
-            m = merge_partitions_stored(m, delta7, self._mesh, ts)
-        full = m is None
-        if full:
-            # fallback ladder's last rung: pre-ttl_host mirror,
-            # stored-width drift, or a delta key the dictionary
-            # can't express — the decode-everything full rebuild
-            m = self._compact_full_rebuild(mirror, keep_idx, rows_prefix, ts)
+        with TRACER.annotate("compact.merge"):
+            with self._mlock:
+                if self._force_rebuild or self._mirror is not mirror:
+                    return True
+                blocks_, rows_prefix, overflow = self._delta.snapshot_blocks()
+            n_rows = len(rows_prefix)
+            ts = self._store.get_timestamp_oracle()
+            # an overflowed delta already commits us to the full rebuild —
+            # don't pay the stored-domain gather just to discard it
+            go_full = n_rows and overflow
+            m = (None if go_full
+                 else compact_partitions_stored(mirror, keep_idx, self._mesh, ts))
+            if m is not None and n_rows:
+                delta7 = merge_sorted_stored(blocks_)
+                m = merge_partitions_stored(m, delta7, self._mesh, ts)
+            full = m is None
+            if full:
+                # fallback ladder's last rung: pre-ttl_host mirror,
+                # stored-width drift, or a delta key the dictionary
+                # can't express — the decode-everything full rebuild
+                m = self._compact_full_rebuild(mirror, keep_idx, rows_prefix, ts)
         phases["merge"] = time.monotonic() - t0
         t1 = time.monotonic()
         superseded = False
-        with self._mlock:
+        with TRACER.annotate("compact.publish"), self._mlock:
             if self._force_rebuild or self._mirror is not mirror:
                 superseded = True
             elif m is mirror and n_rows == 0:
@@ -2162,6 +2253,8 @@ class TpuScanner(Scanner):
                 stats.mirror_path = (
                     "full_rebuild" if full else "stored_incremental")
         phases["publish"] = time.monotonic() - t1
+        if not superseded:
+            self._kick_compact_warm(m)
         return superseded
 
     def _compact_full_rebuild(self, mirror, keep_idx, rows_prefix, ts):

@@ -15,6 +15,7 @@ Runs on the 8-device virtual CPU mesh (conftest.py).
 
 import threading
 import time
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -531,6 +532,9 @@ class _CaptureMetrics:
     def register_gauge_fn(self, *a, **k):
         pass
 
+    def emit_gauge(self, name, value, **tags):
+        self.counters.append((name, value, tags))
+
 
 def test_compact_phase_metrics_and_stats(tb):
     """kb_compact_seconds{phase=mark|gc|merge|publish} and
@@ -565,3 +569,135 @@ def _borders(b):
     """The backend's whole-keyspace compact borders (internal keys)."""
     lo, hi = coder.internal_range(b"", b"")
     return lo, hi
+
+
+def _warmed(sc, timeout=120.0):
+    """Wait for the compaction warm-up the first publish kicked."""
+    deadline = time.monotonic() + timeout
+    while sc.compact_warm_s is None:
+        assert time.monotonic() < deadline, "the compaction warm-up never ran"
+        time.sleep(0.05)
+    return sc.compact_warm_s
+
+
+def _compaction_cache_sizes():
+    from kubebrain_tpu.storage.tpu import engine
+
+    return {f: getattr(engine, f)._cache_size() for f in (
+        "_victim_batch", "_victim_batch_pallas", "_victim_part_counts",
+        "_part_indices_of_mask", "_survivor_mask")}
+
+
+@pytest.mark.parametrize("kernel", ["jnp", "pallas_interpret"])
+@pytest.mark.parametrize("shape", ["few_victims", "few_survivors", "dense"])
+def test_compact_after_the_warm_compiles_nothing(kernel, shape, monkeypatch):
+    """The first publish warms every function a Compact runs at the
+    mirror's shape (the mark, the victim counts, the pull's index block
+    over the victims and over the survivors) on a thread of its own: a
+    Compact after it finds every one compiled, whichever pull it takes —
+    the victims' indices, the survivors' indices or the byte mask."""
+    from kubebrain_tpu.storage.tpu import engine
+
+    from kubebrain_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setenv("KB_PALLAS_INTERPRET", "1")
+    # one partition, as on one chip: the per-partition counts decide the pull
+    store = new_storage("tpu", inner="memkv", use_pallas=kernel != "jnp",
+                        mesh=make_mesh(1))
+    b = Backend(store, BackendConfig(event_ring_capacity=8192,
+                                     watch_cache_capacity=4096))
+    try:
+        sc = b.scanner
+        sc._host_limit_threshold = 0
+        assert sc._scan_kernel == kernel
+        if shape == "few_survivors":
+            # 6 keys x 30 revisions: 174 victims, 6 survivors
+            for i in range(6):
+                r = b.create(b"/registry/pods/c%d" % i, b"v0")
+                for j in range(29):
+                    r = b.update(b"/registry/pods/c%d" % i, b"v%d" % j, r)
+            last = r
+            assert wait_for_revision(b, last)
+        else:
+            # dense: over N/8 victims AND survivors in the partition (the
+            # Pallas layout pads the mask wider than the jnp one)
+            n_keys = 90 if shape == "few_victims" else (
+                150 if kernel == "jnp" else 1700)
+            live, last = _churn(b, n_keys=n_keys)
+        if shape == "few_victims":
+            k = next(iter(live))
+            last = b.update(k, b"again", live[k])
+            assert wait_for_revision(b, last)
+        sc.publish()
+        assert _warmed(sc) > 0
+        before = _compaction_cache_sizes()
+        pulls = []
+        orig = engine._host_pull
+        with mock.patch.object(engine, "_host_pull",
+                               lambda x: pulls.append(x.shape) or orig(x)):
+            if shape == "few_victims":
+                b.compact(last - 1)  # the chain below the one update: 1 victim
+            b.compact(last)
+        assert _compaction_cache_sizes() == before
+        assert sc.compact_errors == 0
+        # the pull each shape takes: [P, N/8] indices, or the [P, N] mask
+        n = (sc._mirror.keys_host.shape[1] if kernel == "jnp"
+             else sc._pallas_layout(sc._mirror)[0].shape[2])
+        blocks = [s[-1] for s in pulls if len(s) == 2]
+        assert blocks and set(blocks) == ({n} if shape == "dense" else {n // 8})
+    finally:
+        b.close()
+        store.close()
+
+
+def test_compact_warm_is_boots_compact_warm_phase(tb):
+    """Its seconds are ``kb_boot_seconds{phase="compact_warm"}``, and a
+    publish that keeps the mirror's shape starts no second warm-up."""
+    m = _CaptureMetrics()
+    sc = tb.scanner
+    sc._metrics = m
+    live, last = _churn(tb, n_keys=30)
+    sc.publish()
+    _warmed(sc)
+    gauges = [(v, t) for n, v, t in m.counters if n == "kb.boot.seconds"]
+    assert [t["phase"] for _v, t in gauges].count("compact_warm") == 1
+    key = sc._warm_key
+    k = next(iter(live))
+    assert wait_for_revision(tb, tb.update(k, b"more", live[k]))
+    sc.publish()
+    time.sleep(0.2)
+    assert sc._warm_key == key
+    gauges = [t for n, _v, t in m.counters if n == "kb.boot.seconds"]
+    assert [t["phase"] for t in gauges].count("compact_warm") == 1
+    sc._metrics = None
+
+
+def test_compact_phases_are_profiler_annotations(tb):
+    """The pass's phases stand on the profiler's clock as the merge's do:
+    ``kb.compact.mark|gc|merge|publish``, in that order."""
+    from kubebrain_tpu.trace import TRACER
+
+    live, last = _churn(tb, n_keys=30)
+    sc = tb.scanner
+    sc.publish()
+    _warmed(sc)
+    names = []
+
+    class _Rec:
+        def __init__(self, name):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    TRACER.set_annotator(_Rec)
+    try:
+        tb.compact(last)
+    finally:
+        TRACER.set_annotator(None)
+    compact = [n for n in names if n.startswith("kb.compact.")]
+    assert compact == ["kb.compact.mark", "kb.compact.gc",
+                       "kb.compact.merge", "kb.compact.publish"]

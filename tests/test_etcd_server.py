@@ -513,3 +513,155 @@ def test_lease_attached_put_expires():
         endpoint.close()
         backend.close()
         store.close()
+
+
+# ------------------------------------------- kube-apiserver's compactor
+# k8s.io/apiserver storage/etcd3/compact.go, one tick: Txn(If(Version(
+# compact_rev_key) == t) Then(Put(compact_rev_key, rev)) Else(Get)); on
+# success it keeps t + 1 without reading the key back and compacts to rev
+# (not on the first tick, where rev is 0); on failure it takes t from the
+# Get. The next tick's rev is this Txn's header revision.
+
+STORAGES = {
+    "memkv": ["--storage", "memkv"],
+    "native": ["--storage", "native"],
+    "tpu": ["--storage", "tpu", "--inner-storage", "memkv"],
+}
+
+
+class _Served:
+    """A fresh single-node endpoint of one storage engine, with a client."""
+
+    def __init__(self, flags):
+        port = free_port()
+        args = build_parser().parse_args([
+            "--single-node", *flags, "--host", "127.0.0.1",
+            "--client-port", str(port), "--peer-port", str(free_port()),
+            "--info-port", str(free_port()),
+        ])
+        self.endpoint, self.backend, self.store = build_endpoint(args)
+        self.endpoint.run()
+        self.client = EtcdClient(f"127.0.0.1:{port}")
+
+    def close(self):
+        self.client.close()
+        self.endpoint.close()
+        self.backend.close()
+        self.store.close()
+
+
+@pytest.fixture
+def served(request):
+    s = _Served(STORAGES[request.param])
+    yield s
+    s.close()
+
+
+class Compactor:
+    """One kube-apiserver's compactor state (t, rev) and its tick."""
+
+    def __init__(self, client):
+        self.client, self.t, self.rev = client, 0, 0
+
+    def tick(self) -> bool:
+        resp = self.client.compact_coordination(self.t, b"%d" % self.rev)
+        target, self.rev = self.rev, resp.header.revision
+        if not resp.succeeded:
+            self.t = resp.responses[0].response_range.kvs[0].version
+            return False
+        self.t += 1
+        if target:
+            self.client.compact(rpc_pb2.CompactionRequest(revision=target))
+        return True
+
+
+def _version(client) -> int:
+    r = client.range_(rpc_pb2.RangeRequest(key=b"compact_rev_key"))
+    return r.kvs[0].version
+
+
+@pytest.mark.parametrize("served", list(STORAGES), indirect=True)
+def test_compact_go_sequence_acknowledged_on_every_tick(served):
+    """t = 0 creates the key, then t + 1 twice: every tick is acknowledged
+    (it used to be refused every second tick), and the key reads version 3."""
+    c = Compactor(served.client)
+    for i in range(3):
+        served.client.create(b"/registry/pods/ns/p%d" % i, b"v")
+        assert c.tick(), f"tick {i + 1} refused"
+    assert c.t == 3 and _version(served.client) == 3
+
+
+@pytest.mark.parametrize("served", ["memkv", "native"], indirect=True)
+def test_three_compactors_one_success_a_round(served):
+    """kube-apiserver's three replicas, each keeping t as compact.go does,
+    interleaved in a rotating order: exactly one tick a round is
+    acknowledged — the first of the round — as etcd answers them."""
+    cs = [Compactor(served.client) for _ in range(3)]
+    got = []
+    for rnd in range(6):
+        served.client.create(b"/registry/pods/ns/r%d" % rnd, b"v")
+        order = [(rnd + k) % 3 for k in range(3)]
+        got.append([cs[i].tick() for i in order])
+    assert got == [[True, False, False]] * 6
+    assert _version(served.client) == 6
+    assert [c.t for c in cs] == [6, 6, 6]
+
+
+@pytest.mark.parametrize("served", list(STORAGES), indirect=True)
+def test_version_survives_the_keys_own_compaction(served):
+    """Each tick compacts to the previous tick's revision, so the key's own
+    older revisions go; its Version does not, and the next tick with
+    t + 1 still wins. A read at an older revision gives the Version then."""
+    c = Compactor(served.client)
+    revs = []
+    for i in range(5):
+        served.client.create(b"/registry/pods/ns/s%d" % i, b"v")
+        assert c.tick()
+        revs.append(c.rev)
+    assert served.backend.compact_revision() == revs[-2]
+    assert _version(served.client) == 5
+    old = served.client.range_(rpc_pb2.RangeRequest(
+        key=b"compact_rev_key", revision=revs[-1] - 1))
+    assert old.kvs[0].version == 4 and old.kvs[0].value == b"%d" % revs[-3]
+    assert c.tick() and _version(served.client) == 6
+
+
+def test_version_is_as_durable_as_the_key(tmp_path):
+    """The count rides the put's own engine batch: a restart of a native
+    store on its data directory reads it back, after the compaction of the
+    key's older revisions."""
+    flags = ["--storage", "native", "--data-dir", str(tmp_path)]
+    s = _Served(flags)
+    try:
+        c = Compactor(s.client)
+        for i in range(4):
+            s.client.create(b"/registry/pods/ns/d%d" % i, b"v")
+            assert c.tick()
+    finally:
+        s.close()
+    s = _Served(flags)
+    try:
+        assert _version(s.client) == 4
+        again = Compactor(s.client)  # a restarted apiserver: t = 0
+        assert not again.tick() and again.t == 4
+        assert again.tick() and _version(s.client) == 5
+    finally:
+        s.close()
+
+
+def test_other_keys_keep_mod_revision_guards(server):
+    """A Version guard on any other key stays a mod-revision guard, and a
+    row of any other key reads version 1."""
+    client, _, _ = server
+    key = b"/registry/configmaps/ns/versioned"
+    created = client.create(key, b"a")
+    rev = created.header.revision
+    req = rpc_pb2.TxnRequest()
+    cmp = req.compare.add(result=rpc_pb2.Compare.EQUAL,
+                          target=rpc_pb2.Compare.VERSION, key=key, version=rev)
+    assert cmp.version == rev
+    req.success.add().request_put.CopyFrom(rpc_pb2.PutRequest(key=key, value=b"b"))
+    req.failure.add().request_range.CopyFrom(rpc_pb2.RangeRequest(key=key))
+    assert client.txn(req).succeeded
+    got = client.range_(rpc_pb2.RangeRequest(key=key))
+    assert got.kvs[0].value == b"b" and got.kvs[0].version == 1

@@ -376,13 +376,22 @@ def test_lock_wait_counts_what_a_writer_waited_for_mlock(tpu_server):
 
 def test_boot_gauges_and_device_memory_on_metrics(tpu_server):
     _client, info_port, backend, endpoint = tpu_server
+    # the first mirror build starts the compaction's warm-up on a thread
+    # of its own: its phase joins once that is over
+    deadline = time.monotonic() + 120
+    while backend.scanner.compact_warm_s is None:
+        assert time.monotonic() < deadline, "no compaction warm-up"
+        time.sleep(0.05)
     snap = _metrics(info_port)
     boot = {lb["phase"]: v for lb, v in snap["kb_boot_seconds"]}
     # listen is main()'s to close (the in-process fixture runs the endpoint
     # itself); the first read built the mirror
-    assert set(boot) == {"jax_init", "store_open", "mirror_build"}
+    assert set(boot) == {"jax_init", "store_open", "mirror_build",
+                         "compact_warm"}
     assert boot["mirror_build"] == pytest.approx(
         backend.scanner.boot_mirror_build_s)
+    assert boot["compact_warm"] == pytest.approx(
+        backend.scanner.compact_warm_s)
     assert boot["store_open"] == pytest.approx(
         endpoint.boot.seconds["store_open"])
     line = json.loads(boot_line(backend, endpoint.boot.seconds).split(": ", 1)[1])

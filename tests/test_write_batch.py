@@ -599,6 +599,80 @@ def test_incremental_merge_runs_off_engine_lock(monkeypatch):
         b.close()
 
 
+def test_reader_over_threshold_serves_overlay_beside_merge_in_flight(
+        monkeypatch):
+    """A reader that finds the delta over the threshold while a merge holds
+    ``_merge_lock`` serves mirror + overlay (exact) at once: it neither
+    parks on the lock nor merges the tail behind it, so one merge absorbs
+    the delta."""
+    from kubebrain_tpu.storage.tpu import engine as engine_mod
+
+    b = mk_tpu_backend(8, merge_threshold=10**9)
+    try:
+        for i in range(200):
+            b.create(b"/registry/off/k%04d" % i, b"v")
+        b.scanner.publish()
+        for i in range(500):
+            b.create(b"/registry/off/m%04d" % i, b"v")
+
+        sc = b.scanner
+        sc._merge_threshold = 100  # the delta's 500 rows are over it
+        entered = threading.Event()
+        release = threading.Event()
+        real = engine_mod.merge_partitions_stored
+
+        def slow_merge(*args, **kwargs):
+            entered.set()
+            release.wait(10)
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(engine_mod, "merge_partitions_stored", slow_merge)
+        merges_before = sc.merge_count
+        merger = threading.Thread(target=sc._merge_delta)
+        merger.start()
+        assert entered.wait(10), "merge never started"
+        got: list = []
+        reader = threading.Thread(target=lambda: got.append(
+            b.count(b"/registry/off/", b"/registry/off0")))
+        reader.start()
+        reader.join(8)
+        finished = not reader.is_alive()
+        release.set()
+        merger.join(30)
+        reader.join(10)
+        assert finished, "reader parked behind the merge in flight"
+        assert got and got[0][0] == 700
+        assert b.count(b"/registry/off/", b"/registry/off0")[0] == 700
+        assert sc.merge_count == merges_before + 1, "a reader merged too"
+        assert len(sc._delta) == 0
+    finally:
+        b.close()
+
+
+def test_read_path_merge_skips_a_tail_under_the_threshold():
+    """The read path's merge re-reads the delta once ``_merge_lock`` is
+    its own: a tail under the threshold (left by a merge that took the
+    lock first) is served from the overlay, not merged; a merge with no
+    threshold (publish, the write kick) still takes it."""
+    b = mk_tpu_backend(8, merge_threshold=10**9)
+    try:
+        for i in range(200):
+            b.create(b"/registry/off/k%04d" % i, b"v")
+        b.scanner.publish()
+        for i in range(10):
+            b.create(b"/registry/off/t%04d" % i, b"v")
+        sc = b.scanner
+        before = sc.merge_count
+        sc._merge_delta(threshold=100)
+        assert sc.merge_count == before and len(sc._delta) == 10
+        assert b.count(b"/registry/off/", b"/registry/off0")[0] == 210
+        sc._merge_delta()
+        assert sc.merge_count == before + 1 and len(sc._delta) == 0
+        assert b.count(b"/registry/off/", b"/registry/off0")[0] == 210
+    finally:
+        b.close()
+
+
 def test_merge_metrics_emitted():
     """kb_mirror_merge_seconds{kind=incremental} + merge_rows_total move
     on an incremental merge; kb_sched_write_batch_size moves on group

@@ -507,8 +507,8 @@ class ReplayableWorkloadRandomness(Rule):
 _DEVICE_PRODUCER_NAMES = {
     "_vis_batch", "_vis_batch_q", "_vis_batch_pallas", "_vis_batch_pallas_q",
     "_part_indices_of_mask", "_part_indices_of_mask_sel",
-    "_part_survivor_indices", "_victim_part_counts", "_victim_batch",
-    "_victim_batch_pallas", "_dev_mask", "_dev_mask_batch",
+    "_part_survivor_indices", "_survivor_mask", "_victim_part_counts",
+    "_victim_batch", "_victim_batch_pallas", "_dev_mask", "_dev_mask_batch",
 }
 #: numpy host-conversion entry points (device arrays convert implicitly)
 _HOST_CONVERTERS = {
