@@ -23,6 +23,7 @@ storage sharding through GetPartitions).
 from __future__ import annotations
 
 import bisect
+import contextlib
 import functools
 import threading
 import time
@@ -252,12 +253,11 @@ def _vis_batch_pallas_q(keys_t, rh31, rl31, tomb8, nv, starts, ends, unbs,
 @functools.partial(jax.jit, static_argnames=("size", "mesh"))
 def _part_indices_of_mask(mask, size, mesh=None):
     """Per-partition compacted row indices [P, size] (fill = N) of a
-    visibility mask [P, N] — the SHARD-LOCAL index extraction of the
-    serving scan path. Each device compacts only its own partitions'
-    rows (shard_map along ``part``), so a multi-device mesh never
-    all-gathers the [P, N] mask, and the host pull that follows is
-    O(visible rows per shard), not O(dataset). ``size`` = pow2 of the max
-    per-partition count (the caller knows it from the counts transfer)."""
+    mask [P, N] — the SHARD-LOCAL index extraction of the Compact's
+    victim pull. Each device compacts only its own partitions' rows
+    (shard_map along ``part``), so a multi-device mesh never all-gathers
+    the [P, N] mask, and the host pull that follows is O(rows picked per
+    shard), not O(dataset)."""
     def local(m):
         per_row = lambda row: jnp.nonzero(
             row, size=size, fill_value=row.shape[0])[0]
@@ -269,12 +269,13 @@ def _part_indices_of_mask(mask, size, mesh=None):
 
 @functools.partial(jax.jit, static_argnames=("size", "mesh"))
 def _part_indices_of_mask_sel(mask, sel, size, mesh=None):
-    """Per-(query, partition) compacted row indices [Q, P, size] of a
-    batched mask [Q, P, N], restricted to the SELECTED queries — the
-    shard-local analogue of `_part_indices_of_mask` for the query-batched
-    path. Count queries (and pow2 padding copies) are deselected so their
-    rows never cross the wire; the ``part`` axis (axis 1) stays sharded
-    end to end."""
+    """Per-(query, partition) compacted row indices [Q, P, size] (fill =
+    N) of a mask [Q, P, N], restricted to the SELECTED queries — the
+    read path's shard-local index extraction (`_vis_rows`; a single read
+    is a batch of one). Count queries (and pow2 padding copies) are
+    deselected so their rows never cross the wire; the ``part`` axis
+    (axis 1) stays sharded end to end, and the host pull is O(visible
+    rows per shard), never the mask."""
     from jax.sharding import PartitionSpec as PS
 
     def local(m, s):
@@ -289,6 +290,52 @@ def _part_indices_of_mask_sel(mask, sel, size, mesh=None):
         out_specs=PS(None, "part", None),
     )
     return f(mask, sel)
+
+
+@functools.partial(jax.jit, static_argnames=("kernel", "n", "size", "mesh"))
+def _vis_rows(cols, query, kernel, n, size, mesh=None):
+    """One read's whole device half in ONE program: the packed query in,
+    ``[P, 1 + size]`` int32 out — column 0 partition p's visible count,
+    then its first ``size`` visible row indices (fill = N), ascending. A
+    query-batched ``query`` [Qpad, 2C + 3] gives ``[Qpad, P, 1 + size]``
+    with the rows of queries that want none (Counts, pow2 padding) left
+    all fill. ``size`` 0 returns the counts alone. The [P, N] mask never
+    leaves the program.
+
+    ``cols`` are the five mirror columns the ``kernel`` reads (the
+    ``prepare_mirror`` layout for Pallas, whose row count is ``n``); the
+    query row is ``TpuScanner._pack_query``'s: start chunks, end chunks,
+    flags (1 = unbounded end, 2 = rows wanted), read revision hi, lo."""
+    c = (query.shape[-1] - 3) // 2
+    start, end = query[..., :c], query[..., c:2 * c]
+    flags, qhi, qlo = (query[..., 2 * c + i] for i in range(3))
+    unb, wanted = (flags & 1) != 0, (flags & 2) != 0
+    interpret = kernel == "pallas_interpret"
+    if query.ndim == 1:
+        if kernel == "jnp":
+            mask, counts = _vis_batch(*cols, start, end, unb, qhi, qlo)
+        else:
+            mask, counts = _vis_batch_pallas(
+                *cols, start, end, unb, qhi, qlo, n=n, interpret=interpret,
+                mesh=mesh)
+        mask, counts, wanted = mask[None], counts[None], wanted[None]
+    elif kernel == "jnp":
+        mask, counts = _vis_batch_q(*cols, start, end, unb, qhi, qlo)
+    else:
+        mask, counts = _vis_batch_pallas_q(
+            *cols, start, end, unb, qhi, qlo, n=n, interpret=interpret,
+            mesh=mesh)
+    block = counts[..., None]
+    if size:
+        block = jnp.concatenate([block, _part_indices_of_mask_sel(
+            mask, wanted, size=size, mesh=mesh)], axis=-1)
+    return block[0] if query.ndim == 1 else block
+
+
+#: (start, end) ranges whose last visible count a scanner remembers to size
+#: the next read's index block (`TpuScanner._pull_visible`): the oldest is
+#: forgotten first
+_BUCKET_MEMO = 4096
 
 
 def _pow2_bucket(want: int, n_flat: int) -> int:
@@ -485,6 +532,11 @@ class TpuScanner(Scanner):
         self._pallas_cache: tuple[Mirror, tuple] | None = None
         self._pallas_ttl_cache: tuple[Mirror, object] | None = None
         self._probe_cache: tuple[Mirror, list] | None = None
+        # the most rows a partition showed at the last read of a (start,
+        # end) range: the next read's index block is sized from it, so a
+        # read is one device call and one pull (`_pull_visible`)
+        self._buckets: dict[tuple[bytes, bytes], int] = {}
+        self._bucket_lock = threading.Lock()
         self._mlock = threading.RLock()
         # mergers serialize on their own lock and do the heavy interleave
         # OFF _mlock — readers keep serving mirror+overlay while a merge
@@ -1245,85 +1297,113 @@ class TpuScanner(Scanner):
             self._pallas_ttl_cache = (mirror, ttl8)
         return ttl8
 
-    def _dev_mask(self, mirror: Mirror, start: bytes, end: bytes, read_rev: int):
-        """Visibility (mask [P, N] device array, counts [P]) through the
-        selected kernel — the one assembly point so count/range/stream can't
+    def _pack_query(self, mirror: Mirror, start: bytes, end: bytes,
+                    read_rev: int, rows: bool = True) -> np.ndarray:
+        """One query as the one host array `_vis_rows` unpacks, uint32[2C +
+        3]: the bounds of `_bound_rows` (the packing point the single and
+        query-batched paths share), the flags (1 = unbounded end, 2 = rows
+        wanted) and the read revision's high and low words."""
+        s_row, e_row, unbounded = self._bound_rows(mirror, start, end)
+        c = len(s_row)
+        query = np.empty(2 * c + 3, np.uint32)
+        query[:c], query[c:2 * c] = s_row, e_row
+        read_rev = int(read_rev)
+        query[2 * c:] = (int(unbounded) | 2 * rows, read_rev >> 32,
+                         read_rev & 0xFFFFFFFF)
+        return query
+
+    def _scan_cols(self, mirror: Mirror):
+        """``(the five mirror columns the selected kernel reads, the Pallas
+        layout's row count)``."""
+        if self._scan_kernel == "jnp":
+            return (mirror.keys_dev, mirror.rh_dev, mirror.rl_dev,
+                    mirror.tomb_dev, mirror.n_valid_dev), 0
+        kt, rh31, rl31, t8, n = self._pallas_layout(mirror)
+        return (kt, rh31, rl31, t8, mirror.n_valid_dev), n
+
+    def _dev_mask(self, mirror: Mirror, start: bytes, end: bytes,
+                  read_rev: int, size: int):
+        """One query's device block ``[P, 1 + size]`` (`_vis_rows`: the
+        visible counts, then ``size`` row indices a partition) through the
+        selected kernel, packed and launched in ONE call — with
+        :meth:`_dev_mask_batch` the only assembly points allowed to launch
+        the scan kernels (kblint KB109), so count/range/stream can't
         diverge and can't silently miss the kernel dispatch."""
-        s, e, unb = self._query_bounds(mirror, start, end)
-        qhi, qlo = keyops.split_revs(np.array([read_rev], dtype=np.uint64))
-        qhi, qlo = jnp.asarray(qhi[0]), jnp.asarray(qlo[0])
-        if self._scan_kernel == "jnp":
-            return _vis_batch(
-                mirror.keys_dev, mirror.rh_dev, mirror.rl_dev, mirror.tomb_dev,
-                mirror.n_valid_dev, s, e, unb, qhi, qlo,
-            )
-        kt, rh31, rl31, t8, n = self._pallas_layout(mirror)
-        return _vis_batch_pallas(
-            kt, rh31, rl31, t8, mirror.n_valid_dev, s, e, unb, qhi, qlo,
-            n=n, interpret=(self._scan_kernel == "pallas_interpret"),
-            mesh=self._kernel_mesh,
-        )
+        cols, n = self._scan_cols(mirror)
+        return _vis_rows(cols, self._pack_query(mirror, start, end, read_rev),
+                         kernel=self._scan_kernel, n=n, size=size,
+                         mesh=self._mesh)
 
-    def _dev_mask_batch(self, mirror: Mirror, specs):
-        """Batched visibility for Q distinct ``(start, end, read_rev)``
-        queries in ONE device dispatch — with :meth:`_dev_mask` the only
-        assembly points allowed to launch the scan kernels (kblint KB109),
-        so the batched path can't silently diverge from the single one.
+    def _dev_mask_batch(self, mirror: Mirror, specs, size: int):
+        """Q distinct ``(start, end, read_rev, rows wanted)`` queries in ONE
+        call: the block ``[Qpad, P, 1 + size]``, the rows of queries that
+        want none left all fill.
 
-        Q is a program *shape* (the bounds arrays are [Q, C]), so every
-        distinct Q would jit-compile a fresh kernel; Q is therefore padded
-        to the next power of two with copies of query 0 and the returned
-        ``(mask [Qpad, P, N], counts [Qpad, P])`` cover the padded axis —
-        callers slice (or deselect) ``[:len(specs)]``."""
-        q = len(specs)
+        Q is a program *shape* (the query array is [Q, 2C + 3]), so every
+        distinct Q would jit-compile a fresh program; Q is therefore padded
+        to the next power of two with copies of query 0 that want no rows,
+        and the block covers the padded axis — callers read
+        ``[:len(specs)]``."""
         qpad = 1
-        while qpad < q:
+        while qpad < len(specs):
             qpad *= 2
-        padded = list(specs) + [specs[0]] * (qpad - q)
-        # per-query bounds through the SAME packing point as the single
-        # path (`_bound_rows`): raw or dictionary-encoded per the mirror
-        rows = [self._bound_rows(mirror, s, e) for s, e, _r in padded]
-        starts = np.stack([r[0] for r in rows])
-        ends = np.stack([r[1] for r in rows])
-        unbs = np.array([r[2] for r in rows])
-        qhi, qlo = keyops.split_revs(
-            np.array([r for _s, _e, r in padded], dtype=np.uint64))
-        if self._scan_kernel == "jnp":
-            return _vis_batch_q(
-                mirror.keys_dev, mirror.rh_dev, mirror.rl_dev, mirror.tomb_dev,
-                mirror.n_valid_dev, jnp.asarray(starts), jnp.asarray(ends),
-                jnp.asarray(unbs), jnp.asarray(qhi), jnp.asarray(qlo),
-            )
-        kt, rh31, rl31, t8, n = self._pallas_layout(mirror)
-        return _vis_batch_pallas_q(
-            kt, rh31, rl31, t8, mirror.n_valid_dev, jnp.asarray(starts),
-            jnp.asarray(ends), jnp.asarray(unbs.astype(np.int32)),
-            jnp.asarray(qhi), jnp.asarray(qlo),
-            n=n, interpret=(self._scan_kernel == "pallas_interpret"),
-            mesh=self._kernel_mesh,
-        )
+        s0, e0, r0, _rows = specs[0]
+        query = np.stack(
+            [self._pack_query(mirror, *spec) for spec in specs]
+            + [self._pack_query(mirror, s0, e0, r0, False)] * (qpad - len(specs)))
+        cols, n = self._scan_cols(mirror)
+        return _vis_rows(cols, query, kernel=self._scan_kernel, n=n,
+                         size=size, mesh=self._mesh)
 
-    def _dev_visible_indices(self, mask, counts, n_rows: int):
-        """(per-partition counts [P], row indices [P, size]) from a device
-        mask [P, N]: partition p's visible rows are ``rows[p,
-        :counts[p]]``, ascending — the shared two-phase gather:
-        per-partition counts first (tiny transfer), then the SHARD-LOCAL
-        compacted index block [P, size] with size = pow2(max per-partition
-        count). The host transfer is bounded by P·pow2(max visible per
-        shard) index words — O(visible rows), never the [P, N] mask — and
-        no cross-device gather happens on a multi-device mesh
-        (`_part_indices_of_mask` keeps the ``part`` axis sharded through
-        the compaction). The pieces are handed on as the device gave them:
-        every host materialization reads them a partition at a time."""
-        counts_h = _host_pull(counts)  # [P]; blocks on the kernel
-        most = int(counts_h.max())
-        if most == 0:
-            return counts_h, np.empty((len(counts_h), 0), dtype=np.int32)
-        return counts_h, _host_pull(_part_indices_of_mask(
-            mask, size=_pow2_bucket(most, n_rows), mesh=self._mesh))
+    def _pull_visible(self, launch, ranges, picked, n_rows: int, path: str,
+                      stage=TRACER.stage):
+        """One read's device round trip → host ``(counts [..., P], rows
+        [..., P, size])``: partition p's visible rows are ``rows[..., p,
+        :counts[..., p]]``, ascending. ``launch(size)`` is the read's
+        :meth:`_dev_mask` / :meth:`_dev_mask_batch` call; ``ranges`` the
+        ``(start, end)`` of the queries that want rows, at ``picked`` on the
+        block's query axis (``[0]`` for a single read).
+
+        The index block's ``size`` is pow2 of the most rows a partition
+        showed at the last read of each of ``ranges`` (the largest of a
+        batch), so a read is ONE call and ONE pull of O(visible rows),
+        never the mask. Where a range was never read (or was forgotten),
+        the read takes two steps: the counts alone, then the block at the
+        exact bucket. Where the counts overflow a remembered bucket, it
+        calls once more at the exact bucket and counts it in
+        ``kb_scan_index_refetch_total{path=}``. The answer is exact either
+        way. ``device_dispatch`` is the packing and the call,
+        ``device_compute`` the pull and any second call."""
+        with self._bucket_lock:
+            known = [self._buckets.get(r) for r in ranges]
+        remembered = None not in known
+        most_known = max(known) if ranges and remembered else 0
+        size = _pow2_bucket(most_known, n_rows) if most_known else 0
+        with stage("device_dispatch"):
+            out = launch(size)
+        with stage("device_compute"):
+            block = _host_pull(out)  # blocks on the program
+            most = block[..., 0].max(axis=-1).reshape(-1)[picked]
+            need = int(most.max()) if len(most) else 0
+            if need > size:
+                if remembered and self._metrics is not None:
+                    self._metrics.emit_counter("kb.scan.index.refetch.total",
+                                               path=path)
+                out = launch(_pow2_bucket(need, n_rows))
+                block = _host_pull(out)
+            # the read's device array goes here, inside a stage: dropping
+            # it gives up the GIL, and getting it back must not fall
+            # between two stages (kb_rpc_unaccounted_seconds)
+            del out
+        with self._bucket_lock:
+            for r, m in zip(ranges, most.tolist()):
+                if r not in self._buckets and len(self._buckets) >= _BUCKET_MEMO:
+                    del self._buckets[next(iter(self._buckets))]
+                self._buckets[r] = m
+        return block[..., 0], block[..., 1:]
 
     def _materialize_visible(self, mirror: Mirror, vis, overlay):
-        """Visible rows (``(counts, rows)`` of :meth:`_dev_visible_indices`)
+        """Visible rows (``(counts, rows)`` of :meth:`_pull_visible`)
         → sorted KeyValue list with the delta overlay merged — the ONE host
         materialization the single and query-batched range paths share, so
         batched responses cannot drift from sequential ones by
@@ -1347,7 +1427,7 @@ class TpuScanner(Scanner):
 
     def _materialize_wire(self, mirror: Mirror, vis, overlay,
                           limit: int = 0) -> tuple[bytes, int, bool]:
-        """Visible rows (``(counts, rows)`` of :meth:`_dev_visible_indices`)
+        """Visible rows (``(counts, rows)`` of :meth:`_pull_visible`)
         → ``(RangeResponse.kvs wire bytes, rows, more)`` with the delta
         overlay merged: what :meth:`_materialize_visible` + ``kvs[:limit]``
         + the front's per-row protobuf produce, byte for byte — the ONE
@@ -1364,8 +1444,10 @@ class TpuScanner(Scanner):
         per row or per overlay entry."""
         counts, rows = vis
         encoding = mirror.encoding
-        # as the device handed them back: int32 and contiguous already, so
-        # this copies nothing; the call reads them through a raw pointer
+        # as the device handed them back, int32: contiguous already on one
+        # partition, so this copies nothing there (on several, the block's
+        # counts column is cut away); the call reads them through a raw
+        # pointer
         rows = np.ascontiguousarray(rows, dtype=np.int32)
         return wire_read(
             mirror.wire_cols, mirror.val_offsets, mirror.keys_host.shape[2],
@@ -1379,9 +1461,10 @@ class TpuScanner(Scanner):
         host half — the stages ``range_`` and ``list_wire`` share."""
         # attribution: delta_overlay = the delta on the read path (publish
         # check, the wait for the writers' lock, the overlay under it);
-        # dispatch = query assembly + async kernel enqueue; compute = the
-        # first blocking device transfer (counts + index pull, which waits
-        # out the kernel); host_copy = row materialization + overlay merge
+        # dispatch = query packing + the one async call; compute = the
+        # one blocking pull of counts and indices, which waits out the
+        # program (and a second call where the bucket was short:
+        # `_pull_visible`); host_copy = row materialization + overlay merge
         # on the host. Only this engine's kernel path records the device_*
         # stages, so their EWMAs are the auto-depth dispatch RTT.
         with TRACER.stage("delta_overlay"):
@@ -1390,20 +1473,12 @@ class TpuScanner(Scanner):
             with self._mlock:
                 mirror = self._mirror
                 overlay = self._delta.overlay(start, end, read_revision)
-        with TRACER.stage("device_dispatch"):
-            mask, counts = self._dev_mask(mirror, start, end, read_revision)
-        with TRACER.stage("device_compute"):
-            vis = self._dev_visible_indices(
-                mask, counts, mirror.keys_host.shape[1]
-            )
+        vis = self._pull_visible(
+            lambda size: self._dev_mask(mirror, start, end, read_revision,
+                                        size),
+            [(start, end)], [0], mirror.keys_host.shape[1], "single")
         with TRACER.stage("host_copy"):
-            out = materialize(mirror, vis, overlay)
-            # the read's device arrays go here, inside the stage: dropping
-            # them gives up the GIL, and under three listers getting it
-            # back took ~1 ms on average (42 ms at worst) that no stage
-            # showed (kb_rpc_unaccounted_seconds over 2 ms, PR 26)
-            del mask, counts
-        return out
+            return materialize(mirror, vis, overlay)
 
     def _on_host(self, limit: int) -> bool:
         """A page small enough that one engine iter beats a kernel launch,
@@ -1515,28 +1590,13 @@ class TpuScanner(Scanner):
                 overlays = [
                     self._delta.overlay(s[1], s[2], s[3]) for _, s in device
                 ]
-        with TRACER.stage("device_dispatch"):
-            mask, counts = self._dev_mask_batch(
-                mirror, [(s[1], s[2], s[3]) for _, s in device])
-            sel = np.zeros(int(mask.shape[0]), dtype=bool)
-            for k, (_, s) in enumerate(device):
-                sel[k] = s[0] != "count"  # counts (and pow2 pad) pull no rows
-        n_rows = mirror.keys_host.shape[1]
-        # both kernels emit [Qpad, P, N] with N == the host row width: the
-        # row indices below index the host columns
-        assert int(mask.shape[2]) == n_rows, (mask.shape, n_rows)
-        idx_parts = np.empty((*mask.shape[:2], 0), dtype=np.int32)
-        with TRACER.stage("device_compute"):
-            counts_h = _host_pull(counts)  # blocks on the kernel; [Qpad, P]
-            want = int(counts_h[sel].max()) if sel.any() else 0
-            if want:
-                # shard-local per-(query, partition) compaction: the host
-                # pulls Qpad·P·pow2(max count) index words — O(visible
-                # rows), never the [Q, P, N] mask — and the ``part`` axis
-                # stays sharded through the nonzero on a multi-device mesh
-                size = _pow2_bucket(want, n_rows)
-                idx_parts = _host_pull(_part_indices_of_mask_sel(
-                    mask, jnp.asarray(sel), size=size, mesh=self._mesh))
+        # counts pull no rows (nor does the pow2 padding)
+        specs = [(s[1], s[2], s[3], s[0] != "count") for _, s in device]
+        picked = [k for k, spec in enumerate(specs) if spec[3]]
+        counts_h, idx_parts = self._pull_visible(
+            lambda size: self._dev_mask_batch(mirror, specs, size),
+            [specs[k][:2] for k in picked], picked, mirror.keys_host.shape[1],
+            "batch")
         with TRACER.stage("host_copy"):
             for k, (qi, spec) in enumerate(device):
                 if spec[0] == "count":
@@ -1552,7 +1612,6 @@ class TpuScanner(Scanner):
                     continue
                 kvs = self._materialize_visible(mirror, vis, overlays[k])
                 out[qi] = (kvs[:limit], len(kvs) > limit) if limit else (kvs, False)
-            del mask, counts  # released inside a stage, as in range_
         return out
 
     def range_stream(self, start: bytes, end: bytes, read_revision: int, batch_size: int = 300):
@@ -1568,10 +1627,12 @@ class TpuScanner(Scanner):
         with self._mlock:
             mirror = self._mirror
             overlay = self._delta.overlay(start, end, read_revision)
-        mask, counts = self._dev_mask(mirror, start, end, read_revision)
-        counts_h, rows = self._dev_visible_indices(
-            mask, counts, mirror.keys_host.shape[1]
-        )
+        # a stream's read records no stage: it answers no unary RPC
+        counts_h, rows = self._pull_visible(
+            lambda size: self._dev_mask(mirror, start, end, read_revision,
+                                        size),
+            [(start, end)], [0], mirror.keys_host.shape[1], "single",
+            stage=lambda _name: contextlib.nullcontext())
         extra = sorted(
             (k, v) for k, v in overlay.items() if v is not None
         )  # (key, (rev, value)) insertions, key-ascending
@@ -1624,10 +1685,10 @@ class TpuScanner(Scanner):
                 mirror = self._mirror
                 overlay = self._delta.overlay(start, end, read_revision)
         with TRACER.stage("device_dispatch"):
-            mask, counts = self._dev_mask(mirror, start, end, read_revision)
+            out = self._dev_mask(mirror, start, end, read_revision, 0)
         with TRACER.stage("device_compute"):
-            total = int(_host_pull(counts).sum())
-            del mask, counts  # released inside a stage, as in range_
+            total = int(_host_pull(out).sum())  # the counts alone
+            del out  # released inside a stage, as in range_
         # the same stage again: one observation per RPC (Tracer.finish)
         with TRACER.stage("delta_overlay"):
             return self._overlay_corrected_count(mirror, total, overlay,
@@ -1835,8 +1896,8 @@ class TpuScanner(Scanner):
 
     def _pull_victim_indices(self, mask_dev, mirror) -> dict[int, np.ndarray]:
         """Per-partition victim row indices via the adaptive SHARD-LOCAL
-        two-phase transfer — the compact analogue of
-        :meth:`_dev_visible_indices` and a named KB111 materialization
+        two-phase transfer — the compact analogue of the read path's
+        :meth:`_pull_visible` and a named KB111 materialization
         funnel. Phase one pulls the per-partition (victims, valid) counts
         (8·P bytes); phase two pulls only the SMALLER index set — victim
         indices on an incremental compact (few victims), survivor indices

@@ -463,6 +463,28 @@ def test_kb109_allows_assembly_points_and_wrappers():
     assert ids(src, TPU_ENG) == []
 
 
+def test_kb109_flags_the_fused_read_outside_the_assembly_points():
+    # `_vis_rows` is a read's one device call (packed query in, counts and
+    # indices out): launched anywhere but the assembly points, it forks the
+    # query packing and the kernel selection they keep
+    src = ("class E:\n"
+           "    def range_(self, cols, q):\n"
+           "        return _vis_rows(cols, q, kernel='jnp', n=0, size=64)\n")
+    assert ids(src, TPU_ENG) == ["KB109"]
+    ok = ("class E:\n"
+          "    def _dev_mask(self, cols, q, size):\n"
+          "        return _vis_rows(cols, q, kernel='jnp', n=0, size=size)\n"
+          "    def _dev_mask_batch(self, cols, q, size):\n"
+          "        return _vis_rows(cols, q, kernel='jnp', n=0, size=size)\n")
+    assert ids(ok, TPU_ENG) == []
+    # and what it returns is device data: pulled outside `_host_pull`, it
+    # is an unmetered transfer as well
+    leak = ("import numpy as np\n"
+            "def leak(cols, q):\n"
+            "    return np.asarray(_vis_rows(cols, q, kernel='jnp', n=0, size=8))\n")
+    assert ids(leak, TPU_ENG) == ["KB109", "KB111"]
+
+
 def test_kb109_scoped_and_suppressible():
     src = ("from kubebrain_tpu.ops.scan_pallas import scan_mask_pallas\n"
            "def f(*a):\n"

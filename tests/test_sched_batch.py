@@ -173,7 +173,9 @@ def test_batched_vs_sequential_byte_identical_randomized():
 # ----------------------------------------------- one launch for the batch
 def test_count_rides_the_same_launch_as_range():
     """A mixed Range+Count batch must cost exactly ONE `_dev_mask_batch`
-    dispatch and ZERO single-query `_dev_mask` dispatches."""
+    dispatch and ZERO single-query `_dev_mask` dispatches — once its
+    ranges' index buckets are remembered; the batch that first reads them
+    takes two (the counts, then the indices at the exact bucket)."""
     rng = random.Random(7)
     store, backend = _tpu_backend()
     sc = backend.scanner
@@ -199,6 +201,9 @@ def test_count_rides_the_same_launch_as_range():
             ("range", b"/registry/", b"/registry0", head, 0),
             ("count", b"/registry/pods/", b"/registry/pods0", head),
         ]
+        sc.scan_batch(specs)
+        assert calls == {"batch": 2, "single": 0}, calls
+        calls["batch"] = 0
         got = sc.scan_batch(specs)
         assert calls == {"batch": 1, "single": 0}, calls
 

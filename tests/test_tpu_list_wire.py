@@ -329,6 +329,8 @@ def test_wire_reads_ride_one_dispatch_and_join_an_identical_one():
                 b.create(NS % ns + b"pod-%04d" % i, b"v%d" % i)
         sc.publish()
         b.create(NS % 2 + b"pod-0003x", b"overlay")
+        for ns in (1, 2, 3):  # each range's index bucket remembered
+            sc.list_wire(*span_of(ns), b.current_revision(), 0)
         calls = {"batch": 0, "single": 0}
         orig_batch, orig_single = sc._dev_mask_batch, sc._dev_mask
         sc._dev_mask_batch = lambda *a: calls.__setitem__(

@@ -338,12 +338,14 @@ _SCAN_DISPATCH_NAMES = {
     "visibility_mask_batch", "visibility_mask_batch_cached",
     "visibility_mask_batch_cached_q",
     "_vis_batch", "_vis_batch_q", "_vis_batch_pallas", "_vis_batch_pallas_q",
+    "_vis_rows",
 }
 #: functions allowed to reference them: the two engine assembly points and
 #: the module-level jit wrappers those assembly points dispatch through
 _SCAN_DISPATCH_ALLOWED = {
     "_dev_mask", "_dev_mask_batch",
     "_vis_batch", "_vis_batch_q", "_vis_batch_pallas", "_vis_batch_pallas_q",
+    "_vis_rows",
 }
 
 
@@ -506,7 +508,7 @@ class ReplayableWorkloadRandomness(Rule):
 #: dry run on real traffic
 _DEVICE_PRODUCER_NAMES = {
     "_vis_batch", "_vis_batch_q", "_vis_batch_pallas", "_vis_batch_pallas_q",
-    "_part_indices_of_mask", "_part_indices_of_mask_sel",
+    "_vis_rows", "_part_indices_of_mask", "_part_indices_of_mask_sel",
     "_part_survivor_indices", "_survivor_mask", "_victim_part_counts",
     "_victim_batch", "_victim_batch_pallas", "_dev_mask", "_dev_mask_batch",
 }
