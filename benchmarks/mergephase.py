@@ -15,20 +15,38 @@ fill restarts from about 0 once the pass is over, somewhere between the
 Compact's due time and ``stall_s`` later, and every later crossing moves with
 it. While the pass holds the merge lock (``_compact_active``) no reader
 merges, so a Compact's merge has no follow-ups.
+
+How long a merge's stall and backlog last is the file's to state
+(``merge_stall_s``, ``STALL_S`` where it states none): at a rate near the
+write path's knee the crossings come closer together than ``STALL_S``
+allows. A file that states its own allowance rests it on a measurement: the
+longest stall and backlog measured on the chip at its store size and write
+rate (``merge_stall_measured_s``, by ``stalls`` below), at least
+``FLOOR_FACTOR`` times over, and its ``merge_rule`` text cites the figure.
+Every run then holds the window's crossings to the allowance (``stalls``:
+``run.py`` prints a loud line where one overruns it). A Compact's allowance
+stays ``STALL_S`` whatever the file says.
 """
 
 from __future__ import annotations
 
 import os
+import statistics
 
 import plugin
 
 #: ``TpuScanner(merge_threshold=)``'s default, which the README server runs
 #: with; tests/test_mergephase.py fails if the engine's is another
 MERGE_THRESHOLD = 4096
-#: how long a merge's (or a Compact's) stall and backlog may last before
-#: the rule counts them as over
+#: how long a Compact's stall and backlog may last before the rule counts
+#: them as over, and a merge's where the traffic file states no allowance
 STALL_S = 7.0
+#: a file's own merge allowance is at least this many times the longest
+#: stall and backlog measured on the chip at its size and rate
+FLOOR_FACTOR = 2.0
+#: a second of the window holds the writers up when its worst Txn took more
+#: than this many times the window's median worst Txn of a second
+HELD_UP = 2.0
 
 _OPS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "ops")
 
@@ -88,6 +106,12 @@ def compacts(traffic: dict, seconds: float, rate_scale: float = 1.0) -> list[flo
     return sorted(out)
 
 
+def merge_stall_s(traffic: dict) -> float:
+    """How long a merge's stall and backlog may last in this mix: the
+    file's ``merge_stall_s``, else ``STALL_S``."""
+    return float(traffic.get("merge_stall_s", STALL_S))
+
+
 def merges(r: int, w: float, seconds: float, t: int = MERGE_THRESHOLD) -> int:
     """Merges that START inside a window of ``seconds`` at ``w`` rows/s when
     the delta holds ``r`` rows as it opens: one per ``t`` rows."""
@@ -117,6 +141,42 @@ def crossings(traffic: dict, seconds: float, rate_scale: float = 1.0,
                  for w, late in ((lo, True), (hi, False)))
 
 
+def crossing_times(traffic: dict, seconds: float, rate_scale: float = 1.0,
+                   t: int = MERGE_THRESHOLD) -> list[float]:
+    """The seconds of the window at which the writes kick a merge: at the
+    highest designed rate, a Compact's pass taken as over at its due time
+    (the earliest each crossing can come)."""
+    w = write_rate(traffic, rate_scale)[1]
+    out: list[float] = []
+    if not w:
+        return out
+    for start, end, fill in segments(traffic, seconds, w, late=False):
+        i = fill // t + 1
+        while start + (i * t - fill) / w < end:
+            out.append(start + (i * t - fill) / w)
+            i += 1
+    return out
+
+
+def stalls(worst_by_second: list[float], crossings: list[float]) -> list[float]:
+    """Each crossing's stall and backlog as the writers saw it, in seconds:
+    from the crossing to the end of the run of seconds, from the crossing's
+    own on, whose worst Txn took more than ``HELD_UP`` times the window's
+    median worst Txn of a second (``worst_by_second``, the line ``run.py``
+    prints, 0 for a second with no Txn). 0 where the crossing's own second
+    held nobody up."""
+    if not worst_by_second:
+        return []
+    bar = HELD_UP * statistics.median(worst_by_second)
+    out = []
+    for c in crossings:
+        end = int(c)
+        while end < len(worst_by_second) and worst_by_second[end] > bar:
+            end += 1
+        out.append(max(0.0, end - c))
+    return out
+
+
 def followup_readers(traffic: dict) -> int:
     """R: the clients of the mix's closed-loop streams that hold an operation
     the device answers (``DEVICE_READ``). ``TpuScanner._ensure_published``
@@ -142,7 +202,7 @@ def expected(traffic: dict, seconds: float, rate_scale: float = 1.0,
     return lo + n, hi * (1 + followup_readers(traffic)) + n
 
 
-def design_faults(traffic: dict, seconds: float, stall_s: float = STALL_S,
+def design_faults(traffic: dict, seconds: float, stall_s: float | None = None,
                   t: int = MERGE_THRESHOLD) -> list[str]:
     """What the traffic file's design breaks of the rule, for an open-loop
     cell at its own window length; empty where it holds.
@@ -152,28 +212,34 @@ def design_faults(traffic: dict, seconds: float, stall_s: float = STALL_S,
     k = 0, the last stretch: r + w*len <= t/2 (half the threshold is the
             margin);
     k >= 1: crossing i starts at (i*t - r)/w; the last one's stall and
-            backlog (``stall_s``) are over before the window closes, or the
-            Compact that ends the stretch is due;
+            backlog (``stall_s``, the file's ``merge_stall_s`` where not
+            given) are over before the window closes, or the Compact that
+            ends the stretch is due;
     and, but for a last stretch with k = 0, the next crossing is far, after
     the window's end or the Compact's due time: (k+1)*t - r > w*len + t/4.
     A Compact's stall is over before the window closes, and the count holds
-    whether its pass lasts 0 or ``stall_s``."""
+    whether its pass lasts 0 or ``STALL_S``. A file that states its own
+    merge allowance states the measurement under it, and the allowance is at
+    least ``FLOOR_FACTOR`` times that."""
+    if stall_s is None:
+        stall_s = merge_stall_s(traffic)
     want = traffic["merges_in_window"]
     if isinstance(want, dict):
         lo, hi = crossings(traffic, seconds, t=t)
-        return [] if (want["min"], want["max"]) == (lo, hi) else [
-            f"the file's range {want} is not the rule's {lo}..{hi}"]
+        return allowance_faults(traffic) + (
+            [] if (want["min"], want["max"]) == (lo, hi) else
+            [f"the file's range {want} is not the rule's {lo}..{hi}"])
     w = write_rate(traffic)[0]
     lo, hi = crossings(traffic, seconds, t=t)
-    out = []
+    out = allowance_faults(traffic)
     if (lo, hi) != (want, want):
         r = int(traffic.get("warmup_writes", 0))
         out.append(f"floor(({r} + {w:g} x {seconds:g}) / {t}) = {hi}"
-                   + (f" ({lo} if a Compact's pass lasts {stall_s:g} s)"
+                   + (f" ({lo} if a Compact's pass lasts {STALL_S:g} s)"
                       if lo != hi else "") + f", the file says {want}")
     cs = compacts(traffic, seconds)
     for c in cs:
-        if c + stall_s > seconds:
+        if c + STALL_S > seconds:
             out.append(f"the Compact at {c:.1f} s: its stall is not over by "
                        f"{seconds:g} s")
     stretches = segments(traffic, seconds, w, late=False)
@@ -199,4 +265,24 @@ def design_faults(traffic: dict, seconds: float, stall_s: float = STALL_S,
         if (k + 1) * t - r <= w * span + t / 4:
             out.append(f"(k+1)*T - r = {(k + 1) * t - r} <= w*W + T/4 = "
                        f"{w * span + t / 4:g}")
+    return out
+
+
+def allowance_faults(traffic: dict) -> list[str]:
+    """Where the file states its own merge allowance: the measurement under
+    it is stated too and cited in ``merge_rule``, and the allowance is at
+    least ``FLOOR_FACTOR`` times it."""
+    if "merge_stall_s" not in traffic:
+        return []
+    allowance = merge_stall_s(traffic)
+    measured = traffic.get("merge_stall_measured_s")
+    if measured is None:
+        return ["merge_stall_s without merge_stall_measured_s, the longest "
+                "stall and backlog measured on the chip"]
+    out = []
+    if allowance < FLOOR_FACTOR * float(measured):
+        out.append(f"merge_stall_s {allowance:g} < {FLOOR_FACTOR:g} x "
+                   f"merge_stall_measured_s {measured:g}")
+    if f"{float(measured):g}" not in traffic.get("merge_rule", ""):
+        out.append(f"merge_rule does not cite the measured {float(measured):g} s")
     return out

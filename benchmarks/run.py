@@ -600,6 +600,18 @@ class Context:
         args = (self.workload, self.window_s, self.rate_scale)
         return counted, mergephase.crossings(*args), mergephase.expected(*args)[1]
 
+    def worst_by_second(self, family: int) -> list[float]:
+        """The worst latency (ms) of the family's requests due in each
+        whole second of the window, 0 for a second with none; empty where
+        the window holds none."""
+        by_second: dict[int, float] = {}
+        for r in self.recs(family, judged_only=False):
+            sec = int(r[2] - self.window[0])
+            by_second[sec] = max(by_second.get(sec, 0.0), (r[4] - r[2]) * 1e3)
+        if not by_second:
+            return []
+        return [by_second.get(s, 0.0) for s in range(int(self.window_s))]
+
 
 def read_metric(name: str, ctx: Context):
     """The metric's value by the reader its file names; None where the
@@ -649,7 +661,8 @@ def summary_lines(ctx: Context) -> list[str]:
     beside them, over those no witnessed pause of the machine touched (the
     pause rule), failures by error string, the per-second worst Txn,
     generator lateness, the machine's pauses, the compile cache's growth, the
-    merges counted beside the designed crossings and the follow-ups allowed."""
+    merges counted beside the designed crossings and the follow-ups allowed,
+    and each crossing's stall and backlog beside the design's allowance."""
     from stats import percentile
 
     def tails(lat):
@@ -698,15 +711,11 @@ def summary_lines(ctx: Context) -> list[str]:
                 errors[r[10][:80]] = errors.get(r[10][:80], 0) + 1
     out.append(f"failed requests by error: {errors or 'none'}")
     for fam, label in ((1, "txn"), (0, "range")):
-        by_second: dict[int, float] = {}
-        for r in ctx.recs(fam, judged_only=False):
-            sec = int(r[2] - ctx.window[0])
-            by_second[sec] = max(by_second.get(sec, 0.0), (r[4] - r[2]) * 1e3)
-        if by_second:
+        worst = ctx.worst_by_second(fam)
+        if worst:
             # a stall (a delta merge, a compaction) shows as a run of seconds
             out.append(f"{label} max latency by second of the window (ms): "
-                       + " ".join(f"{by_second.get(s, 0):.0f}"
-                                  for s in range(int(ctx.window_s))))
+                       + " ".join(f"{ms:.0f}" for ms in worst))
     late = [(r[3] - r[2]) * 1e3 for r in ctx.recs(loop="open", judged_only=False)]
     if late:
         out.append(f"generator lateness (open loops): p50={percentile(late, 50):.3f}ms "
@@ -723,8 +732,18 @@ def summary_lines(ctx: Context) -> list[str]:
             f"{ctx.workload.get('warmup_writes', 0)}, writes/s "
             f"{rate[0]:g}" + (f"..{rate[1]:g}" if rate[1] != rate[0] else "")
             + f", window {ctx.window_s:g} s)")
+    # each designed crossing's stall and backlog as the writers saw it
+    stalls = mergephase.stalls(ctx.worst_by_second(1), mergephase.crossing_times(
+        ctx.workload, ctx.window_s, ctx.rate_scale))
+    allowance = mergephase.merge_stall_s(ctx.workload)
+    if stalls:
+        line += (f"; stall and backlog after each crossing (s): "
+                 + " ".join(f"{x:.2f}" for x in stalls)
+                 + f" (allowance {allowance:g} s)")
     if counted is not None and not lo <= counted <= most:
         line += "  *** MERGE PHASE OFF THE DESIGN: this run measured another cell ***"
+    if stalls and max(stalls) > allowance:
+        line += "  *** MERGE STALL OVER THE DESIGN'S ALLOWANCE ***"
     out.append(line)
     ticks = list(ctx.recs(check.COMPACT, judged_only=False, due_in_window=False))
     if ticks:

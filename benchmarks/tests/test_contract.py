@@ -60,14 +60,17 @@ def test_a_twin_is_its_bases_definition_under_the_name_of_another_cell():
     completes, ``ranges_per_s``: there the latencies swing with the machine
     by more than any bound allows (PERF.md section 2). So it reads every
     other metric under a twin name, ``<base>.beside``: the base's reader and
-    arguments, per layer, ``moves`` ``ranges_per_s``, ``workloads`` that
-    cell alone; the two judged latencies of the other cells among them.
-    ``run.finish`` prints a metric for the cells of its own entry only
-    (``test_rehearsal.py`` holds every cell's line to exactly that). No
-    tail above the p95 and no quiet reading is an end-to-end metric."""
-    cell = "k8s-2500.relist-merge"
+    arguments, per layer, ``moves`` ``ranges_per_s``, ``workloads`` the
+    cells judged on ``ranges_per_s`` (every cell that is judged as it is,
+    ``k8s-2500-h600.relist-compact`` too); the two judged latencies of the
+    other cells among them. ``run.finish`` prints a metric for the cells of
+    its own entry only (``test_rehearsal.py`` holds every cell's line to
+    exactly that). No tail above the p95 and no quiet reading is an
+    end-to-end metric."""
     e2e = {m["name"]: m for m in B["end_to_end"]}
     layer = {m["name"]: m for m in B["per_layer"]}
+    judged = e2e["ranges_per_s"]["workloads"]
+    assert "k8s-2500.relist-merge" in judged
     assert not set(e2e) & set(layer)
     assert len(e2e) + len(layer) == len(B["end_to_end"]) + len(B["per_layer"])
     twins = [n for n in layer if n.endswith(".beside")]
@@ -77,17 +80,22 @@ def test_a_twin_is_its_bases_definition_under_the_name_of_another_cell():
         entry = layer.get(base) or e2e[base]
         a, b = (run.load_json("metrics", n + ".json") for n in (twin, base))
         assert (a["reader"], a.get("args")) == (b["reader"], b.get("args")), twin
-        assert layer[twin]["workloads"] == [cell] and cell not in entry["workloads"]
+        assert layer[twin]["workloads"] == judged, twin
+        assert not set(judged) & set(entry["workloads"]), twin
         assert layer[twin]["moves"] == "ranges_per_s"
         assert {k: layer[twin][k] for k in ("unit", "better", "source")} == {
             k: entry[k] for k in ("unit", "better", "source")}
         assert layer[twin]["layer"] == entry.get("layer", "gRPC front")
-    # what the cell reads per layer is a twin, boot's phases, or its own
-    mine = {n for n, m in layer.items() if cell in m["workloads"]}
-    assert mine - set(twins) == {"range_p99_ms", "boot_jax_init_s",
-                                 "boot_store_open_s", "boot_mirror_build_s"}
-    assert [n for n, m in e2e.items() if cell in m.get("workloads", [cell])] == [
-        "ranges_per_s", "setup_s"]
+    # what such a cell reads per layer is a twin, boot's phases, the Range
+    # tail, or its own (a metric of that cell alone)
+    shared = {"range_p99_ms", "boot_jax_init_s", "boot_store_open_s",
+              "boot_mirror_build_s", "boot_compact_warm_s"}
+    for cell in judged:
+        mine = {n for n, m in layer.items() if cell in m["workloads"]}
+        own = {n for n, m in layer.items() if m["workloads"] == [cell]}
+        assert mine - set(twins) == shared | own, cell
+        assert [n for n, m in e2e.items() if cell in m.get("workloads", [cell])] == [
+            "ranges_per_s", "setup_s"]
     for m in B["end_to_end"]:
         args = run.load_json("metrics", m["name"] + ".json").get("args", {})
         assert "quiet" not in args and args.get("q", 0) < 99, m["name"]

@@ -4,6 +4,7 @@ rate w and the window W — and every traffic file keeps to it."""
 
 import json
 import os
+from types import SimpleNamespace
 
 import pytest
 
@@ -209,3 +210,150 @@ def test_two_compacts_closer_than_a_stall_count_no_rows_between():
     assert mergephase.compacts(mix, 20) == [pytest.approx(c) for c in (10, 13, 16, 19)]
     assert mergephase.crossings(mix, 20) == (0, 0)
     assert mergephase.expected(mix, 20) == (4, 4)
+
+
+# --- a traffic file's own merge allowance ----------------------------------
+
+#: the 5,000-node cluster's documented stream: 500 Lease renewals (nodes /
+#: 10 s) + 10 pod creates + 10 pod deletes a second, open loop
+_CHURN_520 = {"loop": "open", "rate": 520, "ops": [
+    {"op": "update", "table": "leases", "weight": 50},
+    {"op": "create", "table": "pods", "weight": 1},
+    {"op": "delete", "table": "pods", "weight": 1}]}
+
+
+def _churn(r, **more):
+    return _mix(_CHURN_520, _COUNT_POLL, warmup_writes=r, merges_in_window=6, **more)
+
+
+def test_a_520_rows_per_s_stream_needs_an_allowance_under_the_default():
+    """At 520 rows/s crossings come T/w = 7.88 s apart; the default 7 s
+    allowance and the T/4 margin need 8.97 s, so no warm-up fill gives a
+    design. At 4 s, every fill from 656 to 1,647 gives six crossings (at
+    5.6 ... 45.0 s with r = 1,200; the seventh at 52.8 s), and no other."""
+    assert not any(mergephase.design_faults(_churn(r), 50) == [] for r in range(4096))
+    admitted = [r for r in range(4096)
+                if mergephase.design_faults(_churn(r), 50, stall_s=4.0) == []]
+    assert admitted == list(range(656, 1648))
+    assert [r for r in range(4096) if mergephase.design_faults(
+        _churn(r), 50, stall_s=5.0) == []] == list(range(1176, 1648))
+    assert mergephase.crossing_times(_churn(1200), 50) == pytest.approx(
+        [(i * 4096 - 1200) / 520 for i in range(1, 7)])
+    assert (7 * 4096 - 1200) / 520 == pytest.approx(52.83, abs=0.01)
+
+
+@pytest.mark.parametrize("stall_s", [5.91, 6.0, 7.0])
+def test_no_520_rows_per_s_design_above_5_9_s(stall_s):
+    assert all(mergephase.design_faults(_churn(r), 50, stall_s=stall_s)
+               for r in range(4096))
+
+
+def test_the_file_states_its_allowance_on_a_measurement():
+    """``merge_stall_s`` in the file is the allowance the rule reads, and it
+    stands on ``merge_stall_measured_s``: at least twice it, and cited in
+    the file's ``merge_rule``."""
+    rule = "six crossings; the longest stall and backlog measured 1.85 s"
+    good = _churn(1200, merge_stall_s=4.0, merge_stall_measured_s=1.85, merge_rule=rule)
+    assert mergephase.merge_stall_s(good) == 4.0
+    assert mergephase.design_faults(good, 50) == []
+    unmeasured = _churn(1200, merge_stall_s=4.0, merge_rule=rule)
+    assert any("merge_stall_measured_s" in f
+               for f in mergephase.design_faults(unmeasured, 50))
+    thin = _churn(1200, merge_stall_s=4.0, merge_stall_measured_s=2.1,
+                  merge_rule=rule.replace("1.85", "2.1"))
+    assert any("2 x" in f for f in mergephase.design_faults(thin, 50))
+    uncited = _churn(1200, merge_stall_s=4.0, merge_stall_measured_s=1.85,
+                     merge_rule="six crossings")
+    assert any("does not cite" in f for f in mergephase.design_faults(uncited, 50))
+    # the file's allowance, not the default: r = 700 is a design at 4 s only
+    assert mergephase.design_faults(dict(good, warmup_writes=700), 50) == []
+    assert mergephase.design_faults(_churn(700), 50)
+
+
+@pytest.mark.parametrize("mix,crossings,expected,times", [
+    ("relist", (0, 0), (0, 0), []),
+    ("steady", (3, 3), (3, 3), [11.47, 26.64, 41.81]),
+    ("relist-merge", (3, 3), (3, 12), [9.61, 24.79, 39.96]),
+    ("relist-compact", (2, 2), (3, 9), [14.99, 39.17])])
+def test_the_four_accepted_files_read_as_before(mix, crossings, expected, times):
+    """None states an allowance: each reads the default, the parent's
+    crossings and expected merges, and an empty ``design_faults``; the
+    Compact's pass of relist-compact still counts at 7 s."""
+    traffic = run.load_json("traffic", mix + ".json")
+    assert "merge_stall_s" not in traffic
+    assert mergephase.merge_stall_s(traffic) == mergephase.STALL_S == 7.0
+    assert mergephase.crossings(traffic, 50) == crossings
+    assert mergephase.expected(traffic, 50) == expected
+    assert mergephase.design_faults(traffic, 50) == []
+    assert [round(c, 2) for c in mergephase.crossing_times(traffic, 50)] == times
+    if mix == "relist-compact":
+        # the late stretch starts once the Compact's 7 s pass is over
+        assert mergephase.segments(traffic, 50, 270, late=True)[1][0] == pytest.approx(31.0)
+
+
+def test_a_compacts_allowance_does_not_follow_the_files():
+    """A short merge allowance leaves the Compact's at ``STALL_S``: a
+    Compact due at 45 s is still refused, and its late stretch still starts
+    7 s after it."""
+    mix = _mix(_WRITERS, _compactor(12), warmup_writes=3000, merges_in_window=3)
+    assert mergephase.design_faults(mix, 50, stall_s=2.0) == []
+    late = _mix(_WRITERS, _compactor(45), warmup_writes=3000, merges_in_window=3)
+    assert any("its stall is not over by 50 s" in f
+               for f in mergephase.design_faults(late, 50, stall_s=2.0))
+    assert mergephase.crossings(mix, 50) == (3, 3)
+
+
+@pytest.mark.parametrize("worst,crossings,want", [
+    # a crossing at 5.5 holds seconds 5-7 up: over at the end of second 7
+    ([3, 3, 3, 3, 3, 90, 80, 70, 3, 3], [5.5], [2.5]),
+    # a held-up second that does not touch the crossing's run is not its
+    ([3, 3, 3, 3, 3, 90, 3, 70, 3, 3], [5.5], [0.5]),
+    # the crossing's own second held nobody up
+    ([3, 3, 3, 3, 3, 3, 90, 3, 3, 3], [5.5], [0.0]),
+    # two crossings, and a run to the window's end
+    ([3, 40, 3, 3, 3, 3, 3, 3, 50, 50], [1.2, 8.9], [0.8, 1.1]),
+    ([], [1.0], []),
+])
+def test_a_crossings_stall_is_the_run_of_held_up_seconds_from_it(worst, crossings, want):
+    """Held up: a second whose worst Txn took more than twice the window's
+    median worst Txn of a second (3 ms here, so 6 ms)."""
+    assert mergephase.stalls(worst, crossings) == pytest.approx(want)
+
+
+def _recorded(held_up: range, allowance=4.0):
+    """A recorded 50 s window of the 520 rows/s design (r = 1,200, crossings
+    at 5.6 ... 45.0 s): every Txn answered in 3 ms, but those due in the
+    seconds ``held_up``, 400 ms."""
+    mix = _churn(1200, merge_stall_s=allowance, merge_stall_measured_s=allowance / 2,
+                 merge_rule=f"measured {allowance / 2:g} s")
+    recs = []
+    for i in range(520 * 50):
+        due = 100.0 + i / 520
+        ms = 400.0 if int(due - 100.0) in held_up else 3.0
+        recs.append((1, "update", due, due, due + ms / 1e3, True, i + 1, 0, 0, 0, ""))
+    traffic = [{"judged": True, "loop": "open", "recs": recs}]
+    opts = SimpleNamespace(sut="reference", trace=0, workload="x")
+    return run.Context(opts, mix, {}, SimpleNamespace(rows=0, head_revision=0),
+                       traffic, [], (100.0, 150.0), 1.0, None, None, 0,
+                       {"platform": "reference"})
+
+
+def _merge_line(ctx) -> str:
+    return next(line for line in run.summary_lines(ctx)
+                if line.startswith("merges in window:"))
+
+
+def test_a_stall_over_the_allowance_is_loud_and_one_under_it_is_not():
+    """The crossing of 21.3 s (r = 1,200, 520 rows/s): writers held up in
+    seconds 21-26 are 5.68 s of stall and backlog against the file's 4 s;
+    in seconds 21-22, 1.68 s. Only the first run prints the loud line, and
+    every run prints each crossing's reading beside the allowance."""
+    over = _merge_line(_recorded(range(21, 27)))
+    assert "stall and backlog after each crossing (s): 0.00 0.00 5.68 0.00 0.00 0.00 " \
+           "(allowance 4 s)" in over
+    assert over.endswith("*** MERGE STALL OVER THE DESIGN'S ALLOWANCE ***")
+    under = _merge_line(_recorded(range(21, 23)))
+    assert "0.00 0.00 1.68 0.00" in under and "ALLOWANCE ***" not in under
+    # the same 5.68 s under a file that allows 7 s is quiet
+    assert "ALLOWANCE ***" not in _merge_line(_recorded(range(21, 27), allowance=7.0))
+    assert "MERGE PHASE OFF" not in over     # no /metrics: nothing counted

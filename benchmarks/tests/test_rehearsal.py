@@ -1,8 +1,8 @@
-"""Every cell, end to end, at a tiny size on the CPU backend (2,000 keys, a
-3 s window, Pallas interpreted): the same command, server, generators,
-warm-up, comparison and printing as on the chip, so a wrong path or argument
-is found here. A rehearsal is never a result: ``correct`` is false and no
-number it prints is a device's."""
+"""Every cell, end to end, at a tiny size on the CPU backend (about 2,000
+start-state rows, a 3 s window, Pallas interpreted): the same command,
+server, generators, warm-up, comparison and printing as on the chip, so a
+wrong path or argument is found here. A rehearsal is never a result:
+``correct`` is false and no number it prints is a device's."""
 
 import json
 import os
@@ -11,12 +11,22 @@ import sys
 
 import pytest
 
+import run
 from conftest import BENCH
+from state import State
 
 ROOT = os.path.dirname(BENCH)
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     B = json.load(_f)
-SCALE = {"k8s-2500": 2000 / 77500}
+SEED = 2**31 + 11
+#: start-state rows a rehearsal shrinks each configuration to
+ROWS = 2000
+
+
+def _scale(config: str) -> float:
+    """The ``--scale`` that brings the configuration's start state (its
+    objects and its history) to about ``ROWS`` rows."""
+    return ROWS / State(run.load_json("configs", config + ".json"), SEED).rows
 
 
 def _metrics(kind: str, cell: str) -> set[str]:
@@ -26,11 +36,11 @@ def _metrics(kind: str, cell: str) -> set[str]:
 @pytest.mark.parametrize("trace", [0, 1])
 @pytest.mark.parametrize("cell", [w["name"] for w in B["workloads"]])
 def test_cell_on_the_cpu_backend(cell, trace):
-    config = cell.split(".")[0]
+    config = next(w["config"] for w in B["workloads"] if w["name"] == cell)
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
-         "--seed", str(2**31 + 11), "--seconds", "3", "--trace", str(trace),
-         "--sut", "cpu", "--scale", str(SCALE[config])],
+         "--seed", str(SEED), "--seconds", "3", "--trace", str(trace),
+         "--sut", "cpu", "--scale", str(_scale(config))],
         capture_output=True, text=True, timeout=600, cwd=ROOT,
         env=dict(os.environ, BENCH_RUN="x"))
     assert out.returncode == 0, out.stderr[-4000:]
@@ -41,13 +51,13 @@ def test_cell_on_the_cpu_backend(cell, trace):
     assert line["rehearsal"]["comparison_passed"], out.stderr[-3000:]
     assert line["failed"] == 0 and line["attempted"] > 0
     assert all(set(v) == {"value", "limit"} for v in line["compared"].values())
-    # the metrics the cell has to report (device-trace ones need a device)
-    # and merge_stall_ms needs a merge, write_group_size a group of two: a
-    # 3 s window at this size has neither for sure, and a reader that finds
-    # nothing to read returns nothing, never 0
+    # the metrics the cell has to report: device-trace ones need a device,
+    # and a metric whose file says what it ``needs`` (a merge, a group of
+    # two writes, a Compact) may find nothing in a 3 s window at this size;
+    # a reader that finds nothing to read returns nothing, never 0
     mine = _metrics("per_layer" if trace else "end_to_end", cell)
-    want = {n for n in mine if n.split(".")[0] not in (
-        "merge_stall_ms", "write_group_size")} - {
+    want = {n for n in mine if "needs" not in run.load_json(
+        "metrics", n + ".json")} - {
         m["name"] for m in B["per_layer"] if m["source"] == "device_trace"}
     assert want <= set(line["metrics"]), want - set(line["metrics"])
     # and none of another cell's, nor of the other list
