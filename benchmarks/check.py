@@ -250,30 +250,7 @@ def compare(state: State, traffic: list[dict], watches: list[dict],
 
     # ---- watches: every event of the range once, in revision order
     if watches:
-        bad = events = 0
-        first = ""
-        expected: dict[str, list] = {}
-        for rev, key_id, ver, dead, _s, _d in ref.writes:
-            t, i = state.locate(key_id)
-            row = (rev, 1, zlib.crc32(t.key(i)), 0) if dead else (
-                rev, 0, zlib.crc32(t.key(i)), state.value_crc(t, i, ver))
-            expected.setdefault(t.name, []).append(row)
-        for dump in watches:
-            for w in dump["watches"]:
-                want = [e for e in expected.get(w["table"], ())
-                        if e[0] > (w["created"] or 0)]
-                got = [e[:4] for e in w["events"]
-                       if e[0] != sentinels.get(w["table"])]
-                events += len(want)
-                if w["error"] or got != want:
-                    bad += 1
-                    first = first or (
-                        f"{w['table']}: {len(got)} events for {len(want)} "
-                        f"writes {w['error']}")
-        out["watch_wrong"] = _entry(bad)
-        out["compared_watch_events"] = _entry(events, 1, ">=")
-        if first:
-            out["watch_wrong"]["first"] = first
+        out.update(compare_watches(state, ref, watches, sentinels))
 
     # ---- kube-apiserver's compactor: every tick acknowledged; after the
     # window the reads at C exact and those at C - 1 refused as compacted;
@@ -303,6 +280,62 @@ def compare(state: State, traffic: list[dict], watches: list[dict],
             out["compared_device_reads"] = _entry(prom["device_reads"], 1, ">=")
         out["readback_device_unmoved"] = _entry(
             undispatched(readback["device_reads"], prom["readback"]))
+    return out
+
+
+def compare_watches(state: State, ref: Reference, watches: list[dict],
+                    sentinels: dict) -> dict:
+    """Each watch's events after its ``created`` revision (a sentinel's
+    left out) against the expected list of its range: the reference's
+    acknowledged writes whose key lies in it, in revision order, each as
+    (revision, type, key crc, value crc). The list is built once for each
+    distinct range."""
+    import watches as plan
+
+    keys, cols = [], []
+    for rev, key_id, ver, dead, _s, _d in ref.writes:
+        t, i = state.locate(key_id)
+        key = t.key(i)
+        keys.append(key)
+        cols.append((rev, 1, zlib.crc32(key), 0) if dead else (
+            rev, 0, zlib.crc32(key), state.value_crc(t, i, ver)))
+    by_key = sorted(range(len(keys)), key=lambda n: (keys[n], cols[n][0]))
+    sorted_keys = [keys[n] for n in by_key]
+    cols = np.array(cols, np.int64).reshape(-1, 4)
+    expected: dict[tuple, np.ndarray] = {}
+
+    def want_of(start: bytes, end: bytes) -> np.ndarray:
+        if (start, end) not in expected:
+            lo = bisect.bisect_left(sorted_keys, start)
+            hi = bisect.bisect_left(sorted_keys, end)
+            rows = cols[by_key[lo:hi]] if hi > lo else cols[:0]
+            expected[(start, end)] = rows[np.argsort(rows[:, 0], kind="stable")]
+        return expected[(start, end)]
+
+    skip = np.array(sorted(sentinels.values()), np.int64)
+    bad = events = 0
+    first = ""
+    for dump in watches:
+        ev = dump["events"]
+        order = np.argsort(ev["watch"], kind="stable")
+        got_all = np.stack([ev[f][order].astype(np.int64) for f, _c, _d
+                            in plan.FIELDS[1:5]], axis=1)
+        at = np.searchsorted(ev["watch"][order], np.arange(len(dump["watches"]) + 1))
+        for i, w in enumerate(dump["watches"]):
+            want = want_of(w["start"], w["end"])
+            want = want[np.searchsorted(want[:, 0], w["created"] or 0, "right"):]
+            got = got_all[at[i]:at[i + 1]]
+            got = got[~np.isin(got[:, 0], skip)]
+            events += len(want)
+            if w["error"] or got.shape != want.shape or not (got == want).all():
+                bad += 1
+                first = first or (
+                    f"{w['table']} [{w['start']!r}, {w['end']!r}): {len(got)} "
+                    f"events for {len(want)} writes {w['error']}")
+    out = {"watch_wrong": _entry(bad),
+           "compared_watch_events": _entry(events, 1, ">=")}
+    if first:
+        out["watch_wrong"]["first"] = first
     return out
 
 

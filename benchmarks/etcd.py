@@ -10,6 +10,7 @@ import os
 import sys
 
 import grpc
+import grpc.aio
 
 _PROTO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "proto")
 if _PROTO not in sys.path:
@@ -114,6 +115,19 @@ def txn_revision(resp) -> int:
     return resp.header.revision
 
 
-def watch_create(start: bytes, end: bytes, start_revision: int = 0):
+def watch_create(start: bytes, end: bytes, start_revision: int = 0,
+                 watch_id: int = 0):
+    """A watch on ``[start, end)`` (``end`` empty: the one key ``start``);
+    ``watch_id`` 0 lets the server name it."""
     return rpc_pb2.WatchRequest(create_request=rpc_pb2.WatchCreateRequest(
-        key=start, range_end=end, start_revision=start_revision))
+        key=start, range_end=end, start_revision=start_revision,
+        watch_id=watch_id))
+
+
+def aio_watch(target: str):
+    """An asyncio channel and its Watch callable, made inside the event
+    loop that will read every watcher stream of a process."""
+    channel = grpc.aio.insecure_channel(target, options=_CHANNEL_OPTIONS)
+    return channel, channel.stream_stream(
+        WATCH, request_serializer=rpc_pb2.WatchRequest.SerializeToString,
+        response_deserializer=rpc_pb2.WatchResponse.FromString)

@@ -9,6 +9,8 @@ from stats import percentile
 
 
 def read(ctx, q: float, quiet: bool = False):
-    lags = [(arrived - due) * 1e3 for due, arrived in ctx.watch_pairs()
-            if not (quiet and ctx.touched(due, arrived))]
-    return percentile(lags, q) if lags else None
+    due, arrived = ctx.watch_pairs()
+    lags = (arrived - due) * 1e3
+    if quiet:
+        lags = lags[~ctx.touched(due, arrived)]
+    return percentile(lags.tolist(), q) if len(lags) else None

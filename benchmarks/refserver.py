@@ -7,12 +7,21 @@ configuration broken (``--break``) it must read not correct.
 
     python benchmarks/refserver.py <config.json> <seed> <port> [--break NAME]
 
+A Watch stream holds any number of watches, each under its ``watch_id``
+(the client's, else the next of the stream's own), each on its range
+(``range_end`` empty: the one key). A write finds the watches it owes an
+event through an index of the watched ranges (``Ranges``), never a loop
+over every watch. One asyncio event loop serves every call and every
+stream (grpc's asyncio server): no watch or stream waits for a thread, and
+a watch's events that came meanwhile leave in one response, as etcd's do.
+
 Breaks (each is what a tempting shortcut in the program would do):
     stale_read     Ranges are answered from the state three revisions back
                    (a mirror published late / a read that skips the delta)
     lost_write     1 write in 200 is acknowledged and never applied
                    (an acknowledgement sent before the commit)
-    dropped_event  1 watch event in 200 is not delivered
+    dropped_event  1 watch event in 200 is not delivered (of the events
+                   owed to every watch of every stream, counted together)
     altered_row    1 Range answer in 4 carries one row with another revision
                    (an answer altered where it is produced)
     uncompacted    a Compact is acknowledged and nothing is removed: reads
@@ -26,15 +35,17 @@ from __future__ import annotations
 import bisect
 import json
 import os
-import queue
 import sys
 import threading
-from concurrent import futures
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+
 import grpc  # noqa: E402
+import grpc.aio  # noqa: E402
 
 from etcd import COMPACT_REV_KEY, kv_pb2, rpc_pb2  # noqa: E402
 from state import State  # noqa: E402
@@ -46,6 +57,45 @@ ERR_COMPACTED = "etcdserver: mvcc: required revision has been compacted"
 
 class Compacted(Exception):
     """A read below the compact revision."""
+
+
+class Ranges:
+    """The watched ranges as an index: the sorted bounds of every range
+    and, for each stretch between two neighbouring bounds, the watches whose
+    range covers it. A key finds its watches by one bisection; the index is
+    rebuilt on the first write after a watch comes or goes."""
+
+    def __init__(self):
+        self.watches: dict[int, dict] = {}
+        self.bounds: list[bytes] = []
+        self.cover: list[list] = []
+        self.dirty = False
+
+    def add(self, w: dict) -> None:
+        self.watches[id(w)] = w
+        self.dirty = True
+
+    def remove(self, w: dict) -> None:
+        self.watches.pop(id(w), None)
+        self.dirty = True
+
+    def match(self, key: bytes) -> list:
+        if self.dirty:
+            self._build()
+        i = bisect.bisect_right(self.bounds, key) - 1
+        return self.cover[i] if 0 <= i < len(self.cover) else []
+
+    def _build(self) -> None:
+        ranges: dict[tuple, list] = {}
+        for w in self.watches.values():
+            ranges.setdefault((w["start"], w["end"]), []).append(w)
+        bounds = sorted({b for r in ranges for b in r})
+        cover: list[list] = [[] for _ in bounds]
+        for (start, end), ws in ranges.items():
+            for i in range(bisect.bisect_left(bounds, start),
+                           bisect.bisect_left(bounds, end)):
+                cover[i].extend(ws)
+        self.bounds, self.cover, self.dirty = bounds, cover, False
 
 
 class Store:
@@ -62,7 +112,7 @@ class Store:
             self.hist.setdefault(t.key(i), []).append(
                 (rev, None if verb == "delete" else (t, i, ver)))
         self.keys = sorted(self.hist)
-        self.watchers: list = []
+        self.ranges = Ranges()
         self.counter = {"write": 0, "event": 0, "range": 0}
         self.base = self.rev
 
@@ -142,12 +192,11 @@ class Store:
                     self.hist[key] = chain = []
                     bisect.insort(self.keys, key)
                 chain.append((rev, val))
-                for w in self.watchers:
-                    if w["start"] <= key < w["end"]:
-                        if (self.broken == "dropped_event"
-                                and self._tick("event", 200)):
-                            continue
-                        w["queue"].put((rev, key, val))
+                for w in self.ranges.match(key):
+                    if (self.broken == "dropped_event"
+                            and self._tick("event", 200)):
+                        continue
+                    w["queue"].put_nowait(("event", w["id"], rev, key, val))
         resp = rpc_pb2.TxnResponse(succeeded=True)
         resp.header.revision = rev
         if put:
@@ -184,63 +233,122 @@ class Store:
         resp.header.revision = self.rev
         return resp
 
-    def watch(self, requests, context):
-        create = next(requests).create_request
-        w = {"start": bytes(create.key), "end": bytes(create.range_end),
-             "queue": queue.SimpleQueue()}
-        with self.lock:
-            self.watchers.append(w)
-            created = self.rev
-        context.add_callback(lambda: w["queue"].put(None))
-        first = rpc_pb2.WatchResponse(created=True)
-        first.header.revision = created
-        yield first
-        while True:
-            item = w["queue"].get()
-            if item is None:
-                return
-            rev, key, val = item
-            resp = rpc_pb2.WatchResponse()
+    async def watch(self, requests, context):
+        """One Watch stream: a task reads its create and cancel requests;
+        this coroutine writes its responses, every event a watch is owed
+        that has come meanwhile in one response, as etcd does."""
+        out: asyncio.Queue = asyncio.Queue()
+        mine: dict[int, dict] = {}
+        reader = asyncio.get_running_loop().create_task(
+            self._watch_requests(requests, out, mine))
+        try:
+            while True:
+                items = [await out.get()]
+                while not out.empty():
+                    items.append(out.get_nowait())
+                for resp in _responses(items):
+                    await context.write(resp)
+        finally:
+            reader.cancel()
+            with self.lock:
+                for w in mine.values():
+                    self.ranges.remove(w)
+
+    async def _watch_requests(self, requests, out, mine: dict) -> None:
+        """A watch is in the index, and its created response queued ahead of
+        every event it is owed, under the store's lock."""
+        next_id = 0
+        async for req in requests:
+            which = req.WhichOneof("request_union")
+            if which == "create_request":
+                c = req.create_request
+                next_id += 1
+                wid = c.watch_id if c.watch_id > 0 else next_id
+                w = {"start": bytes(c.key),
+                     "end": bytes(c.range_end) or bytes(c.key) + b"\0",
+                     "id": wid, "queue": out}
+                with self.lock:
+                    mine[wid] = w
+                    self.ranges.add(w)
+                    out.put_nowait(("created", wid, self.rev))
+            elif which == "cancel_request":
+                wid = req.cancel_request.watch_id
+                with self.lock:
+                    if wid in mine:
+                        self.ranges.remove(mine.pop(wid))
+                        out.put_nowait(("canceled", wid, self.rev))
+
+
+def _responses(items: list):
+    """The responses for a stream's queued items, in order: a created or
+    canceled one as it comes; a watch's events gathered into one response
+    until the next such item (its header the last event's revision)."""
+    events: dict[int, rpc_pb2.WatchResponse] = {}
+    for kind, wid, rev, *kv in items:
+        if kind != "event":
+            yield from events.values()
+            events = {}
+            resp = rpc_pb2.WatchResponse(watch_id=wid, created=kind == "created",
+                                         canceled=kind == "canceled")
             resp.header.revision = rev
-            if val is None:
-                resp.events.add(type=kv_pb2.Event.DELETE).kv.CopyFrom(
-                    kv_pb2.KeyValue(key=key, mod_revision=rev))
-            else:
-                resp.events.add(type=kv_pb2.Event.PUT).kv.CopyFrom(
-                    kv_pb2.KeyValue(key=key, value=val, mod_revision=rev))
             yield resp
+            continue
+        resp = events.get(wid)
+        if resp is None:
+            resp = events[wid] = rpc_pb2.WatchResponse(watch_id=wid)
+        resp.header.revision = rev
+        key, val = kv
+        if val is None:
+            resp.events.add(type=kv_pb2.Event.DELETE).kv.CopyFrom(
+                kv_pb2.KeyValue(key=key, mod_revision=rev))
+        else:
+            resp.events.add(type=kv_pb2.Event.PUT).kv.CopyFrom(
+                kv_pb2.KeyValue(key=key, value=val, mod_revision=rev))
+    yield from events.values()
 
 
-def _refusing(context, call, req):
+async def _refusing(context, call, req):
     """etcd's error strings for a read below the compact revision and a
     Compact above the head."""
     try:
         return call(req)
     except Compacted:
-        context.abort(grpc.StatusCode.OUT_OF_RANGE, ERR_COMPACTED)
+        await context.abort(grpc.StatusCode.OUT_OF_RANGE, ERR_COMPACTED)
     except ValueError:
-        context.abort(grpc.StatusCode.OUT_OF_RANGE,
-                      "etcdserver: mvcc: required revision is a future revision")
+        await context.abort(
+            grpc.StatusCode.OUT_OF_RANGE,
+            "etcdserver: mvcc: required revision is a future revision")
 
 
-def serve(config: dict, seed: int, port: int, broken: str = ""):
-    """Start the reference on ``port``; returns the grpc server."""
+async def serve(config: dict, seed: int, port: int, broken: str = ""):
+    """Start the reference on ``port`` in the running event loop; returns
+    the grpc server. One thread serves every call and every watch stream:
+    no stream waits for a thread."""
     store = Store(State(config, seed), broken)
-    server = grpc.server(futures.ThreadPoolExecutor(max_workers=64),
-                         options=[("grpc.max_send_message_length", 256 << 20),
-                                  ("grpc.max_receive_message_length", 64 << 20)])
+
+    async def range_(req, ctx):
+        return await _refusing(ctx, store.range, req)
+
+    async def txn(req, _ctx):
+        return store.txn(req)
+
+    async def compact(req, ctx):
+        return await _refusing(ctx, store.compact, req)
+
+    server = grpc.aio.server(options=[("grpc.max_send_message_length", 256 << 20),
+                                      ("grpc.max_receive_message_length", 64 << 20)])
     server.add_generic_rpc_handlers((
         grpc.method_handlers_generic_handler("etcdserverpb.KV", {
             "Range": grpc.unary_unary_rpc_method_handler(
-                lambda req, ctx: _refusing(ctx, store.range, req),
+                range_,
                 request_deserializer=rpc_pb2.RangeRequest.FromString,
                 response_serializer=rpc_pb2.RangeResponse.SerializeToString),
             "Txn": grpc.unary_unary_rpc_method_handler(
-                lambda req, _ctx: store.txn(req),
+                txn,
                 request_deserializer=rpc_pb2.TxnRequest.FromString,
                 response_serializer=rpc_pb2.TxnResponse.SerializeToString),
             "Compact": grpc.unary_unary_rpc_method_handler(
-                lambda req, ctx: _refusing(ctx, store.compact, req),
+                compact,
                 request_deserializer=rpc_pb2.CompactionRequest.FromString,
                 response_serializer=rpc_pb2.CompactionResponse.SerializeToString)}),
         grpc.method_handlers_generic_handler("etcdserverpb.Watch", {
@@ -250,23 +358,26 @@ def serve(config: dict, seed: int, port: int, broken: str = ""):
                 response_serializer=rpc_pb2.WatchResponse.SerializeToString)}),
     ))
     server.add_insecure_port(f"127.0.0.1:{port}")
-    server.start()
+    await server.start()
     return server
 
 
-def main(argv: list[str]) -> int:
-    config_path, seed, port, *rest = argv
-    broken = rest[1] if rest[:1] == ["--break"] else ""
-    if broken and broken not in BREAKS:
-        raise SystemExit(f"unknown break {broken!r}; one of {BREAKS}")
-    with open(config_path) as f:
-        config = json.load(f)
-    server = serve(config, int(seed), int(port), broken)
+async def _main(opts, config: dict) -> None:
+    server = await serve(config, opts.seed, opts.port, opts.broken)
     print("ready", flush=True)
-    try:
-        server.wait_for_termination()
-    except KeyboardInterrupt:
-        server.stop(0)
+    await server.wait_for_termination()
+
+
+def main(argv: list[str]) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("config")
+    ap.add_argument("seed", type=int)
+    ap.add_argument("port", type=int)
+    ap.add_argument("--break", dest="broken", default="", choices=("",) + BREAKS)
+    opts = ap.parse_args(argv)
+    with open(opts.config) as f:
+        config = json.load(f)
+    asyncio.run(_main(opts, config))
     return 0
 
 

@@ -47,6 +47,8 @@ import tempfile  # noqa: E402
 import threading  # noqa: E402
 import urllib.request  # noqa: E402
 
+import numpy as np  # noqa: E402
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
@@ -56,6 +58,7 @@ import etcd  # noqa: E402
 import mergephase  # noqa: E402
 import plugin  # noqa: E402
 import prom  # noqa: E402
+import watches  # noqa: E402
 import witness  # noqa: E402
 from state import State  # noqa: E402
 
@@ -201,6 +204,42 @@ def cache_entries() -> int:
     return len(os.listdir(path)) if os.path.isdir(path) else 0
 
 
+class CacheGrowth:
+    """The compile cache over the warm-up, read every ``POLL_S`` on a thread
+    of its own from the warm-up's first request: when an entry last
+    appeared, and the longest the warm-up waited for one (from its start to
+    the first, or between two). A program compiles while the cache is
+    quiet, so the warm-up ends only after a quiet stretch longer than any
+    wait it has seen: ``QUIET_FACTOR`` times the longest, at least the
+    floor. On a cache that holds every program nothing appears and the
+    floor alone holds."""
+
+    POLL_S = 0.25
+    QUIET_FACTOR = 1.5
+
+    def __init__(self, begin: float):
+        self.last, self.longest, self.added = begin, 0.0, 0
+        self._entries = cache_entries()
+        self._done = threading.Event()
+        self._thread = threading.Thread(target=self._watch, daemon=True)
+        self._thread.start()
+
+    def _watch(self) -> None:
+        while not self._done.wait(self.POLL_S):
+            n, now = cache_entries(), time.monotonic()
+            if n > self._entries:
+                self.longest = max(self.longest, now - self.last)
+                self.added += n - self._entries
+                self._entries, self.last = n, now
+
+    def quiet_s(self, floor: float) -> float:
+        return max(floor, self.QUIET_FACTOR * self.longest)
+
+    def stop(self) -> None:
+        self._done.set()
+        self._thread.join()
+
+
 # ------------------------------------------------------------------ workers
 class Worker:
     def __init__(self, spec: dict, run_dir: str, name: str):
@@ -271,6 +310,9 @@ def plan_workers(workload: dict, config: dict, seed: int, target: str,
             elif s.get("loop") == "open":
                 spec["rate"] = float(s["rate"]) * rate_scale / procs
                 spec["phase"] = (p + float(s.get("phase", 0.0))) / procs
+            elif "watch" in s:
+                # the stream's gRPC streams, dealt over its processes
+                spec["watch_proc"], spec["watch_procs"] = p, procs
             if writes_here:
                 # the mix's warm-up writes, dealt evenly over its writers
                 spec["warmup_writes"] = warm // n_writers + (
@@ -333,6 +375,7 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
         for w in workers:
             w.expect("ready", 300.0)
         begin = time.monotonic() + 0.2
+        growth = CacheGrowth(begin)
         for w in workers:
             w.tell(f"start {begin!r}")
 
@@ -340,20 +383,22 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
         # ``warmup_writes``, then none: the delta's fill as the window
         # opens); its reads run on for ``warm_seconds`` and until nothing
         # compiles any more.
-        for w in workers:
-            w.expect("warmed", 300.0)
-        warm_s = float(workload.get("warm_seconds", 5.0))
-        quiet_since, entries = time.monotonic(), cache_entries()
-        while True:
-            time.sleep(0.25)
-            now = time.monotonic()
-            n = cache_entries()
-            if n != entries:
-                entries, quiet_since = n, now
-            if now - begin >= warm_s and now - quiet_since >= min(3.0, warm_s):
-                break
-            if now - begin > 900.0:
-                raise RunFailure("the warm-up never went quiet")
+        try:
+            for w in workers:
+                w.expect("warmed", 300.0)
+            warm_s = float(workload.get("warm_seconds", 5.0))
+            warmed = time.monotonic()
+            while True:
+                time.sleep(0.25)
+                now = time.monotonic()
+                quiet = now - max(warmed, growth.last)
+                if (now - begin >= warm_s
+                        and quiet >= growth.quiet_s(min(3.0, warm_s))):
+                    break
+                if now - begin > 900.0:
+                    raise RunFailure("the warm-up never went quiet")
+        finally:
+            growth.stop()
         # from before the window's first instant until every request that
         # was due in it is answered: this process only sleeps meanwhile
         machine.start()
@@ -366,7 +411,9 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
             w.tell(f"window {win0!r} {win1!r}")
         setup_s = win0 - T_PROCESS
         log(f"window opens: load {t_loaded - t0:.1f}s, boot "
-            f"{t_serving - t_loaded:.1f}s, warm-up {win0 - t_serving:.1f}s")
+            f"{t_serving - t_loaded:.1f}s, warm-up {win0 - t_serving:.1f}s "
+            f"(compile cache +{growth.added} entries, longest wait for one "
+            f"{growth.longest:.1f}s)")
 
         capture: dict = {}
         if opts.trace and on_server:
@@ -419,14 +466,7 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
         sentinels: dict[str, int] = {}
         watch_dumps = []
         if watchers:
-            tables = sorted({w["table"] for wk in watchers
-                             for w in wk.spec["stream"]["watch"]})
-            for name in tables:
-                key = state.tables[name].prefix + SENTINEL
-                resp = stub.txn(etcd.put_txn(key, b"end", 0), timeout=60.0)
-                sentinels[name] = etcd.txn_revision(resp)
-            for w in watchers:
-                w.tell("finish " + " ".join(str(sentinels[t]) for t in tables))
+            drain_watches(stub, state, traffic, watchers, run_dir, sentinels)
             for w in watchers:
                 w.expect("done", 200.0)
                 watch_dumps.append(w.result())
@@ -504,6 +544,37 @@ def run_once(opts, workload: dict, config: dict, bench: dict,
         shutil.rmtree(run_dir, ignore_errors=True)
 
 
+def drain_watches(stub, state: State, traffic: list[dict], watchers: list,
+                  run_dir: str, sentinels: dict) -> None:
+    """Tell each watcher process what each of its watches is owed last: a
+    whole table's watch its table's sentinel, a write after every other
+    (as before there were scopes); any other watch the last acknowledged
+    write of its own range. No sentinel and no progress request is added
+    for a scoped range (one that holds a sentinel's key sees it, and the
+    comparison leaves it out there as for a table's watch)."""
+    shares = [watches.share(w.spec["stream"], state, w.spec["watch_proc"],
+                            w.spec["watch_procs"]) for w in watchers]
+    scoped = [(w["start"], w["end"]) for streams in shares for s in streams
+              for w in s if w["scope"] != "table"]
+    for name in sorted({w["table"] for streams in shares for s in streams
+                        for w in s if w["scope"] == "table"}):
+        key = state.tables[name].prefix + SENTINEL
+        resp = stub.txn(etcd.put_txn(key, b"end", 0), timeout=60.0)
+        sentinels[name] = etcd.txn_revision(resp)
+    acked = []
+    for d in traffic if scoped else ():
+        for r in d["recs"]:
+            if r[0] == check.TXN and r[5]:
+                t, i = state.locate(r[7])
+                acked.append((t.key(i), r[6]))
+    last = watches.last_acked(acked, scoped) if scoped else {}
+    for w, streams in zip(watchers, shares):
+        path = os.path.join(run_dir, w.name + ".finish.json")
+        with open(path, "w") as f:
+            json.dump(watches.targets(streams, sentinels, last), f)
+        w.tell(f"finish {path}")
+
+
 def scale_tables(config: dict, scale: float) -> None:
     """``--scale`` shrinks every table for a rehearsal, and the rates of its
     history with it (the history's seconds stay, so that a Compact's target
@@ -575,19 +646,27 @@ class Context:
                     yield r
 
     def watch_pairs(self):
-        """(the write's due time, the event's arrival) for every (event,
-        watcher) pair of the window's acknowledged writes."""
+        """(the write's due time, the event's arrival), two arrays, for
+        every (event, watcher) pair of the window's acknowledged writes, in
+        the order the events were recorded."""
         due = {r[6]: r[2] for r in self.recs(1, judged_only=False) if r[5]}
-        for dump in self.watches:
-            for w in dump["watches"]:
-                for ev in w["events"]:
-                    if ev[0] in due:
-                        yield due[ev[0]], ev[4]
+        if not due or not self.watches:
+            return np.zeros(0), np.zeros(0)
+        revs = np.fromiter(due, np.int64, len(due))
+        dues = np.fromiter(due.values(), np.float64, len(due))
+        order = np.argsort(revs)
+        revs, dues = revs[order], dues[order]
+        rev = np.concatenate([d["events"]["rev"] for d in self.watches])
+        arrival = np.concatenate([d["events"]["arrival"] for d in self.watches])
+        at = np.minimum(np.searchsorted(revs, rev), len(revs) - 1)
+        hit = revs[at] == rev
+        return dues[at[hit]], arrival[hit]
 
-    def touched(self, a: float, b: float) -> bool:
+    def touched(self, a, b):
         """The pause rule, stated once for every reader: was the machine
         witnessed pausing (or draining a pause's burst) anywhere in ``[a,
-        b]``, a request's due time and its answer's arrival?"""
+        b]``, a request's due time and its answer's arrival? ``a`` and ``b``
+        are arrays: a boolean array."""
         return witness.touched(self.machine.pauses, a, b)
 
     def merges(self):
@@ -670,11 +749,13 @@ def summary_lines(ctx: Context) -> list[str]:
                 f"p95={percentile(lat, 95):.2f}ms p99={percentile(lat, 99):.2f}ms "
                 f"max={max(lat):.2f}ms")
 
-    def both(label, pairs, rate=""):
+    def both(label, a, b, rate=""):
         """One family's line: every request's reading (what is judged) and,
-        beside it, the reading without those a pause touched."""
-        lat = [(b - a) * 1e3 for a, b in pairs]
-        quiet = [(b - a) * 1e3 for a, b in pairs if not ctx.touched(a, b)]
+        beside it, the reading without those a pause touched (``a``, ``b``:
+        arrays of due or sent times and arrivals)."""
+        lat = (b - a) * 1e3
+        quiet = lat[~ctx.touched(a, b)].tolist()
+        lat = lat.tolist()
         return (f"{label}: n={len(lat)}{rate} {tails(lat)}; touched by a pause "
                 f"{len(lat) - len(quiet)}; untouched "
                 + (tails(quiet) if quiet else "nothing"))
@@ -686,15 +767,17 @@ def summary_lines(ctx: Context) -> list[str]:
                      if d["judged"] == judged for r in d["recs"]
                      if r[0] == fam and r[5] and ctx.window[0] <= r[2] < ctx.window[1]]
             if pairs and judged:
-                out.append(both(label, pairs,
+                out.append(both(label, *np.array(pairs).T,
                                 f" {len(pairs) / ctx.window_s:.1f}/s"))
             elif pairs:
                 lat = [(b - a) * 1e3 for a, b in pairs]
                 out.append(f"{label} (background): n={len(lat)} {tails(lat)} "
                            f"{len(lat) / ctx.window_s:.1f}/s")
-    pairs = list(ctx.watch_pairs())
-    if pairs:
-        out.append(both("watch lag (event, watcher) pairs", pairs))
+    due, arrival = ctx.watch_pairs()
+    if len(due):
+        out.append(both("watch lag (event, watcher) pairs", due, arrival))
+    if ctx.watches:
+        out.append(watchers_line(ctx))
     mid = (ctx.window[0] + ctx.window[1]) / 2
     halves = [[(r[4] - r[2]) * 1e3 for r in ctx.recs(1, loop="open") if r[5]
                and (r[2] < mid) == first] for first in (True, False)]
@@ -749,6 +832,32 @@ def summary_lines(ctx: Context) -> list[str]:
     if ticks:
         out.append(compaction_line(ctx, ticks))
     return out
+
+
+def watchers_line(ctx: Context) -> str:
+    """Where the mix watches: watches, streams and threads of each watcher
+    process, the events each recorded in the window, per second, and what
+    that cost it: the share of the window its loop thread (which reads every
+    stream) and the whole process spent on a CPU, and the loop thread's CPU
+    time an event."""
+    lo, hi = ctx.window
+    per = [int(((d["events"]["arrival"] >= lo) & (d["events"]["arrival"] < hi)).sum())
+           for d in ctx.watches]
+    line = (f"watchers: {sum(len(d['watches']) for d in ctx.watches)} watches "
+            f"on {sum(d['streams'] for d in ctx.watches)} streams in "
+            f"{len(ctx.watches)} processes; threads a process "
+            f"{[d['threads'] for d in ctx.watches]}; events recorded in the "
+            f"window a process {per}, "
+            + " ".join(f"{n / ctx.window_s:.1f}/s" for n in per))
+    cpu = [d.get("loop_cpu", ()) for d in ctx.watches]
+    if all(len(c) == 2 for c in cpu):
+        spans = [[b - a for a, b in zip(*c)] for c in cpu]
+        line += ("; CPU in the window a process, loop thread / process "
+                 + " ".join(f"{t / w:.1%}/{p / w:.1%}" for w, t, p in spans)
+                 + ", loop thread an event "
+                 + " ".join(f"{t / n * 1e6:.1f}us" if n else "-"
+                            for (_w, t, _p), n in zip(spans, per)))
+    return line
 
 
 def compaction_line(ctx: Context, ticks: list) -> str:

@@ -21,6 +21,8 @@ with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as _f:
 CELLS = {w["name"]: w for w in BENCHMARK["workloads"]}
 with open(os.path.join(BENCH, "tests", "history_cell.json")) as _f:
     HISTORY_CELL = json.load(_f)
+with open(os.path.join(BENCH, "tests", "watch_cell.json")) as _f:
+    WATCH_CELL = json.load(_f)
 
 
 def _run(cell: str, broken: str, seed: int = 41):
@@ -205,3 +207,34 @@ def test_the_program_with_its_device_path_off_reads_not_correct(cell):
     assert n["readback_device_unmoved"]["value"] == 4
     assert n["mirror_not_serving"]["value"] == 3
     assert not check.verdict(n) and out["result"]["correct"] is False
+
+
+# ---- watches on their own ranges, many to a stream: the dropped event
+@pytest.mark.parametrize("scope,broken", [
+    ("", ""), ("namespace", "dropped_event"), ("key", "dropped_event")])
+def test_a_dropped_event_is_caught_on_a_scoped_watch(scope, broken):
+    """The plain reference in the program's place, serving the watch-heavy
+    mix (streams of many watches, each on its own range): whole, every
+    watch's events are exactly its range's writes; with one (event, watch)
+    delivery in 200 dropped, a namespace's watches alone, or a key's alone,
+    read not correct, and ``first`` names the scoped range."""
+    opts = SimpleNamespace(workload="tiny-watch.watching", seed=2**31 + 61,
+                           seconds=4.0, trace=0, sut="reference", broken=broken,
+                           scale=1.0, keep_trace="", chips=1)
+    workload = json.loads(json.dumps(WATCH_CELL["traffic"]))
+    if scope:
+        watchers = workload["streams"][1]
+        watchers["watch"] = [e for e in watchers["watch"] if e.get("scope") == scope]
+    out = run.run_once(opts, workload, json.loads(json.dumps(WATCH_CELL["config"])),
+                       BENCHMARK)
+    n = out["numbers"]
+    assert n["compared_watch_events"]["value"] >= 200
+    if not broken:
+        assert check.verdict(n), n
+        return
+    assert n["watch_wrong"]["value"] >= 1, n
+    assert not check.verdict(n)
+    first = n["watch_wrong"]["first"]
+    assert any(first.split(": ")[0] == f"{w['table']} [{w['start']!r}, {w['end']!r})"
+               for d in out["ctx"].watches for w in d["watches"]
+               if w["scope"] == scope), first

@@ -22,6 +22,10 @@ with open(os.path.join(BENCH, "tests", "history_cell.json")) as _f:
     #: a configuration with a history and a mix with kube-apiserver's
     #: Compact, as a ``model_config`` PR adds them: files and entries only
     HISTORY_CELL = json.load(_f)
+with open(os.path.join(BENCH, "tests", "watch_cell.json")) as _f:
+    #: a small configuration and a watch-heavy mix (every scope, streams of
+    #: many watches, two watcher processes), as a ``model_config`` PR adds them
+    WATCH_CELL = json.load(_f)
 
 
 def test_names_and_files():
@@ -223,3 +227,47 @@ def test_a_history_and_a_compact_are_added_as_files_only(tmp_path):
     assert "compaction: 1 ticks, 1 acknowledged" in text, text[-3000:]
     # the Compact's own merge, and no crossing: 1..1
     assert "merges in window: counted 1, designed 0 crossings" in text, text[-3000:]
+
+
+def test_a_watch_heavy_mix_is_added_as_files_only(tmp_path):
+    """Thousands of watchers as a later PR adds them, at a size a test run
+    holds: 227 watches on 8 streams in 2 processes, each on its own range
+    (namespaces, keys, whole tables), rehearsed on the CPU backend through
+    the whole run. Every watch's events are its range's acknowledged
+    writes, and no watcher process runs a thread a stream, let alone a
+    watch."""
+    shutil.copytree(BENCH, tmp_path / "benchmarks",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    for program in ("kubebrain_tpu", "native"):
+        (tmp_path / program).symlink_to(os.path.join(ROOT, program))
+    (tmp_path / "benchmarks" / "configs" / "tiny-watch.json").write_text(
+        json.dumps(WATCH_CELL["config"]))
+    (tmp_path / "benchmarks" / "traffic" / "watching.json").write_text(
+        json.dumps(WATCH_CELL["traffic"]))
+    (tmp_path / "benchmarks" / "metrics" / "watch_lag_p95_ms.json").write_text(
+        json.dumps({"reader": "watch_lag_percentile", "args": {"q": 95}}))
+    bench = json.loads(json.dumps(B))
+    bench["configs"].append({"name": "tiny-watch", "source": "test", "reduced": [],
+                             "file": "benchmarks/configs/tiny-watch.json", "why": "t"})
+    bench["workloads"].append({"name": "tiny-watch.watching", "config": "tiny-watch",
+                               "traffic": "watching", "chips": 1, "why": "t"})
+    bench["end_to_end"].append({
+        "name": "watch_lag_p95_ms", "unit": "ms", "better": "lower", "bound": 0.1,
+        "source": "host_clock", "workloads": ["tiny-watch.watching"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    out = subprocess.run(
+        [sys.executable, str(tmp_path / "benchmarks" / "run.py"), "--workload",
+         "tiny-watch.watching", "--seed", str(2**31 + 91), "--seconds", "4",
+         "--trace", "0", "--sut", "cpu"],
+        capture_output=True, text=True, timeout=600, cwd=tmp_path,
+        env=dict(os.environ, JAX_COMPILATION_CACHE_DIR=os.path.join(ROOT, ".jax_cache")))
+    assert out.returncode == 0, out.stderr[-4000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["compared"]["watch_wrong"] == {"value": 0, "limit": 0}, out.stderr[-3000:]
+    assert line["compared"]["compared_watch_events"]["value"] >= 1
+    assert line["rehearsal"]["comparison_passed"] and line["failed"] == 0
+    assert set(line["metrics"]) == {"watch_lag_p95_ms", "setup_s"}
+    watchers = next(t for t in out.stdout.splitlines() if t.startswith("watchers: "))
+    assert watchers.startswith("watchers: 227 watches on 8 streams in 2 processes"), watchers
+    threads = json.loads(watchers.split("threads a process ")[1].split(";")[0])
+    assert len(threads) == 2 and max(threads) <= 4, watchers

@@ -11,6 +11,7 @@ from types import SimpleNamespace
 import pytest
 
 import run
+import watches
 import witness
 from conftest import BENCH
 from stats import percentile
@@ -33,13 +34,14 @@ def _ctx(ticks, txns, events=()):
         machine.observe(before, after)
     recs = [(1, "update", due, due, done, ok, 100 + i, i, 1, 0, "" if ok else "x",
              False) for i, (due, done, ok) in enumerate(txns)]
-    watches = [{"watches": [{"events": [
-        (100 + i, 0, 0, 0, arrived) for i, arrived in events]}]}]
+    watch_dumps = [{"watches": [{}], "events": watches.pack({
+        "watch": [0] * len(events), "rev": [100 + i for i, _a in events],
+        "arrival": [arrived for _i, arrived in events]})}]
     opts = SimpleNamespace(workload="k8s-2500.steady", trace=0, sut="cpu",
                            broken="")
     return run.Context(
         opts, {}, {}, SimpleNamespace(rows=0),
-        [{"judged": True, "loop": "open", "recs": recs}], watches, WINDOW,
+        [{"judged": True, "loop": "open", "recs": recs}], watch_dumps, WINDOW,
         1.0, None, None, 0, {}, machine=machine)
 
 
@@ -95,7 +97,7 @@ def test_the_pause_rule(name):
     assert run.read_metric("touched_ops.txn", ctx) == sum(
         ctx.touched(*txns[i][:2]) for i in ok)
     pairs = [(txns[i][0], arrived) for i, arrived in events]
-    assert list(ctx.watch_pairs()) == pairs
+    assert list(zip(*(a.tolist() for a in ctx.watch_pairs()))) == pairs
     if pairs:
         assert run.read_metric("watch_lag_p99_ms", ctx) == percentile(
             _ms(pairs), 99)

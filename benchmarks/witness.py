@@ -25,6 +25,8 @@ from __future__ import annotations
 import threading
 import time
 
+import numpy as np
+
 from stats import percentile
 
 PAUSE_MIN_S = 0.020   # README.md has the readings behind both constants
@@ -41,9 +43,15 @@ TICK_S = 0.001
 DRAIN = 10
 
 
-def touched(pauses, a: float, b: float) -> bool:
-    """Does ``[a, b]`` overlap a witnessed pause or the drain after it?"""
-    return any(a <= t + DRAIN * (t - f) and b >= f for f, t in pauses)
+def touched(pauses, a, b):
+    """Does ``[a, b]`` overlap a witnessed pause or the drain after it?
+    ``a`` and ``b`` are arrays of instants (or two instants): a boolean array
+    of their shape."""
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    out = np.zeros(np.broadcast(a, b).shape, bool)
+    for f, t in pauses:
+        out |= (a <= t + DRAIN * (t - f)) & (b >= f)
+    return out
 
 
 def clipped_total(pauses, lo: float, hi: float) -> float:

@@ -13,7 +13,8 @@ Protocol with ``run.py`` (lines on stdin / stdout):
     -> warmed                     this process's share of the mix's
                                   ``warmup_writes`` is sent and acknowledged
     <- window <t0> <t1>           requests due in [t0, t1) are the window's
-    <- finish <rev>...            watchers: wait for these sentinel revisions
+    <- finish <path>              watchers: wait until each watch has seen the
+                                  revision the file gives it (``watches.targets``)
     -> done                       drained, records written to ``out``
 
 The warm-up is the stream's own traffic, but its WRITES are counted, not
@@ -29,6 +30,8 @@ machine share. The process never imports JAX (asserted on exit).
 
 from __future__ import annotations
 
+import array
+import asyncio
 import json
 import os
 import pickle
@@ -43,9 +46,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 import grpc  # noqa: E402
+import numpy as np  # noqa: E402
 
 import etcd  # noqa: E402
 import plugin  # noqa: E402
+import watches  # noqa: E402
 from state import State  # noqa: E402
 
 RPC_TIMEOUT_S = 60.0
@@ -98,7 +103,7 @@ class Control:
                 elif word == "window":
                     self.window = (float(args[0]), float(args[1]))
                 elif word == "finish":
-                    self.finish = [int(a) for a in args]
+                    self.finish = args[0]
                 self._cond.notify_all()
 
     def wait(self, attr: str, timeout: float):
@@ -414,70 +419,151 @@ class Traffic:
 
 # ----------------------------------------------------------------- watchers
 class Watchers:
-    """The stream's watches, one gRPC stream and one reader thread each."""
+    """This process's share of a ``watch`` stream's gRPC streams
+    (``watches.share``), all read by ONE thread: an asyncio event loop with
+    a task a stream, never a thread a watch (nor a stream: the sync API
+    spends two threads on each). What a stream receives is recorded as
+    arrays (``watches.FIELDS``), and each watch's highest revision seen is
+    kept for the drain."""
 
     def __init__(self, spec: dict):
         self.spec = spec
         self.state = State(spec["config"], spec["seed"])
-        self.stub = etcd.Stub(spec["target"])
-        self.watches = []
-        for w in spec["stream"]["watch"]:
-            t = self.state.tables[w["table"]]
-            for _ in range(int(w["count"])):
-                self.watches.append({
-                    "table": t.name, "start": t.prefix,
-                    "end": etcd.prefix_end(t.prefix), "created": None,
-                    "events": [], "error": "", "requests": queue.SimpleQueue()})
+        self.streams = watches.share(spec["stream"], self.state,
+                                     int(spec.get("watch_proc", 0)),
+                                     int(spec.get("watch_procs", 1)))
+        self.watches = [dict(w, created=None, error="")
+                        for s in self.streams for w in s]
+        self.last = [0] * len(self.watches)
+        self.records = [{name: array.array(code) for name, code, _dt in
+                         watches.FIELDS} for _s in self.streams]
+        self.threads = 0
+        self.loop = asyncio.new_event_loop()
+        self.reader = threading.Thread(target=self.loop.run_forever,
+                                       daemon=True)
+        self.channel = None
+        self.calls: list = []
+        self.tasks: list = []
+        self.loop_cpu: list = []
 
     def open(self) -> None:
-        for w in self.watches:
-            w["requests"].put(etcd.watch_create(w["start"], w["end"]))
-            w["thread"] = threading.Thread(target=self._read, args=(w,),
-                                           daemon=True)
-            w["thread"].start()
+        self.reader.start()
+        asyncio.run_coroutine_threadsafe(self._open(), self.loop).result(60.0)
         deadline = time.monotonic() + 60.0
         while any(w["created"] is None and not w["error"] for w in self.watches):
             if time.monotonic() > deadline:
                 raise RuntimeError("a watch was never created")
             time.sleep(0.01)
+        self.threads = threading.active_count()
 
-    def _read(self, w: dict) -> None:
-        events = w["events"]
+    async def _open(self) -> None:
+        """Every stream's create requests, then a reader task a stream. A
+        stream of several watches names each by ``watch_id`` k + 1; a
+        stream of one names none (the server's id is that watch's)."""
+        self.channel, watch = etcd.aio_watch(self.spec["target"])
+        first = 0
+        for n, stream in enumerate(self.streams):
+            # a stream of one watch: no watch_id, every response its own
+            single = len(stream) == 1
+            call = watch()
+            self.calls.append(call)
+            for k, w in enumerate(stream):
+                await call.write(etcd.watch_create(
+                    w["start"], b"" if w["scope"] == "key" else w["end"],
+                    watch_id=0 if single else k + 1))
+            self.tasks.append(self.loop.create_task(
+                self._read(call, n, first, len(stream), single)))
+            first += len(stream)
+
+    async def _read(self, call, n: int, first: int, size: int,
+                    single: bool) -> None:
+        rec = self.records[n]
+        at_w, at_r, at_t, at_k, at_v, at_a = (rec[name].append for name, _c, _d
+                                              in watches.FIELDS)
+        mine = range(first, first + size)
         try:
-            for resp in self.stub.watch(iter(w["requests"].get, None)):
+            while True:
+                resp = await call.read()
+                if resp is grpc.aio.EOF:
+                    return
                 t = time.monotonic()
+                idx = first if single else first + resp.watch_id - 1
+                if idx not in mine:
+                    self._fail(mine, f"a response for watch_id {resp.watch_id}")
+                    continue
+                w = self.watches[idx]
                 if resp.created:
                     w["created"] = resp.header.revision
                 if resp.canceled:
                     w["error"] = f"canceled: {resp.cancel_reason}"
-                    return
+                    continue
                 for ev in resp.events:
-                    events.append((ev.kv.mod_revision, int(ev.type),
-                                   zlib.crc32(ev.kv.key),
-                                   zlib.crc32(ev.kv.value), t))
-        except grpc.RpcError as e:
+                    kv = ev.kv
+                    at_w(idx)
+                    at_r(kv.mod_revision)
+                    at_t(int(ev.type))
+                    at_k(zlib.crc32(kv.key))
+                    at_v(zlib.crc32(kv.value))
+                    at_a(t)
+                if resp.events:
+                    self.last[idx] = resp.events[-1].kv.mod_revision
+        except grpc.aio.AioRpcError as e:
             if e.code() != grpc.StatusCode.CANCELLED:
-                w["error"] = f"{e.code().name}: {e.details()}"[:200]
+                self._fail(mine, f"{e.code().name}: {e.details()}"[:200])
+        except asyncio.CancelledError:
+            pass
+
+    def _fail(self, mine, error: str) -> None:
+        for i in mine:
+            self.watches[i]["error"] = self.watches[i]["error"] or error
+
+    async def _close(self) -> None:
+        for call in self.calls:
+            call.cancel()
+        await asyncio.gather(*self.tasks, return_exceptions=True)
+        if self.channel is not None:
+            await self.channel.close()
+
+    def _clock_window(self, lo: float, hi: float) -> None:
+        """At the window's open and close (the loop's clock is
+        ``time.monotonic``), the loop thread's CPU seconds and the
+        process's: what recording the window's events cost this process."""
+        for at in (lo, hi):
+            self.loop.call_at(at, lambda: self.loop_cpu.append(
+                (time.monotonic(), time.thread_time(), time.process_time())))
 
     def run(self, control: Control) -> None:
-        finish = control.wait("finish", 3600.0)
-        if finish is None:
+        window = control.wait("window", 3600.0)
+        if window is not None:
+            self.loop.call_soon_threadsafe(self._clock_window, *window)
+        path = control.wait("finish", 3600.0)
+        if path is None:
             raise RuntimeError("never told to finish")
-        # the sentinel writes come last, so in revision order everything
-        # before them has been delivered once they are seen
-        want = dict(zip(sorted({w["table"] for w in self.watches}), finish))
+        # each watch's last acknowledged write, or its table's sentinel:
+        # the revision after which, in revision order, it is owed nothing
+        with open(path) as f:
+            want = json.load(f)
         deadline = time.monotonic() + DRAIN_S
-        for w in self.watches:
-            while not w["error"] and time.monotonic() < deadline and not (
-                    w["events"] and w["events"][-1][0] >= want[w["table"]]):
+        for i, w in enumerate(self.watches):
+            while (not w["error"] and time.monotonic() < deadline
+                   and self.last[i] < want[i] and want[i] > (w["created"] or 0)):
                 time.sleep(0.01)
-        self.stub.close()
+        asyncio.run_coroutine_threadsafe(self._close(), self.loop).result(30.0)
+        self.loop.call_soon_threadsafe(self.loop.stop)
+        self.reader.join(10.0)
 
     def dump(self, path: str) -> None:
+        columns = {name: np.concatenate([np.frombuffer(r[name], dt)
+                                         for r in self.records])
+                   for name, _c, dt in watches.FIELDS} if self.records else {}
         with open(path, "wb") as f:
-            pickle.dump({"kind": "watch", "watches": [
-                {k: w[k] for k in ("table", "created", "events", "error")}
-                for w in self.watches]}, f)
+            pickle.dump({"kind": "watch", "threads": self.threads,
+                         "streams": len(self.streams),
+                         "loop_cpu": self.loop_cpu,
+                         "watches": [{k: w[k] for k in (
+                             "table", "scope", "start", "end", "created", "error")}
+                             for w in self.watches],
+                         "events": watches.pack(columns)}, f)
 
 
 def main(argv: list[str]) -> int:
